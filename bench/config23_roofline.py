@@ -3,7 +3,7 @@ chain depth, donation, and multi-query width (ROADMAP item 4).
 
 Bench rounds r01–r16 showed dispatch chains at 462–477 GB/s device
 throughput (~57% of the v5e HBM spec) and a single-stream floor of
-~287–300 qps — one device→host read RPC per dispatch.  r17 attacks
+~287–300 qps — one device→host read per dispatch.  r17 attacks
 both ends (donated ping-pong chains, solo fast lane, popcount-chain
 layout) and this config measures every piece:
 
@@ -321,8 +321,8 @@ def selected_roofline(d, oracle: np.ndarray) -> dict:
 
 def multiquery_single_stream(api, oracle: np.ndarray) -> dict:
     """ONE client, W Counts per request, through the product path: W
-    answers per read RPC.  This is the attack on the ~290 qps
-    one-RPC-per-dispatch floor — qps scales with width until the scan
+    answers per host read.  This is the attack on the ~290 qps
+    one-read-per-dispatch floor — qps scales with width until the scan
     itself dominates."""
     out = {}
     widths, w = [], 1
@@ -445,7 +445,7 @@ def main() -> None:
         log(f"first product query (plane build + compile): "
             f"{time.perf_counter() - t0:.1f}s")
         # the width sweep measures the WINDOWED floor-amortization
-        # curve (W answers per read RPC) — the fast lane would move
+        # curve (W answers per host read) — the fast lane would move
         # the width-1 floor the gain bar and round-over-round
         # vs_baseline are computed against; solo_lane below measures
         # the lane explicitly, against that same windowed floor

@@ -61,7 +61,7 @@ def regression_guards(metric: str, detail: dict) -> list:
     """The round-over-round guard (bench.py machinery): the tracked
     sub-metric is the on/off qps RATIO — overhead creeping up shrinks
     it, so a future change that quietly fattens the cost plane fails
-    the guard even while absolute qps wanders with the tunnel."""
+    the guard even while absolute qps wanders run to run."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     spec = importlib.util.spec_from_file_location(
         "bench_headline", os.path.join(repo, "bench.py"))

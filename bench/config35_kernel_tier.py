@@ -11,8 +11,9 @@ delta-aware program ladder pre-compiles at plane-residency time, off
 the serving path).  This config measures and PROVES all three:
 
 - **tier table**: each kernel kind (whole-plane ``row_counts``, the
-  ``count`` chain, the selected-row gather) timed per tier on the
-  config23 plane shapes → GB/s side by side.  On CPU the pallas tier
+  ``count`` chain, and — XLA only, it has no Pallas form — the
+  selected-row gather) timed per tier on the config23 plane shapes →
+  GB/s side by side.  On CPU the pallas tier
   runs interpreter mode — the table proves the contract, not HBM;
   the real bandwidth column lands with the TPU round;
 - **loop-fusion proof**: a collection window of 8 same-shape
@@ -124,23 +125,24 @@ def tier_table(plane: np.ndarray, use_pallas: bool,
             p, interpret=interpret)),
         "count": jax.jit(lambda w: pallas_kernels.count(
             w, interpret=interpret)),
-        "selected": jax.jit(lambda p, ix: pallas_kernels.selected_row_counts(
-            p, ix, interpret=interpret)),
     }
     for tier, kit in (("xla", xla),) + ((("pallas", plk),)
                                         if use_pallas else ()):
-        sel_bytes = plane.shape[0] * N_SEL * WORDS * 4
         tiers[tier] = {
             "rowcounts": timed(lambda: kit["rowcounts"](d), plane.nbytes),
             "count": timed(lambda: kit["count"](flat), plane.nbytes),
-            "selected": timed(lambda: kit["selected"](d, idx), sel_bytes),
         }
         # every tier oracle-exact on the same draw
         got = np.asarray(kit["rowcounts"](d)).sum(0, dtype=np.int64)
         assert (got == oracle_rows).all(), f"{tier} rowcounts diverged"
-        got = np.asarray(kit["selected"](d, idx)).sum(0, dtype=np.int64)
-        assert (got == oracle_rows[np.asarray(idx)]).all(), \
-            f"{tier} selected gather diverged"
+        if "selected" in kit:
+            sel_bytes = plane.shape[0] * N_SEL * WORDS * 4
+            tiers[tier]["selected"] = timed(
+                lambda: kit["selected"](d, idx), sel_bytes)
+            got = np.asarray(kit["selected"](d, idx)).sum(
+                0, dtype=np.int64)
+            assert (got == oracle_rows[np.asarray(idx)]).all(), \
+                f"{tier} selected gather diverged"
         log(f"tier {tier}: " + "  ".join(
             f"{k}={v['gbps']:.2f} GB/s" for k, v in tiers[tier].items()))
     del d, flat

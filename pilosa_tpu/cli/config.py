@@ -134,15 +134,14 @@ class Config:
     # success restores healthy serving.
     device_health_probe_seconds: float = 5.0
     # Serving kernel tier (r24): "xla" (default) compiles every fused
-    # family through the XLA oracle tier; "pallas" routes the hottest
-    # families (selected-row gather scans, whole-plane count chains,
-    # filtered row-count reduces — delta-overlay variants included)
-    # through hand-written Pallas TPU kernels.  Per-family fail-safe:
-    # a family whose Pallas lowering fails falls back to XLA silently
-    # (pallas_fallback_total counts it), and degraded serving always
-    # runs the per-item XLA fallback whatever the tier.  On non-TPU
-    # backends "pallas" resolves to "xla" unless the test-only
-    # PILOSA_PALLAS_INTERPRET escape hatch forces interpret mode.
+    # family through the XLA oracle tier; "pallas" routes the
+    # whole-plane scans (count chains, filtered row-count reduces —
+    # delta-overlay variants included) through hand-written Pallas TPU
+    # kernels.  "pallas" is a start-up error on a non-TPU backend and
+    # under a mesh placement (set mesh=false).  A shape whose Pallas
+    # program fails to compile serves XLA, logged at ERROR with the
+    # compiler's message and counted in pallas_fallback_total;
+    # degraded serving always runs the per-item XLA fallback.
     kernel_tier: str = "xla"
     # On-device dispatch loops (r24): the batcher collapses a
     # collection window's same-shape selected-count groups into ONE
@@ -199,10 +198,6 @@ class Config:
     # node re-expands at near raw-copy speed instead of re-decoding
     # roaring containers; any write/compaction/restore invalidates.
     plane_sidecars: bool = True
-    # JAX persistent compilation cache directory ("" = off): warm
-    # restarts skip the ~1 s first-query XLA compile by reloading
-    # compiled programs from disk (jax_compilation_cache_dir).
-    compilation_cache_dir: str = ""
     # Queries EXECUTING at once; extras queue at the executor (bounds
     # concurrent device scratch; 0 = off).  Size against HBM headroom:
     # resident planes (plane_budget_bytes) + slots × ~0.5 GB scratch

@@ -11,10 +11,10 @@ bandwidth beats container branching on a vector machine.
 
 This module is deliberately jax-free (host layout constants and numpy
 helpers only) so that ``import pilosa_tpu`` has no side effects; the
-compute modules (:mod:`.kernels`, :mod:`.bsi`) enable JAX x64 on *their*
-import via :mod:`._jaxcfg` — cross-shard counts on a 1B-column index
-exceed ``int32``, and all engine arrays use explicit dtypes so the global
-flag only widens our reductions.
+compute modules (:mod:`.kernels`, :mod:`.bsi`, :mod:`.sparse`) configure
+JAX on *their* import via :mod:`._jaxcfg` — the compile-cache location,
+and NOT ``jax_enable_x64``: device accumulations stay int32 (exact per
+shard) and cross-shard totals finish on the host in int64.
 """
 
 from pilosa_tpu.engine.words import (

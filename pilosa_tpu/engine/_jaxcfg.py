@@ -17,7 +17,32 @@ path.  The engine's contract instead is:
 import os
 import warnings
 
-import jax  # noqa: F401  (kept as the single config hook point)
+import jax
+
+# Persistent compilation cache: ONE rule, decided here before the first
+# compile.  An operator (or the machine image) places the cache with
+# JAX_COMPILATION_CACHE_DIR, which jax reads itself — then no code sets
+# a directory.  Otherwise it lives at a FIXED path under the checkout:
+# the path is part of what a warm start needs to find again, so never a
+# temp name, pid or time.  Server children, bench.py and chip_smoke.py
+# all import this module and therefore share one cache.  The two
+# thresholds drop to "persist everything": the serving program set is
+# small and every entry saves a first-query compile on the next boot.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir",
+                      DEFAULT_COMPILE_CACHE_DIR)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+def compile_cache_dir() -> str:
+    """Where compiled programs persist (for the boot log)."""
+    return jax.config.jax_compilation_cache_dir
+
 
 # Donated ping-pong buffer chains (r17): the chain families pass a
 # retired output buffer as a donated scratch argument so consecutive

@@ -2,36 +2,39 @@
 
 The XLA-fused kernels in :mod:`pilosa_tpu.engine.kernels` are the
 default compute path; these Pallas variants give explicit control of
-the HBM→VMEM streaming and accumulation for the two hottest shapes
-(reference hot loops: container-pairwise intersect kernels and the
-popcount matrix behind TopN, ``roaring/roaring.go`` /
+the HBM→VMEM streaming and accumulation for the two whole-plane scans
+(reference hot loop: the popcount matrix behind TopN,
 ``fragment.top``; SURVEY.md §4.2–4.3):
 
-- :func:`intersect_count`: ``uint32[S, W] × uint32[S, W] → int32[S]``
-  (and + popcount + per-shard reduce, one VMEM pass);
 - :func:`row_counts`: ``uint32[S, R, W] (× filter) → int32[S, R]``
-  (the TopN matrix), gridded over shards × row blocks so each block
-  streams ~1MB through VMEM;
+  (the TopN matrix), gridded over shards × row blocks × word blocks so
+  each step streams a ≤4 MB tile through VMEM;
 - :func:`count`: ``uint32[S, W] → int32[S]`` (the whole-bitmap count
   chain), word-blocked so a wide scan accumulates through VMEM-sized
-  tiles like :func:`kernels.count`'s tiled reduce;
-- :func:`selected_row_counts`: ``uint32[S, R, W] + int32[N] →
-  int32[S, N]`` — the TopN/product gather scan.  The slot list rides
-  the scalar-prefetch channel so Mosaic knows the next gathered row
-  block before the grid step runs (matches
-  ``kernels.selected_row_counts``'s sorted-slot contract: ascending
-  slots walk the row axis in ascending stride order).
+  tiles like :func:`kernels.count`'s tiled reduce.
 
 These are the ``kernel_tier="pallas"`` serving tier: ``exec/fused.py``
-routes the hottest fused families here when the knob is on, keeping
-the XLA kernels as the correctness oracle and fallback.  Delta-overlay
+routes the count-batch and rowcounts families here when the knob is
+on, keeping the XLA kernels as the correctness oracle.  Delta-overlay
 adjustment (base⊕delta) stays one program: the fused layer composes
-these base scans with the overlay scatter inside a single jit.
+these base scans with the overlay scatter inside a single jit.  The
+selected-row gather stays on XLA: a one-row block in the second-minor
+(sublane) position is not a legal Mosaic block, and widening it to the
+8-row tile reads 8× the bytes.
+
+No operand is padded or copied: the shard and row grids are
+``pl.cdiv`` grids whose edge block reads past the array (unspecified
+data) into output rows/columns that are themselves past the array and
+therefore dropped on write-back — every (shard, row) count depends on
+its own words only.  The word axis is a reduction, so ITS edge block
+is masked in the kernel (only when ``W`` is not a block multiple; the
+production shard width, 32768 words, always is).
 
 Popcount uses the SWAR bit-twiddling reduction (shift/mask adds) —
 portable across Mosaic versions regardless of ``population_count``
 support.  Tests run the same kernels in interpreter mode on CPU
-against the numpy oracle; on TPU they compile to Mosaic.
+against the numpy oracle and lower them for TPU without a chip; on TPU
+they compile to Mosaic.
 """
 
 from __future__ import annotations
@@ -41,7 +44,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+_SB = 8      # shard block (Mosaic sublane granule)
+_RB = 128    # row block (int32 lane granule)
+_WB = 1024   # word block: 8 x 128 x 1024 x 4B = 4MB tile through VMEM
+_CWB = 128 * 1024  # count word block: 8 x 128K x 4B = 4MB tile
+
 
 def _popcount_u32(x: jax.Array) -> jax.Array:
     """SWAR popcount per uint32 lane -> int32.  Masks are weak python
@@ -55,49 +63,17 @@ def _popcount_u32(x: jax.Array) -> jax.Array:
     return (x & 0x3F).astype(jnp.int32)
 
 
-def _intersect_count_kernel(a_ref, b_ref, out_ref):
-    words = a_ref[...] & b_ref[...]
-    out_ref[...] = jnp.sum(_popcount_u32(words), axis=-1, keepdims=True)
+def _mask_word_tail(words: jax.Array, k, wb: int, w: int) -> jax.Array:
+    """Zero the lanes of word block ``k`` that lie past the array's
+    ``w`` words (an edge block reads unspecified data there)."""
+    col = k * wb + jax.lax.broadcasted_iota(jnp.int32, words.shape,
+                                            words.ndim - 1)
+    return jnp.where(col < w, words, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def intersect_count(a: jax.Array, b: jax.Array,
-                    interpret: bool = False) -> jax.Array:
-    """Count(Intersect) per shard: uint32[S, W] x2 -> int32[S].
-
-    Shards stream in blocks of 8 (Mosaic requires the sublane block dim
-    divisible by 8); each grid step moves 2x8x4W bytes through VMEM.
-    """
-    s, w = a.shape
-    sb = 8
-    pad = (-s) % sb
-    if pad:
-        a = jnp.pad(a, ((0, pad), (0, 0)))
-        b = jnp.pad(b, ((0, pad), (0, 0)))
-    s_pad = s + pad
-    out = pl.pallas_call(
-        _intersect_count_kernel,
-        grid=(s_pad // sb,),
-        in_specs=[pl.BlockSpec((sb, w), lambda i: (i, 0)),
-                  pl.BlockSpec((sb, w), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((sb, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s_pad, 1), jnp.int32),
-        interpret=interpret,
-    )(a, b)
-    return out[:s, 0]
-
-
-_SB = 8      # shard block (Mosaic sublane granule)
-_RB = 128    # row block (int32 lane granule)
-_WB = 1024   # word block: 8 x 128 x 1024 x 4B = 4MB tile through VMEM
-
-
-def _row_counts_kernel(plane_ref, filter_ref, out_ref):
-    k = pl.program_id(2)
-    # plane (SB, rb, wb) & filter (SB, 1, wb) -> broadcast over rows
-    words = plane_ref[...] & filter_ref[...]
-    counts = jnp.sum(_popcount_u32(words), axis=-1)  # (SB, rb)
-
+def _accumulate(out_ref, counts, k) -> None:
+    """The output tile persists across the innermost (word) grid axis:
+    the first word block stores, the rest add."""
     @pl.when(k == 0)
     def _init():
         out_ref[...] = counts
@@ -105,6 +81,18 @@ def _row_counts_kernel(plane_ref, filter_ref, out_ref):
     @pl.when(k != 0)
     def _acc():
         out_ref[...] += counts
+
+
+def _row_counts_kernel(*refs, w: int, wb: int):
+    plane_ref, out_ref = refs[0], refs[-1]
+    k = pl.program_id(2)
+    words = plane_ref[...]                       # (SB, rb, wb)
+    if len(refs) == 3:
+        # filter (SB, wb) broadcasts over the row axis in VMEM
+        words = words & refs[1][...][:, None, :]
+    if w % wb:
+        words = _mask_word_tail(words, k, wb, w)
+    _accumulate(out_ref, jnp.sum(_popcount_u32(words), axis=-1), k)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -118,49 +106,32 @@ def row_counts(plane: jax.Array, filter_words: jax.Array | None = None,
     word-block axis and accumulates partial counts.
     """
     s, r, w = plane.shape
-    if filter_words is None:
-        filter_words = jnp.full((s, w), 0xFFFFFFFF, dtype=jnp.uint32)
-    # rows pad to one full block (<=128 rows) or to 128-row blocks
-    rb = r if r <= _RB else _RB
-    s_pad, r_pad = (-s) % _SB, (-r) % rb
-    # words pad with zeros to a _WB multiple (zero words popcount to
-    # zero under any filter) — NEVER stream the whole word axis in one
-    # grid step: an 8 x 128 x w tile blows the ~4MB VMEM budget at
-    # real plane widths when w % _WB != 0
-    wb, w_pad = (w, 0) if w <= _WB else (_WB, (-w) % _WB)
-    if s_pad or r_pad or w_pad:
-        plane = jnp.pad(plane, ((0, s_pad), (0, r_pad), (0, w_pad)))
-        filter_words = jnp.pad(filter_words, ((0, s_pad), (0, w_pad)))
-    sp, rp, wp = s + s_pad, r + r_pad, w + w_pad
-    filt3 = filter_words.reshape(sp, 1, wp)
-    out = pl.pallas_call(
-        _row_counts_kernel,
-        grid=(sp // _SB, rp // rb, wp // wb),
-        in_specs=[
-            pl.BlockSpec((_SB, rb, wb), lambda i, j, k: (i, j, k)),
-            pl.BlockSpec((_SB, 1, wb), lambda i, j, k: (i, 0, k)),
-        ],
+    # blocks: the whole axis when it fits one tile, else the tile —
+    # NEVER the whole of a wide word axis (an 8 x 128 x w tile blows
+    # the VMEM budget at real plane widths)
+    rb, wb = min(r, _RB), min(w, _WB)
+    operands = [plane]
+    in_specs = [pl.BlockSpec((_SB, rb, wb), lambda i, j, k: (i, j, k))]
+    if filter_words is not None:
+        operands.append(filter_words)
+        in_specs.append(pl.BlockSpec((_SB, wb), lambda i, j, k: (i, k)))
+    return pl.pallas_call(
+        functools.partial(_row_counts_kernel, w=w, wb=wb),
+        grid=(pl.cdiv(s, _SB), pl.cdiv(r, rb), pl.cdiv(w, wb)),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((_SB, rb), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((sp, rp), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((s, r), jnp.int32),
         interpret=interpret,
-    )(plane, filt3)
-    return out[:s, :r]
+    )(*operands)
 
 
-_CWB = 128 * 1024  # count word block: 8 x 128K x 4B = 4MB tile
-
-
-def _count_kernel(w_ref, out_ref):
+def _count_kernel(w_ref, out_ref, *, w: int, wb: int):
     k = pl.program_id(1)
-    counts = jnp.sum(_popcount_u32(w_ref[...]), axis=-1, keepdims=True)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = counts
-
-    @pl.when(k != 0)
-    def _acc():
-        out_ref[...] += counts
+    words = w_ref[...]                           # (SB, wb)
+    if w % wb:
+        words = _mask_word_tail(words, k, wb, w)
+    _accumulate(out_ref,
+                jnp.sum(_popcount_u32(words), axis=-1, keepdims=True), k)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -173,68 +144,13 @@ def count(words: jax.Array, interpret: bool = False) -> jax.Array:
     partial popcounts (each step streams a <=4MB tile through VMEM).
     """
     s, w = words.shape
-    s_pad = (-s) % _SB
-    wb, w_pad = (w, 0) if w <= _CWB else (_CWB, (-w) % _CWB)
-    if s_pad or w_pad:
-        words = jnp.pad(words, ((0, s_pad), (0, w_pad)))
-    sp, wp = s + s_pad, w + w_pad
+    wb = min(w, _CWB)
     out = pl.pallas_call(
-        _count_kernel,
-        grid=(sp // _SB, wp // wb),
+        functools.partial(_count_kernel, w=w, wb=wb),
+        grid=(pl.cdiv(s, _SB), pl.cdiv(w, wb)),
         in_specs=[pl.BlockSpec((_SB, wb), lambda i, k: (i, k))],
         out_specs=pl.BlockSpec((_SB, 1), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((sp, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.int32),
         interpret=interpret,
     )(words)
-    return out[:s, 0]
-
-
-def _selected_kernel(idx_ref, plane_ref, out_ref):
-    del idx_ref  # consumed by the index maps
-    k = pl.program_id(2)
-    counts = jnp.sum(_popcount_u32(plane_ref[...]), axis=-1)  # (SB, 1)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = counts
-
-    @pl.when(k != 0)
-    def _acc():
-        out_ref[...] += counts
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def selected_row_counts(plane: jax.Array, row_idx: jax.Array,
-                        interpret: bool = False) -> jax.Array:
-    """Gathered-row popcounts: uint32[S, R, W] + int32[N] -> int32[S, N].
-
-    The Pallas face of :func:`kernels.selected_row_counts`: the slot
-    list rides the scalar-prefetch channel, so each grid step's block
-    index map reads ``idx_ref[j]`` and Mosaic can start the next
-    gathered row block's HBM→VMEM copy before the step runs.  Sorted
-    ascending slots (the fused layer's contract) make those copies
-    walk the row axis in ascending stride order.  Slots may repeat
-    (padded asks); each output column accumulates independently.
-    """
-    s, r, w = plane.shape
-    n = row_idx.shape[0]
-    s_pad = (-s) % _SB
-    wb, w_pad = (w, 0) if w <= _WB else (_WB, (-w) % _WB)
-    if s_pad or w_pad:
-        plane = jnp.pad(plane, ((0, s_pad), (0, 0), (0, w_pad)))
-    sp, wp = s + s_pad, w + w_pad
-    idx = row_idx.astype(jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n, sp // _SB, wp // wb),
-        in_specs=[pl.BlockSpec((_SB, 1, wb),
-                               lambda j, i, k, idx_ref: (i, idx_ref[j], k))],
-        out_specs=pl.BlockSpec((_SB, 1), lambda j, i, k, idx_ref: (i, j)),
-    )
-    out = pl.pallas_call(
-        _selected_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((sp, n), jnp.int32),
-        interpret=interpret,
-    )(idx, plane)
-    return out[:s]
+    return out[:, 0]

@@ -91,10 +91,7 @@ def _mesh_program(mesh, axis: str, k: int | None):
     """jitted (filter, word_idx, mask, row_ptr) -> counts | top_k.
     Cached per (mesh, axis, k): shard_map re-wrapping per call would
     retrace every query."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sm = shard_map(

@@ -15,6 +15,7 @@ sign scalar, see ``engine.bsi.predicate_masks``), so ``amount > 5`` and
 
 from __future__ import annotations
 
+import logging as _logging
 import threading as _threading
 import time as _time
 
@@ -24,6 +25,8 @@ import numpy as np
 
 from pilosa_tpu.engine import bsi as bsik
 from pilosa_tpu.engine import kernels
+
+_log = _logging.getLogger("pilosa_tpu.exec")
 
 # node encodings (hashable nested tuples):
 #   ("leaf", i)                      leaf i is uint32[..., W] words
@@ -295,35 +298,42 @@ class FusedCache:
         # caused it, not just as a climbing built counter
         self._ledger = ledger or NULL_LEDGER
         self.flight = flight or NULL_FLIGHT
-        # kernel tier (r24): "pallas" routes the hottest fused families
-        # (selcounts[-delta/-loop], rowcounts-batch/-delta, count-batch)
-        # through the Pallas/Mosaic kernels; "xla" (default) is today's
-        # proven path and stays the correctness oracle + the governor's
-        # degraded fallback.  Real pallas selection gates on the TPU
-        # backend at runtime — on any other backend the knob silently
-        # falls back to XLA (counted) unless the TEST-ONLY interpret
-        # escape hatch (PILOSA_PALLAS_INTERPRET=1) is set, which runs
-        # the same kernels through the pallas interpreter on CPU so
-        # tier-1 can pin bit-exactness without a device.
+        # kernel tier (r24): "pallas" routes the whole-plane scans
+        # (rowcounts-batch/-delta, count-batch) through the
+        # Pallas/Mosaic kernels; "xla" (default) is the proven path
+        # and stays the correctness oracle + the governor's degraded
+        # fallback.  Asking for "pallas" where it cannot run is a
+        # start-up error, never a quiet XLA serve: Mosaic only targets
+        # TPU (the TEST-ONLY escape hatch PILOSA_PALLAS_INTERPRET=1
+        # runs the same kernels through the pallas interpreter so
+        # tier-1 can pin bit-exactness without a device), and a Mosaic
+        # call cannot be auto-partitioned over a mesh placement until
+        # the kernels are wrapped in shard_map.
         self.kernel_tier = kernel_tier
         self._pallas_interpret = False
-        tier = "xla"
+        if kernel_tier not in ("xla", "pallas"):
+            raise ValueError(
+                f"kernel_tier: expected 'xla' or 'pallas', got "
+                f"{kernel_tier!r}")
         if kernel_tier == "pallas":
-            if jax.default_backend() == "tpu":
-                tier = "pallas"
-            elif os.environ.get("PILOSA_PALLAS_INTERPRET",
-                                "") not in ("", "0", "false"):
-                tier = "pallas"
+            if mesh_guard:
+                raise ValueError(
+                    "kernel_tier='pallas' cannot serve a mesh placement "
+                    "(Mosaic kernels are not auto-partitioned); set "
+                    "mesh=false or kernel_tier='xla'")
+            if os.environ.get("PILOSA_PALLAS_INTERPRET",
+                              "") not in ("", "0", "false"):
                 self._pallas_interpret = True
-            else:
-                self._stats.count("pallas_fallback_total", 1,
-                                  reason="backend")
-        self._tier = tier
+            elif jax.default_backend() != "tpu":
+                raise ValueError(
+                    "kernel_tier='pallas' needs the TPU backend, found "
+                    f"{jax.default_backend()!r}")
         # tier token appended to pallas-built program keys (like
         # sharding_key: same shape, different tier = different program);
         # xla keys stay byte-identical to the pre-tier key space
         self._tier_tok = ((("pallas-interpret" if self._pallas_interpret
-                            else "pallas"),) if tier == "pallas" else ())
+                            else "pallas"),)
+                          if kernel_tier == "pallas" else ())
         self._pallas_bad: set = set()   # (family, shape) lowering fails
         self.pallas_fallbacks = 0
 
@@ -331,7 +341,7 @@ class FusedCache:
     def effective_tier(self) -> str:
         """The tier actually serving: "xla", "pallas", or
         "pallas-interpret" (the test escape hatch)."""
-        if self._tier == "pallas":
+        if self.kernel_tier == "pallas":
             return ("pallas-interpret" if self._pallas_interpret
                     else "pallas")
         return "xla"
@@ -343,20 +353,30 @@ class FusedCache:
     # -- kernel-tier routing (r24) ---------------------------------------
 
     def _pallas_ok(self, sig) -> bool:
-        return self._tier == "pallas" and sig not in self._pallas_bad
+        return (self.kernel_tier == "pallas"
+                and sig not in self._pallas_bad)
 
     def _pallas_failed(self, sig, exc) -> None:
+        """A pallas-tier program failed to lower/compile/run for
+        ``sig``: that ``(family, shape)`` serves XLA from here on.
+        Logged ONCE per sig (``_pallas_bad`` gates re-entry) at ERROR
+        with the compiler's own message, and counted — chip_smoke.py
+        treats a non-zero ``pallas_fallback_total`` as fatal."""
         self._pallas_bad.add(sig)
         self.pallas_fallbacks += 1
         self._stats.count("pallas_fallback_total", 1, reason="lowering")
         self.flight.record("pallas_fallback", str(sig[0]),
                            type(exc).__name__)
+        _log.error("pallas tier: %s %s failed, serving XLA for this "
+                   "shape: %s: %s", sig[0], sig[1],
+                   type(exc).__name__, exc)
 
     def _tier_run(self, sig, dispatch):
         """Dispatch through the pallas tier when it covers ``sig`` (a
         ``(family, plane shape)`` pair); a Mosaic lowering failure
-        marks the shape bad, counts ``pallas_fallback_total``, and
-        silently re-dispatches the XLA-tier program."""
+        marks the shape bad, logs and counts it (see
+        :meth:`_pallas_failed`), and re-dispatches the XLA-tier
+        program."""
         if self._pallas_ok(sig):
             try:
                 return dispatch(True)
@@ -365,17 +385,6 @@ class FusedCache:
             except Exception as e:  # noqa: BLE001 — lowering/compile
                 self._pallas_failed(sig, e)
         return dispatch(False)
-
-    def _sel_kernel(self, pallas: bool, sorted_idx: bool):
-        """The selected-row gather base kernel for one tier: ``(plane,
-        idx) → int32[S, N]``."""
-        if pallas:
-            from pilosa_tpu.engine import pallas_kernels
-            interp = self._pallas_interpret
-            return lambda p, ix: pallas_kernels.selected_row_counts(
-                p, ix, interpret=interp)
-        return lambda p, ix: kernels.selected_row_counts(
-            p, ix, sorted_idx=sorted_idx)
 
     def _rc_kernel(self, pallas: bool):
         """The whole-plane row-counts base kernel for one tier:
@@ -515,8 +524,7 @@ class FusedCache:
     def run_count_batch(self, nodes: tuple, leaves, scratch=None):
         """K Count trees in ONE program: returns int32[K, n_shards] —
         one dispatch and one host read amortize fixed per-read costs
-        across every Count in the request (critical on transports with
-        a per-read floor; see BASELINE.md).  ``scratch`` (r17): a
+        across every Count in the request.  ``scratch`` (r17): a
         retired int32[K, n_shards] output to donate for the
         chained-dispatch form."""
         n_leaves = len(leaves)
@@ -634,65 +642,54 @@ class FusedCache:
         donate_ok = (scratch is not None
                      and tuple(scratch.shape) == (bucket,))
         if delta is not None:
-            def dispatch(pallas: bool):
-                key = self._selcounts_delta_key(
-                    plane.shape, sharding_key(plane), bucket,
-                    delta.rows.shape[0], sorted_idx, donate_ok, pallas)
-                build = self._selcounts_delta_build(sorted_idx, pallas)
-                args = (plane, idx, delta.rows, delta.words, delta.vals)
-                if donate_ok:
-                    return self._cached(key, build,
-                                        donate=(5,))(*args, scratch)
-                return self._cached(key, build)(*args)
-
-            return self._tier_run(("selcounts", plane.shape), dispatch)
-
-        def dispatch(pallas: bool):
-            key = self._selcounts_key(plane.shape, sharding_key(plane),
-                                      bucket, sorted_idx, donate_ok,
-                                      pallas)
-            build = self._selcounts_build(sorted_idx, pallas)
+            key = self._selcounts_delta_key(
+                plane.shape, sharding_key(plane), bucket,
+                delta.rows.shape[0], sorted_idx, donate_ok)
+            build = self._selcounts_delta_build(sorted_idx)
+            args = (plane, idx, delta.rows, delta.words, delta.vals)
             if donate_ok:
                 return self._cached(key, build,
-                                    donate=(2,))(plane, idx, scratch)
-            return self._cached(key, build)(plane, idx)
-
-        return self._tier_run(("selcounts", plane.shape), dispatch)
+                                    donate=(5,))(*args, scratch)
+            return self._cached(key, build)(*args)
+        key = self._selcounts_key(plane.shape, sharding_key(plane),
+                                  bucket, sorted_idx, donate_ok)
+        build = self._selcounts_build(sorted_idx)
+        if donate_ok:
+            return self._cached(key, build,
+                                donate=(2,))(plane, idx, scratch)
+        return self._cached(key, build)(plane, idx)
 
     # selcounts key/build helpers: SHARED between the serving path and
     # the warm-up ladder (warm_delta_ladder), so a warmed program IS
     # the serving program — the two can never drift apart on key shape
 
     def _selcounts_key(self, shape, shard, bucket, sorted_idx,
-                       donate_ok, pallas: bool):
-        tok = self._tier_tok if pallas else ()
+                       donate_ok):
         return (("selcounts", shape, shard, bucket, sorted_idx,
-                 donate_ok) + tok, "count")
+                 donate_ok), "count")
 
-    def _selcounts_build(self, sorted_idx: bool, pallas: bool):
-        sel = self._sel_kernel(pallas, sorted_idx)
-
+    def _selcounts_build(self, sorted_idx: bool):
         def build():
             def program(p, ix, *sc):
-                return jnp.sum(sel(p, ix), axis=0, dtype=jnp.int32)
+                return jnp.sum(
+                    kernels.selected_row_counts(p, ix,
+                                                sorted_idx=sorted_idx),
+                    axis=0, dtype=jnp.int32)
             return program
         return build
 
     def _selcounts_delta_key(self, shape, shard, bucket, dbucket,
-                             sorted_idx, donate_ok, pallas: bool):
-        tok = self._tier_tok if pallas else ()
+                             sorted_idx, donate_ok):
         return (("selcounts-delta", shape, shard, bucket, dbucket,
-                 sorted_idx, donate_ok) + tok, "count")
+                 sorted_idx, donate_ok), "count")
 
-    def _selcounts_delta_build(self, sorted_idx: bool, pallas: bool):
+    def _selcounts_delta_build(self, sorted_idx: bool):
         from pilosa_tpu.ingest.delta import adjusted_selected_counts
-        sel = self._sel_kernel(pallas, sorted_idx) if pallas else None
 
         def build():
             def program(p, ix, dr, dw, dv, *sc):
                 return adjusted_selected_counts(
-                    p, ix, dr, dw, dv, sorted_idx=sorted_idx,
-                    selected_fn=sel)
+                    p, ix, dr, dw, dv, sorted_idx=sorted_idx)
             return program
         return build
 
@@ -729,71 +726,67 @@ class FusedCache:
         same_plane = all(p is planes[0] for p in planes)
         shape, shard = planes[0].shape, sharding_key(planes[0])
 
-        def dispatch(pallas: bool):
-            from pilosa_tpu.ingest.delta import adjusted_selected_counts
-            tok = self._tier_tok if pallas else ()
-            key = (("selcounts-loop", shape, shard, k_pad, bucket,
-                    dbucket, sorted_idx, same_plane) + tok, "count")
-            sel = self._sel_kernel(pallas, sorted_idx)
-            sel_fn = sel if pallas else None
-            if same_plane and has_delta:
-                drs = jnp.stack([d.rows for d in deltas])
-                dws = jnp.stack([d.words for d in deltas])
-                dvs = jnp.stack([d.vals for d in deltas])
+        from pilosa_tpu.ingest.delta import adjusted_selected_counts
+        key = (("selcounts-loop", shape, shard, k_pad, bucket,
+                dbucket, sorted_idx, same_plane), "count")
 
-                def build():
-                    def program(p, ix, dr, dw, dv):
-                        def step(c, xs):
-                            ixj, drj, dwj, dvj = xs
-                            return c, adjusted_selected_counts(
-                                p, ixj, drj, dwj, dvj,
-                                sorted_idx=sorted_idx,
-                                selected_fn=sel_fn)
-                        _, outs = jax.lax.scan(step, 0,
-                                               (ix, dr, dw, dv))
-                        return outs
-                    return program
-                return self._cached(key, build)(planes[0], idx,
-                                                drs, dws, dvs)
-            if same_plane:
-                def build():
-                    def program(p, ix):
-                        def step(c, ixj):
-                            return c, jnp.sum(sel(p, ixj), axis=0,
-                                              dtype=jnp.int32)
-                        _, outs = jax.lax.scan(step, 0, ix)
-                        return outs
-                    return program
-                return self._cached(key, build)(planes[0], idx)
-            if has_delta:
-                def build():
-                    def program(ix, *rest):
-                        ps = rest[:k_pad]
-                        outs = []
-                        for j in range(k_pad):
-                            dr, dw, dv = rest[k_pad + 3 * j:
-                                              k_pad + 3 * j + 3]
-                            outs.append(adjusted_selected_counts(
-                                ps[j], ix[j], dr, dw, dv,
-                                sorted_idx=sorted_idx,
-                                selected_fn=sel_fn))
-                        return jnp.stack(outs)
-                    return program
-                args = [idx] + list(planes)
-                for d in deltas:
-                    args += [d.rows, d.words, d.vals]
-                return self._cached(key, build)(*args)
+        def sel(p, ix):
+            return kernels.selected_row_counts(p, ix,
+                                               sorted_idx=sorted_idx)
+
+        if same_plane and has_delta:
+            drs = jnp.stack([d.rows for d in deltas])
+            dws = jnp.stack([d.words for d in deltas])
+            dvs = jnp.stack([d.vals for d in deltas])
 
             def build():
-                def program(ix, *ps):
-                    return jnp.stack([
-                        jnp.sum(sel(ps[j], ix[j]), axis=0,
-                                dtype=jnp.int32)
-                        for j in range(k_pad)])
+                def program(p, ix, dr, dw, dv):
+                    def step(c, xs):
+                        ixj, drj, dwj, dvj = xs
+                        return c, adjusted_selected_counts(
+                            p, ixj, drj, dwj, dvj,
+                            sorted_idx=sorted_idx)
+                    _, outs = jax.lax.scan(step, 0, (ix, dr, dw, dv))
+                    return outs
                 return program
-            return self._cached(key, build)(idx, *planes)
+            return self._cached(key, build)(planes[0], idx,
+                                            drs, dws, dvs)
+        if same_plane:
+            def build():
+                def program(p, ix):
+                    def step(c, ixj):
+                        return c, jnp.sum(sel(p, ixj), axis=0,
+                                          dtype=jnp.int32)
+                    _, outs = jax.lax.scan(step, 0, ix)
+                    return outs
+                return program
+            return self._cached(key, build)(planes[0], idx)
+        if has_delta:
+            def build():
+                def program(ix, *rest):
+                    ps = rest[:k_pad]
+                    outs = []
+                    for j in range(k_pad):
+                        dr, dw, dv = rest[k_pad + 3 * j:
+                                          k_pad + 3 * j + 3]
+                        outs.append(adjusted_selected_counts(
+                            ps[j], ix[j], dr, dw, dv,
+                            sorted_idx=sorted_idx))
+                    return jnp.stack(outs)
+                return program
+            args = [idx] + list(planes)
+            for d in deltas:
+                args += [d.rows, d.words, d.vals]
+            return self._cached(key, build)(*args)
 
-        return self._tier_run(("selcounts", shape), dispatch)
+        def build():
+            def program(ix, *ps):
+                return jnp.stack([
+                    jnp.sum(sel(ps[j], ix[j]), axis=0,
+                            dtype=jnp.int32)
+                    for j in range(k_pad)])
+            return program
+        return self._cached(key, build)(idx, *planes)
 
     def run_rowcounts_delta(self, plane, delta, filter_words=None,
                             reduce: bool = True) -> jax.Array:
@@ -903,8 +896,7 @@ class FusedCache:
                 (plane_av, dr, dw, dv) + ((flt_av,) if has_filter
                                           else ()),
                 ()))
-        sig = ("selcounts", tuple(shape))
-        pall = self._pallas_ok(sig)
+        sig = None  # the selected-row gather has no pallas form
         b = self.WARM_SLOT_BUCKET
         ix_av, scr_av = sds((b,), jnp.int32), sds((b,), jnp.int32)
         for donate_ok in (False, True):
@@ -912,8 +904,8 @@ class FusedCache:
                 sig,
                 self._selcounts_delta_key(tuple(shape), shard, b,
                                           overlay_bucket, True,
-                                          donate_ok, pall),
-                self._selcounts_delta_build(True, pall),
+                                          donate_ok),
+                self._selcounts_delta_build(True),
                 (plane_av, ix_av, dr, dw, dv) + ((scr_av,)
                                                  if donate_ok else ()),
                 (5,) if donate_ok else ()))
@@ -935,7 +927,7 @@ class FusedCache:
             try:
                 dt = self._warm_insert(key, build, avatars, donate)
             except Exception as e:  # noqa: BLE001 — lowering/compile
-                if self._pallas_ok(sig):
+                if sig is not None and self._pallas_ok(sig):
                     self._pallas_failed(sig, e)
                     retry = True
                 continue
@@ -1185,8 +1177,8 @@ class FusedCache:
         """Concatenate the flattened int32 outputs of a collection
         window's programs into ONE device array — the whole window
         then costs a single device->host read instead of one per
-        kind/shape group (on transports with a fixed per-read RPC
-        floor, the read count IS the serving floor; BASELINE.md).
+        kind/shape group (each read has a fixed cost, so the read
+        count sets the serving floor).
         ``scratch`` (r17): a retired packed output of the same total
         size to donate — consecutive windows of the same shape mix
         ping-pong through two standing packed buffers instead of

@@ -326,24 +326,19 @@ def overlay_row(val: jax.Array, slot, d_rows: jax.Array,
 def adjusted_selected_counts(plane: jax.Array, row_idx: jax.Array,
                              d_rows: jax.Array, d_words: jax.Array,
                              d_vals: jax.Array,
-                             sorted_idx: bool = False,
-                             selected_fn=None) -> jax.Array:
+                             sorted_idx: bool = False) -> jax.Array:
     """Selected-row popcounts of base⊕delta, shard axis reduced on
     device: int32[N] for ``row_idx`` int32[N] (plane row slots, the
     multi-query fused gather).  Each overlay cell contributes its diff
     to EVERY matching output lane (duplicate slots answer
     independently, like the clean gather).  ``sorted_idx``: the static
     ascending-stride gather promise (see
-    ``kernels.selected_row_counts``).  ``selected_fn`` swaps the base
-    gather kernel ``(plane, row_idx) → int32[S, N]`` (the pallas
-    serving tier) — the overlay adjustment traces into the same jit,
-    so base⊕delta stays one program."""
+    ``kernels.selected_row_counts``)."""
     from pilosa_tpu.engine import kernels
-    sel_fn = (selected_fn if selected_fn is not None else
-              lambda p, ix: kernels.selected_row_counts(
-                  p, ix, sorted_idx=sorted_idx))
-    sel = jnp.sum(sel_fn(plane, row_idx),
-                  axis=-2, dtype=jnp.int32)              # int32[N]
+    sel = jnp.sum(
+        kernels.selected_row_counts(plane, row_idx,
+                                    sorted_idx=sorted_idx),
+        axis=-2, dtype=jnp.int32)                        # int32[N]
     diff, slot = _cell_diffs(plane, d_rows, d_words, d_vals, None)
     match = slot[:, None] == row_idx[None, :]            # [C_pad, N]
     add = jnp.sum(jnp.where(match, diff[:, None], 0), axis=0,

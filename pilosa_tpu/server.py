@@ -65,31 +65,6 @@ class PilosaTPUServer:
                             if self.cfg.jax_process_id >= 0 else None))
             self.logger.info("jax.distributed: process %d of %d",
                              jax.process_index(), jax.process_count())
-        if self.cfg.compilation_cache_dir:
-            # persistent XLA compilation cache: a warm restart reloads
-            # compiled programs from disk instead of paying the ~1 s
-            # first-query compile (BENCH_r05).  Thresholds drop to
-            # zero so the handful of serving programs always persist.
-            import os as _os
-
-            import jax
-            cache_dir = _os.path.expanduser(self.cfg.compilation_cache_dir)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            # the cache singleton latches its directory on first use:
-            # drop any instance initialized before this config landed
-            # (library embedders may have compiled already).  Private
-            # API — a jax that moved it degrades to a cold compile,
-            # never a failed boot.
-            try:
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except (ImportError, AttributeError):
-                pass
-            self.logger.info("compilation cache: %s", cache_dir)
         from pilosa_tpu.store import syswrap
         syswrap.GLOBAL.set_max(self.cfg.max_map_count)
         # disk-health governor (r19): wire stats + knobs BEFORE the
@@ -129,6 +104,7 @@ class PilosaTPUServer:
             kernel_tier=self.cfg.kernel_tier,
             dispatch_loop_fusion=self.cfg.dispatch_loop_fusion,
             fused_warmup=self.cfg.fused_warmup)
+        self._log_boot(placement)
         self.api = API(self.holder, self.executor,
                        query_timeout=self.cfg.query_timeout,
                        trace_sample_rate=self.cfg.trace_sample_rate,
@@ -186,6 +162,36 @@ class PilosaTPUServer:
             slow_log=self.api.slow_log,
             executor=self.executor).start()
         return self
+
+    def _log_boot(self, placement) -> None:
+        """One line naming what this process actually serves on — the
+        facts a fallback would otherwise hide (chip_smoke.py and the
+        operator read them here instead of assuming)."""
+        import importlib.metadata as md
+
+        import jax
+
+        from pilosa_tpu.engine import _jaxcfg
+        from pilosa_tpu.store import native
+
+        def version(pkg: str) -> str:
+            try:
+                return md.version(pkg)
+            except md.PackageNotFoundError:
+                return "absent"
+
+        devs = jax.devices()
+        self.logger.info(
+            "boot: platform=%s device_kind=%r devices=%d serving=%s "
+            "kernel_tier=%s native_codec=%s compile_cache=%s "
+            "jax=%s jaxlib=%s libtpu=%s",
+            devs[0].platform, devs[0].device_kind, len(devs),
+            (f"mesh({placement.n_devices})" if placement is not None
+             else "single"),
+            self.executor.fused.effective_tier,
+            "loaded" if native.available() else "python-fallback",
+            _jaxcfg.compile_cache_dir(),
+            version("jax"), version("jaxlib"), version("libtpu"))
 
     def close(self) -> None:
         if self.diagnostics is not None:

@@ -2,8 +2,8 @@
 
 The native slot of SURVEY.md §3.4: fragment blob parse/serialize and
 dense-plane expansion in C++ at memory bandwidth.  Byte-compatible with
-the pure-Python codec in :mod:`pilosa_tpu.store.roaring`, which remains
-the always-available fallback (``PILOSA_NO_NATIVE=1`` forces it).
+the pure-Python codec in :mod:`pilosa_tpu.store.roaring`, which serves
+when the library is not built (``PILOSA_NO_NATIVE=1`` forces it).
 
 Build: ``make -C native`` → ``native/libroaring_codec.so``.
 """
@@ -25,16 +25,25 @@ _ERRORS = {-1: "truncated buffer", -2: "bad magic/version",
 
 
 def _load():
+    """The codec library, or None when it is not built (the server's
+    boot log says which codec serves).  A library that IS there but
+    cannot be used — built for another machine, or older than this
+    module's symbol list — raises: serving on the Python codec beside
+    a stale ``.so`` is a slowdown nobody asked for."""
     if os.environ.get("PILOSA_NO_NATIVE"):
         return None
     if not os.path.exists(_LIB_PATH):
         return None
-    # an older .so may lack newer symbols: AttributeError below must
-    # also mean "fall back to Python", not a hard import crash
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        return None
+        return _bind(ctypes.CDLL(_LIB_PATH))
+    except (OSError, AttributeError) as e:
+        raise ImportError(
+            f"{_LIB_PATH} is present but unusable ({e}); rebuild it "
+            "with `make -C native`, or delete it / set "
+            "PILOSA_NO_NATIVE=1 to serve on the Python codec") from e
+
+
+def _bind(lib):
     u8p = ctypes.POINTER(ctypes.c_uint8)
     u32p = ctypes.POINTER(ctypes.c_uint32)
     u64p = ctypes.POINTER(ctypes.c_uint64)
@@ -69,10 +78,7 @@ def _load():
     return lib
 
 
-try:
-    _lib = _load()
-except AttributeError:  # stale .so missing newer symbols
-    _lib = None
+_lib = _load()
 
 
 def available() -> bool:

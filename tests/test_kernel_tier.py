@@ -1,10 +1,11 @@
 """Kernel-tier integration tests (r24): the ``kernel_tier="pallas"``
 serving tier must be bit-exact against the XLA oracle tier THROUGH the
 executor and batcher — clean planes, delta overlays under interleaved
-ingest, governor-degraded fallback, and silent XLA fallback on a
-lowering failure.  On CPU the pallas tier runs interpret-mode via the
-test-only ``PILOSA_PALLAS_INTERPRET`` escape hatch; real selection
-gates on a TPU backend.  Also covers the r24 dispatch-loop fusion
+ingest, governor-degraded fallback, and a LOUD (logged with the
+compiler's message, counted) XLA fallback on a lowering failure.  On
+CPU the pallas tier runs interpret-mode via the test-only
+``PILOSA_PALLAS_INTERPRET`` escape hatch; without it, asking for the
+tier where it cannot run raises at ``Executor`` construction.  Also covers the r24 dispatch-loop fusion
 (one jitted loop per same-shape window) and the compile-ladder
 warm-up (zero serving-path compiles after ingest).
 """
@@ -57,16 +58,28 @@ class TestTierResolution:
         assert ex.fused.kernel_tier == "xla"
         assert ex.fused.effective_tier == "xla"
 
-    def test_pallas_on_cpu_falls_back_to_xla(self, tmp_path, monkeypatch):
-        # no TPU backend and no interpret escape hatch: the tier
-        # resolves to xla SILENTLY, with the fallback counted
+    def test_pallas_off_tpu_raises_at_construction(self, tmp_path,
+                                                   monkeypatch):
+        # no TPU backend and no interpret escape hatch: a start-up
+        # error naming the backend, never a quiet XLA serve
         monkeypatch.delenv("PILOSA_PALLAS_INTERPRET", raising=False)
-        stats = Stats()
-        ex = make_env(tmp_path, "x", stats=stats, kernel_tier="pallas")
-        assert ex.fused.effective_tier == "xla"
-        fb = stats.snapshot()["counters"].get("pallas_fallback_total", {})
-        assert sum(fb.values()) == 1
-        assert any("backend" in str(k) for k in fb)
+        with pytest.raises(ValueError, match="needs the TPU backend.*cpu"):
+            make_env(tmp_path, "x", kernel_tier="pallas")
+
+    def test_pallas_with_placement_raises_at_construction(
+            self, tmp_path, monkeypatch):
+        # Mosaic calls cannot be auto-partitioned over a mesh: until
+        # the kernels are wrapped in shard_map, the combination is a
+        # start-up error even where the tier itself could run
+        from pilosa_tpu.parallel import MeshPlacement
+        monkeypatch.setenv("PILOSA_PALLAS_INTERPRET", "1")
+        with pytest.raises(ValueError, match="mesh placement"):
+            make_env(tmp_path, "x", kernel_tier="pallas",
+                     placement=MeshPlacement())
+
+    def test_unknown_tier_raises(self, tmp_path):
+        with pytest.raises(ValueError, match="kernel_tier"):
+            make_env(tmp_path, "x", kernel_tier="mosaic")
 
     def test_interpret_escape_hatch(self, tmp_path, monkeypatch):
         monkeypatch.setenv("PILOSA_PALLAS_INTERPRET", "1")
@@ -132,33 +145,48 @@ class TestTierParity:
 
 
 class TestLoweringFallback:
-    def test_silent_xla_fallback_and_counter(self, tmp_path, monkeypatch):
+    def test_loud_xla_fallback_and_counter(self, tmp_path, monkeypatch):
+        import logging
         monkeypatch.setenv("PILOSA_PALLAS_INTERPRET", "1")
         stats = Stats()
         ex = make_env(tmp_path, "p", stats=stats, kernel_tier="pallas")
         seed(ex)
-        # residency: Count(Row(f=..)) routes through the selected-row
-        # gather family only once the whole-field plane is resident
-        ex.execute("i", "TopN(f, n=3)")
+        want = make_env(tmp_path, "x")
+        seed(want)
 
         from pilosa_tpu.engine import pallas_kernels
 
         def boom(*a, **kw):
             raise RuntimeError("Mosaic lowering failed (simulated)")
 
-        monkeypatch.setattr(pallas_kernels, "selected_row_counts", boom)
-        # the query still answers — the family silently re-dispatches
-        # through the XLA oracle program — and the fallback is counted
-        assert ex.execute("i", "Count(Row(f=1))") == [12]
-        assert ex.fused.pallas_fallbacks >= 1
+        monkeypatch.setattr(pallas_kernels, "row_counts", boom)
+        errors = []
+
+        class Collect(logging.Handler):
+            def emit(self, record):
+                errors.append(record.getMessage())
+
+        handler = Collect(level=logging.ERROR)
+        log = logging.getLogger("pilosa_tpu.exec")
+        log.addHandler(handler)
+        try:
+            # the query still answers — the family re-dispatches
+            # through the XLA oracle program — but the failure is
+            # counted AND logged with the compiler's own message
+            assert ex.execute("i", "TopN(f, n=3)") == \
+                want.execute("i", "TopN(f, n=3)")
+            # the shape is marked bad: the next serve skips pallas
+            # without re-failing (no new fallback tick, no new line)
+            ex.execute("i", "TopN(f, n=2)")
+        finally:
+            log.removeHandler(handler)
+        assert ex.fused.pallas_fallbacks == 1
         fb = stats.snapshot()["counters"].get("pallas_fallback_total", {})
-        assert sum(fb.values()) >= 1
+        assert sum(fb.values()) == 1
         assert any("lowering" in str(k) for k in fb)
-        # the shape is marked bad: subsequent serves skip pallas
-        # without re-failing (no new fallback ticks)
-        before = ex.fused.pallas_fallbacks
-        assert ex.execute("i", "Count(Row(f=2))") == [12]
-        assert ex.fused.pallas_fallbacks == before
+        assert len(errors) == 1
+        assert "Mosaic lowering failed (simulated)" in errors[0]
+        assert "rowcounts" in errors[0]
 
 
 class TestLoopFusion:

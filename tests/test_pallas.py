@@ -1,5 +1,8 @@
-"""Pallas kernel tests (interpreter mode on CPU) against numpy oracles
-and the XLA kernels — same-answer guarantees for the hot-loop variants."""
+"""Pallas kernel tests: interpreter mode on CPU against numpy oracles
+and the XLA kernels (same-answer guarantees for the hot-loop variants),
+plus a chip-less TPU lowering of every kernel at serving shapes — the
+interpreter accepts block shapes Mosaic rejects, so parity alone never
+showed whether a kernel could run on the device it was written for."""
 
 import numpy as np
 import pytest
@@ -23,9 +26,7 @@ class TestSwarPopcount:
         import jax.numpy as jnp
         x = rng.integers(0, 1 << 32, size=(64,), dtype=np.uint32)
         got = np.asarray(pallas_kernels._popcount_u32(jnp.asarray(x)))
-        expect = np.bitwise_count(x).astype(np.int32) \
-            if hasattr(np, "bitwise_count") else \
-            np.array([bin(v).count("1") for v in x], np.int32)
+        expect = np.bitwise_count(x).astype(np.int32)
         np.testing.assert_array_equal(got, expect)
 
     def test_edges(self):
@@ -33,24 +34,6 @@ class TestSwarPopcount:
         x = jnp.asarray(np.array([0, 1, 0xFFFFFFFF, 0x80000000], np.uint32))
         np.testing.assert_array_equal(
             np.asarray(pallas_kernels._popcount_u32(x)), [0, 1, 32, 1])
-
-
-class TestIntersectCount:
-    def test_matches_xla_kernel(self, rng):
-        a = rng.integers(0, 1 << 32, size=(5, W), dtype=np.uint32)
-        b = rng.integers(0, 1 << 32, size=(5, W), dtype=np.uint32)
-        got = np.asarray(pallas_kernels.intersect_count(a, b,
-                                                        interpret=True))
-        expect = np.asarray(kernels.intersection_count(a, b))
-        np.testing.assert_array_equal(got, expect)
-
-    def test_sparse_rows(self, rng):
-        cols_a = rng.choice(W * 32, 500, replace=False)
-        cols_b = rng.choice(W * 32, 500, replace=False)
-        a = pack_columns(cols_a, n_words=W)[None, :]
-        b = pack_columns(cols_b, n_words=W)[None, :]
-        got = int(pallas_kernels.intersect_count(a, b, interpret=True)[0])
-        assert got == len(np.intersect1d(cols_a, cols_b))
 
 
 class TestRowCounts:
@@ -82,11 +65,7 @@ class TestRowCounts:
 
 
 def _np_popcount(words):
-    if hasattr(np, "bitwise_count"):
-        return np.bitwise_count(words).astype(np.int64)
-    return np.unpackbits(
-        words.view(np.uint8), bitorder="little").reshape(
-        *words.shape, 32).sum(-1).astype(np.int64)
+    return np.bitwise_count(words).astype(np.int64)
 
 
 class TestCount:
@@ -112,45 +91,6 @@ class TestCount:
             np.zeros(3, np.int32))
 
 
-class TestSelectedRowCounts:
-    """Selected-row gather scan vs kernels.selected_row_counts — the
-    sorted-slot contract the fused serving tier relies on."""
-
-    @pytest.mark.parametrize("shape,n_sel", [
-        ((2, 8, 64), 3), ((3, 10, 160), 4), ((2, 7, 1300), 5),
-        ((4, 16, 2048), 8)])
-    def test_parity_sweep(self, rng, shape, n_sel):
-        plane = rng.integers(0, 1 << 32, size=shape, dtype=np.uint32)
-        idx = np.sort(rng.choice(shape[1], n_sel, replace=False))
-        idx = idx.astype(np.int32)
-        got = np.asarray(pallas_kernels.selected_row_counts(
-            plane, idx, interpret=True))
-        expect = np.asarray(kernels.selected_row_counts(
-            plane, idx, sorted_idx=True))
-        np.testing.assert_array_equal(got, expect)
-        np.testing.assert_array_equal(
-            got.astype(np.int64), _np_popcount(plane[:, idx]).sum(-1))
-
-    def test_repeated_slots(self, rng):
-        # padded slot lists repeat the last slot — the contract the
-        # batcher's loop-fused dispatch pads with
-        plane = rng.integers(0, 1 << 32, size=(2, 6, 128), dtype=np.uint32)
-        idx = np.array([1, 4, 4, 4], np.int32)
-        got = np.asarray(pallas_kernels.selected_row_counts(
-            plane, idx, interpret=True))
-        np.testing.assert_array_equal(
-            got, np.asarray(kernels.selected_row_counts(
-                plane, idx, sorted_idx=True)))
-
-    def test_all_ones_rows(self):
-        plane = np.zeros((1, 5, 96), np.uint32)
-        plane[0, 2] = 0xFFFFFFFF
-        idx = np.array([0, 2], np.int32)
-        got = np.asarray(pallas_kernels.selected_row_counts(
-            plane, idx, interpret=True))
-        np.testing.assert_array_equal(got, [[0, 96 * 32]])
-
-
 class TestRandomizedParity:
     """Randomized sweep across awkward (non-pow2, non-block-aligned)
     shapes — every pallas kernel vs its XLA oracle on the same draw."""
@@ -170,14 +110,6 @@ class TestRandomizedParity:
             np.testing.assert_array_equal(
                 np.asarray(pallas_kernels.count(filt, interpret=True)),
                 np.asarray(kernels.count(filt)))
-            n_sel = int(rng.integers(1, r + 1))
-            idx = np.sort(rng.choice(r, n_sel, replace=False)) \
-                .astype(np.int32)
-            np.testing.assert_array_equal(
-                np.asarray(pallas_kernels.selected_row_counts(
-                    plane, idx, interpret=True)),
-                np.asarray(kernels.selected_row_counts(
-                    plane, idx, sorted_idx=True)))
 
     def test_empty_filter(self, rng):
         plane = rng.integers(0, 1 << 32, size=(2, 5, 96), dtype=np.uint32)
@@ -185,3 +117,76 @@ class TestRandomizedParity:
         got = np.asarray(pallas_kernels.row_counts(plane, filt,
                                                    interpret=True))
         np.testing.assert_array_equal(got, np.zeros((2, 5), np.int32))
+
+
+class TestEdgeBlocks:
+    """No operand is padded: shard/row edge blocks read past the array
+    into outputs that are dropped, and the word-axis edge block is
+    masked in the kernel.  Shapes chosen so every axis has a ragged
+    last block at once."""
+
+    def test_every_axis_ragged(self, rng):
+        s, r, w = 11, 130, pallas_kernels._WB * 2 + 96
+        plane = rng.integers(0, 1 << 32, size=(s, r, w), dtype=np.uint32)
+        filt = rng.integers(0, 1 << 32, size=(s, w), dtype=np.uint32)
+        np.testing.assert_array_equal(
+            np.asarray(pallas_kernels.row_counts(plane, interpret=True)),
+            _np_popcount(plane).sum(-1))
+        np.testing.assert_array_equal(
+            np.asarray(pallas_kernels.row_counts(plane, filt,
+                                                 interpret=True)),
+            _np_popcount(plane & filt[:, None, :]).sum(-1))
+
+    def test_count_ragged_word_blocks(self, rng):
+        words = rng.integers(0, 1 << 32,
+                             size=(11, pallas_kernels._CWB + 4101),
+                             dtype=np.uint32)
+        np.testing.assert_array_equal(
+            np.asarray(pallas_kernels.count(words, interpret=True)),
+            _np_popcount(words).sum(-1))
+
+
+class TestLowersForTpu:
+    """Every kernel in ``engine/pallas_kernels.py`` must get through
+    the Pallas→Mosaic lowering for the TPU platform — no chip and no
+    libtpu needed.  This is where an illegal block shape (a size-1
+    block in the second-minor position, a last dimension that is
+    neither 128-divisible nor the whole axis) is rejected; interpret
+    mode never checks it.  Shapes: the 1B-column serving plane
+    ``[954, 32, 32768]``, and one with R > 128 and W % 1024 != 0 so
+    the row grid and the masked word tail lower too."""
+
+    SHAPES = [(954, 32, 32768), (20, 130, 32768 + 96)]
+
+    @staticmethod
+    def _lower(fn, *avals):
+        import jax
+        return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+    def test_every_public_kernel_is_covered(self):
+        # the kernels are the module's public jitted callables
+        public = {n for n, f in vars(pallas_kernels).items()
+                  if not n.startswith("_") and hasattr(f, "lower")}
+        assert public == {"row_counts", "count"}, \
+            f"new kernel(s) {public} need a TPU lowering case below"
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_row_counts(self, shape):
+        import jax
+        import jax.numpy as jnp
+        s, r, w = shape
+        plane = jax.ShapeDtypeStruct((s, r, w), jnp.uint32)
+        filt = jax.ShapeDtypeStruct((s, w), jnp.uint32)
+        assert "tpu_custom_call" in self._lower(
+            pallas_kernels.row_counts, plane).as_text()
+        assert "tpu_custom_call" in self._lower(
+            pallas_kernels.row_counts, plane, filt).as_text()
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_count(self, shape):
+        import jax
+        import jax.numpy as jnp
+        s, _, w = shape
+        words = jax.ShapeDtypeStruct((s, w), jnp.uint32)
+        assert "tpu_custom_call" in self._lower(
+            pallas_kernels.count, words).as_text()

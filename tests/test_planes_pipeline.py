@@ -323,35 +323,50 @@ class TestWarmSidecarCache:
 
 
 class TestCompilationCache:
-    def test_server_wires_persistent_cache(self, tmp_path):
-        """compilation_cache_dir populates a reusable on-disk XLA
-        cache after the first query — the warm-restart compile skip."""
-        import jax
+    """The one cache rule (``engine/_jaxcfg.py``): an operator-placed
+    ``JAX_COMPILATION_CACHE_DIR`` wins and no code sets another
+    directory; otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``.  Decided at import, so each branch runs
+    in a fresh interpreter."""
 
-        from pilosa_tpu.cli.config import Config
-        from pilosa_tpu.server import PilosaTPUServer
+    PROBE = (
+        "import jax, jax.numpy as jnp\n"
+        "import pilosa_tpu.server\n"
+        "from pilosa_tpu.engine import _jaxcfg, kernels\n"
+        "int(kernels.count(jnp.ones((3, 64), jnp.uint32)).sum())\n"
+        "assert jax.config.jax_compilation_cache_dir == "
+        "_jaxcfg.compile_cache_dir()\n"
+        "print(_jaxcfg.compile_cache_dir())\n")
+
+    def _probe(self, env_dir):
+        import os
+        import subprocess
+        import sys
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_ENABLE_COMPILATION_CACHE="true")  # conftest: off
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+        out = subprocess.run([sys.executable, "-c", self.PROBE], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        return out.stdout.strip().splitlines()[-1]
+
+    def test_env_placed_cache_wins(self, tmp_path):
         cache_dir = tmp_path / "jaxcache"
-        prev = jax.config.jax_compilation_cache_dir
-        srv = PilosaTPUServer(Config(
-            bind="127.0.0.1:0", data_dir=str(tmp_path / "data"),
-            compilation_cache_dir=str(cache_dir), mesh=False)).open()
-        try:
-            assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-            # earlier tests may have warmed the in-process jit cache
-            # for this program shape; force a real compile so the
-            # persistent cache demonstrably populates
-            jax.clear_caches()
-            from pilosa_tpu.api import Client
-            c = Client("127.0.0.1", srv.port)
-            c.create_index("i")
-            c.create_field("i", "f")
-            c.query("i", "Set(1, f=10)")
-            assert c.query("i", "Count(Row(f=10))") == [1]
-            assert any(cache_dir.iterdir()), \
-                "first query must persist compiled programs"
-        finally:
-            srv.close()
-            jax.config.update("jax_compilation_cache_dir", prev)
+        assert self._probe(cache_dir) == str(cache_dir)
+        assert any(cache_dir.iterdir()), \
+            "the first compile must persist under the placed directory"
+
+    def test_default_is_fixed_under_checkout(self):
+        import os
+
+        import pilosa_tpu
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(pilosa_tpu.__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert self._probe(None) == want
+        assert os.listdir(want)
 
 
 class TestBuildFailureObservability:
