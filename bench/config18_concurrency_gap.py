@@ -11,9 +11,11 @@ measures it instead of guessing:
   program (device ceiling) and (b) the PRODUCT path (`API.query`),
   every product response oracle-verified;
 - print qps and the product/raw ratio per concurrency level;
-- dump the executor's per-stage timers (admit / parse / plan /
-  dispatch / read / assemble, ``query_stage_seconds``) per level, so
-  the residual gap is attributed per stage.
+- dump the request's per-stage clock (admit / plan_cache / parse /
+  plan / queue / dispatch / read / deliver / assemble,
+  ``query_stage_seconds``) per level, so the residual gap is
+  attributed per stage — the batcher's window wait (``queue``) apart
+  from the program enqueue and the device→host read.
 
 The r6 serving-spine work this config exists to measure: the query-plan
 cache (repeat shapes skip parse/plan), the default-on adaptive batcher
@@ -52,7 +54,8 @@ ITERS = 3 if SMOKE else 6
 WORDS = 32768  # words per shard (2^20 bits / 32)
 INDEX, FIELD = "i", "f"
 
-STAGES = ("admit", "parse", "plan", "dispatch", "read", "assemble")
+STAGES = ("admit", "plan_cache", "parse", "plan", "queue", "dispatch",
+          "read", "deliver", "assemble")
 
 
 def write_index(plane: np.ndarray, data_dir: str) -> None:

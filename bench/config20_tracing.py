@@ -16,10 +16,12 @@ call) twice —
   asserted while measuring.
 
 The acceptance bar: sampled-on throughput within 3% of tracing-off at
-the widest concurrency level (asserted in full runs; ``--smoke`` runs
-tiny planes on CPU where per-query fixed costs dominate and noise
-swamps a 3% bar, so smoke only sanity-bounds the ratio and asserts the
-tracing semantics).
+the widest concurrency level, asserted in full runs.  ``--smoke``
+asserts counts only — one JSON line, both tiers at every level, the
+sampler fired, the sampled trace resolvable in the ring: a speed ratio
+between two bursts on a CPU that the test shares with five other test
+workers proves nothing, and what the instrumentation costs is measured
+once, on the chip (PERF.md, PR 26).
 
 ``--smoke`` (or PILOSA_BENCH_SMOKE=1): 2 shards × 4 rows, sweep 1/2/4 —
 tier-1 runs it (tests/test_bench_smoke.py) so this bench can never
@@ -184,11 +186,9 @@ def main() -> None:
         # 0.41 exactly here: the default config materialized a span
         # tree per query regardless of the retention decision.
         # Interleaved best-of-5 bursts at the widest level filter
-        # scheduler noise; the smoke bar is noise-adjusted (toy-scale
-        # CPU bursts wander ±5%, and the r05 class measures ~0.5 at
-        # toy scale — 0.85 still catches it decisively) while full
-        # runs hold the 0.95 acceptance bar.
-        default_bar = 0.85 if SMOKE else 0.95
+        # scheduler noise; full runs hold the 0.95 acceptance bar,
+        # smoke runs one burst of each and reports the ratio unjudged.
+        default_bar = None if SMOKE else 0.95
         api_default = API(holder, executor, trace_sample_rate=0.01,
                           slow_query_threshold=1.0)
 
@@ -199,7 +199,7 @@ def main() -> None:
             return burst(call, top, ITERS * 3, N_ROWS)
 
         runs_off, runs_def = [], []
-        for _ in range(5):
+        for _ in range(1 if SMOKE else 5):
             runs_off.append(one(api_off))
             runs_def.append(one(api_default))
         best_off = max(runs_off)
@@ -208,7 +208,7 @@ def main() -> None:
         log(f"default-config tracing ratio at {top} clients: "
             f"{default_ratio:.3f} (default {best_def:,.1f} qps / off "
             f"{best_off:,.1f} qps; bar {default_bar})")
-        assert default_ratio >= default_bar, \
+        assert SMOKE or default_ratio >= default_bar, \
             (f"default tracing config serves {default_ratio:.2f}x of "
              f"tracing-off; the r05-regression pin is {default_bar}x")
         overhead = 1.0 - qps_on[top] / qps_off[top]
@@ -219,19 +219,9 @@ def main() -> None:
         log(f"tracing overhead at {top} clients: {overhead * 100:.2f}% "
             f"(off {qps_off[top]:,.1f} qps / on {qps_on[top]:,.1f} qps; "
             f"{sampled} traces retained)")
-        if SMOKE:
-            # toy scale: per-query fixed costs dominate and run-to-run
-            # noise exceeds the 3% bar — bound catastrophe only.  The
-            # r12 lite path widened the honest gap here (the off tier
-            # no longer builds trees at all while rate=1.0 builds one
-            # per query), so the catastrophe bound is 0.7; the real
-            # r05-class pin is default_ratio below
-            assert overhead < 0.7, \
-                f"smoke tracing overhead {overhead:.2%} is pathological"
-        else:
-            assert overhead < MAX_OVERHEAD, \
-                (f"sampled tracing costs {overhead:.2%} at {top} "
-                 f"clients; the r9 bar is {MAX_OVERHEAD:.0%}")
+        assert SMOKE or overhead < MAX_OVERHEAD, \
+            (f"sampled tracing costs {overhead:.2%} at {top} "
+             f"clients; the r9 bar is {MAX_OVERHEAD:.0%}")
         holder.close()
     finally:
         shutil.rmtree(data_dir, ignore_errors=True)

@@ -266,6 +266,7 @@ class API:
           PQL, shards and duration intact; full executor trees need
           sampling/profile/floor)."""
         from pilosa_tpu.obs import GLOBAL_TRACER, LiteTracer, Tracer
+        from pilosa_tpu.obs.metrics import current_timer
         from pilosa_tpu.obs.tracing import set_current_trace_id
         self._index(index)
         cap = self.query_timeout
@@ -280,8 +281,12 @@ class API:
         trace = (profile or sampled
                  or 0 < self.slow_query_threshold <= self.SLOW_TRACE_FLOOR)
         stats = self.executor.stats
+        timer = current_timer()  # the HTTP edge's stage clock, if any
         if not trace:
             tracer = LiteTracer()
+            if timer is not None:
+                timer.attach(tracer)
+                timer.enter("admit")
             # publish the id as this thread's ACTIVE trace id so log
             # lines emitted while serving join the query's exemplar
             # (one thread-local write — the lite path stays lite)
@@ -326,6 +331,9 @@ class API:
         t0 = _time.perf_counter()
         with tracer.span("query", index=index, node=node) as root:
             set_current_trace_id(root.trace_id)
+            if timer is not None:
+                timer.attach(tracer)
+                timer.enter("admit")
             try:
                 out, err = self._run_query(index, pql, shards, tracer,
                                            deadline, timeout, t0)

@@ -33,6 +33,9 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from pilosa_tpu import __version__, fault
 from pilosa_tpu.api.api import API, ApiError
+from pilosa_tpu.obs import metrics as _metrics
+from pilosa_tpu.obs.metrics import (StageTimer, enter_stage,
+                                    set_current_timer)
 from pilosa_tpu.store.health import StorageFaultError as _StorageFaultError
 
 
@@ -108,6 +111,13 @@ class Handler(BaseHTTPRequestHandler):
         if logger is not None:
             logger.debug("http: " + fmt % args)
 
+    def parse_request(self) -> bool:
+        # the request line has just been read off the socket: where a
+        # served query's stage clock starts (_dispatch), so that header
+        # parsing is http_in's and not nobody's
+        self._t_request = time.perf_counter()
+        return super().parse_request()
+
     def _body(self) -> bytes:
         # read-once, cached: _dispatch drains the body for EVERY
         # request — a handler that replies without reading it would
@@ -139,8 +149,10 @@ class Handler(BaseHTTPRequestHandler):
             # connection makes the client see a reset, not a timeout.
             self.close_connection = True
             return
+        enter_stage("encode")
         data = (obj if isinstance(obj, bytes)
                 else json.dumps(obj).encode())
+        enter_stage("http_out")
         self.send_response(status)
         self.send_header("Content-Type", content_type)
         for k, v in (headers or {}).items():
@@ -173,6 +185,15 @@ class Handler(BaseHTTPRequestHandler):
                               path=parsed.path)
             if spec is not None and spec["action"] == "drop_response":
                 self._fault_drop_response = True
+        # the stage clock of a served query starts at the socket (the
+        # request line's stamp): the executor and the batcher charge
+        # their stages to this timer (obs.metrics.StageTimer), _reply
+        # enters encode and http_out, the finally below closes it
+        timer = None
+        if fn is Handler.h_query:
+            timer = StageTimer(srv.api.executor.stats, "http_in",
+                               at=self._t_request)
+            set_current_timer(timer)
         t0 = time.perf_counter()
         code = 200
         try:
@@ -216,6 +237,11 @@ class Handler(BaseHTTPRequestHandler):
                             method=method, status=str(code))
                 stats.observe("http_request_seconds",
                               time.perf_counter() - t0, method=method)
+            if timer is not None:
+                # last: http_out runs until the handler returns, so the
+                # stages cover all of http_request_seconds
+                set_current_timer(None)
+                timer.finish()
 
     def do_GET(self):
         self._dispatch("GET")
@@ -283,6 +309,7 @@ class Handler(BaseHTTPRequestHandler):
             status = e.status
         else:
             trace_id = res.pop("traceId", None)
+            enter_stage("encode")
             try:
                 raw = proto.encode_query_response(res["results"])
             except ValueError as e:  # result shape has no proto encoding
@@ -661,7 +688,10 @@ class Handler(BaseHTTPRequestHandler):
         """Capture a jax device profile for ?seconds= (default 3,
         clamped — see :func:`clamp_profile_seconds`) into ?dir=
         (default under the data dir) — TensorBoard-readable
-        (SURVEY.md §6: expose jax.profiler traces)."""
+        (SURVEY.md §6: expose jax.profiler traces).  While it is open
+        every request stage and batcher phase is a ``pilosa.*`` event
+        in the same trace: ``python -m pilosa_tpu.obs.gaps <dir>``
+        puts the device's idle time down to them."""
         import time as _time
 
         import jax
@@ -674,9 +704,18 @@ class Handler(BaseHTTPRequestHandler):
         seconds = clamp_profile_seconds(seconds)
         out_dir = self.query.get("dir", [None])[0] or \
             self.server.api.holder.path + "/_profiles"
-        jax.profiler.start_trace(out_dir)
-        _time.sleep(seconds)
-        jax.profiler.stop_trace()
+        # device ops, XLA's host events and the program's own stages
+        # (obs.metrics.swap_span) — not every Python call of every
+        # thread, which is jax's default and stops the server
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out_dir, profiler_options=options)
+        _metrics.capture_open = True
+        try:
+            _time.sleep(seconds)
+        finally:
+            _metrics.capture_open = False
+            jax.profiler.stop_trace()
         self._reply({"traceDir": out_dir, "seconds": seconds})
 
 

@@ -97,7 +97,30 @@ from pilosa_tpu.engine import kernels
 # attribution context (r19): submits run on the CALLER's thread, so
 # the executor's thread-local (tenant, plane, trace) is read once at
 # _Pending construction and rides the item through the window
+from pilosa_tpu.obs import metrics as _metrics
 from pilosa_tpu.obs.ledger import query_context as _query_ctx
+from pilosa_tpu.obs.metrics import current_timer as _current_timer
+from pilosa_tpu.obs.metrics import enter_stage as _stage
+
+
+_phase_tls = threading.local()
+
+
+def _phase(name: str | None, items=None) -> None:
+    """The phase of the batcher thread's loop that starts here, as a
+    ``pilosa.batcher.<name>`` event in the profiler's trace while a
+    ``/debug/profile`` capture is open (None: the thread idles, nothing
+    is open).  ``items``: the window's items — their requests' trace
+    ids ride the event, so a request's spans on its serving thread and
+    on the batcher's threads can be joined."""
+    open_span = getattr(_phase_tls, "span", None)
+    if open_span is None and not _metrics.capture_open:
+        return
+    traces = ""
+    if items and _metrics.capture_open:
+        traces = ",".join(sorted({p.trace for p in items if p.trace}))
+    _phase_tls.span = _metrics.swap_span(
+        open_span, name and "batcher." + name, traces)
 
 
 def _stall_error(msg: str, stage: str, elapsed: float = 0.0):
@@ -109,7 +132,8 @@ def _stall_error(msg: str, stage: str, elapsed: float = 0.0):
 class _Pending:
     __slots__ = ("kind", "nodes", "leaves", "delta", "event", "result",
                  "error", "deadline", "abandoned", "stage", "delivered",
-                 "tenant", "plane", "trace")
+                 "tenant", "plane", "trace", "t_queued", "t_dispatch",
+                 "t_readback", "t_done")
 
     def __init__(self, kind, nodes, leaves, delta=None, deadline=None):
         self.kind = kind      # "count" | "sum" | "minmax" | "rowcounts"
@@ -138,6 +162,12 @@ class _Pending:
         self.deadline = deadline
         self.abandoned = False
         self.stage = "queued"
+        # perf_counter() where ``stage`` changes, and where the answer
+        # (or error) is stored: wait() hands them to the caller's
+        # StageTimer, which books queue / dispatch / read / deliver
+        # from them
+        self.t_queued = time.perf_counter()
+        self.t_dispatch = self.t_readback = self.t_done = None
         # True once a result/error was actually STORED — the event
         # alone cannot distinguish "answered" from "abandoned item
         # acknowledged" at the deadline boundary (see wait())
@@ -356,6 +386,7 @@ class CountBatcher:
         if not (p.abandoned or p.event.is_set()):
             p.result = value
             p.delivered = True
+            p.t_done = time.perf_counter()
         p.event.set()
 
     @staticmethod
@@ -363,6 +394,7 @@ class CountBatcher:
         if not (p.abandoned or p.event.is_set()):
             p.error = err
             p.delivered = True
+            p.t_done = time.perf_counter()
         p.event.set()
 
     @staticmethod
@@ -411,7 +443,27 @@ class CountBatcher:
         Deadline-aware (r18): an item carrying a deadline waits with a
         BOUNDED timeout; on expiry it is marked abandoned (the shared
         readback skips it) and ``QueryTimeoutError`` names the stage
-        the item was in when the clock ran out."""
+        the item was in when the clock ran out.
+
+        The one place a caller resumes: the time it spent blocked is
+        booked on its stage clock as ``queue`` (enqueued → the window's
+        dispatch begins), ``dispatch``, ``read`` and ``deliver`` (value
+        on the host → this thread runs again), cut at the stamps the
+        batcher's threads left on the item."""
+        timer = _current_timer()
+        if timer is not None:
+            timer.enter("queue", at=p.t_queued)
+        try:
+            return CountBatcher._wait(p)
+        finally:
+            if timer is not None and p.t_done is not None:
+                timer.recut((("dispatch", p.t_dispatch),
+                             ("read", p.t_readback),
+                             ("deliver", p.t_done)))
+                timer.enter("assemble")
+
+    @staticmethod
+    def _wait(p: _Pending):
         if p.deadline is None:
             p.event.wait()
         else:
@@ -483,6 +535,12 @@ class CountBatcher:
         tenant, plane, trace = _query_ctx()
         self.ledger.charge_solo(tenant, kind, plane, wall, nbytes,
                                 trace_id=trace)
+        _stage("assemble")
+
+    # Every lane below runs on the caller's thread, so it enters
+    # ``dispatch``, ``read`` and ``deliver`` on the caller's stage clock
+    # directly (no ``queue``: nothing waited for a window), and
+    # _fastlane_done leaves it in ``assemble``.
 
     def _fastlane_counts(self, nodes: tuple, leaves: tuple):
         """One request's Count run dispatched inline on the caller
@@ -490,6 +548,7 @@ class CountBatcher:
         (offset-0 single item), donated ping-pong scratch for the
         int32[K_pad, S] output.  None = fall back to the window."""
         from pilosa_tpu.exec.fused import pow2_bucket
+        _stage("dispatch")
         t0 = time.perf_counter()
         try:
             padded = tuple(nodes) + (nodes[0],) * (
@@ -498,7 +557,9 @@ class CountBatcher:
                 (len(padded), leaves[0].shape[0]), "int32")
             out = self.fused.run_count_batch(padded, leaves,
                                              scratch=scratch)
+            _stage("read")
             host = np.asarray(out).astype(np.int64)
+            _stage("deliver")
             self._pp.retire(out)
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
@@ -515,6 +576,7 @@ class CountBatcher:
         from pilosa_tpu.exec.fused import pow2_bucket
         order = sorted(set(slots))
         pos = {s: i for i, s in enumerate(order)}
+        _stage("dispatch")
         t0 = time.perf_counter()
         try:
             scratch = self._pp.scratch(
@@ -522,7 +584,9 @@ class CountBatcher:
             out = self.fused.run_selected_counts(
                 plane, tuple(order), delta=delta, scratch=scratch,
                 sorted_idx=True)
+            _stage("read")
             host = np.asarray(out).astype(np.int64)
+            _stage("deliver")
             self._pp.retire(out)
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
@@ -534,12 +598,15 @@ class CountBatcher:
         return host[[pos[s] for s in slots]]
 
     def _fastlane_rowcounts(self, plane, filter_words, delta):
+        _stage("dispatch")
         t0 = time.perf_counter()
         try:
             if delta is not None:
                 out = self.fused.run_rowcounts_delta(
                     plane, delta, filter_words=filter_words)
+                _stage("read")
                 host = np.asarray(out).astype(np.int64)
+                _stage("deliver")
             else:
                 flags = (filter_words is not None,)
                 leaves = ((plane,) if filter_words is None
@@ -548,7 +615,9 @@ class CountBatcher:
                                            "int32")
                 out = self.fused.run_rowcounts_batch(flags, leaves,
                                                      scratch=scratch)
+                _stage("read")
                 host = np.asarray(out).astype(np.int64)[0]
+                _stage("deliver")
                 self._pp.retire(out)
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
@@ -562,12 +631,15 @@ class CountBatcher:
 
     def _fastlane_tree(self, plane, slots: tuple, prog: tuple,
                        extras: tuple, delta):
+        _stage("dispatch")
         t0 = time.perf_counter()
         try:
             out = self.fused.run_tree_counts(plane, tuple(slots),
                                              (tuple(prog),),
                                              tuple(extras), delta=delta)
+            _stage("read")
             val = int(np.asarray(out).astype(np.int64)[0])
+            _stage("deliver")
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
@@ -590,16 +662,20 @@ class CountBatcher:
         from pilosa_tpu.engine import bsi as bsik
         flags = (filter_words is not None,)
         filters = (filter_words,) if filter_words is not None else ()
+        _stage("dispatch")
         t0 = time.perf_counter()
         try:
             if kind == "sum":
                 out = self.fused.run_sum_plane_batch(
                     plane, flags, filters, delta=delta)
+                _stage("read")
                 val = bsik.decode_sum_packed(np.asarray(out)[0])
             else:
                 out = self.fused.run_minmax_plane_batch(
                     plane, flags, filters, delta=delta)
+                _stage("read")
                 val = bsik.decode_minmax_packed(np.asarray(out)[0])
+            _stage("deliver")
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
@@ -612,12 +688,15 @@ class CountBatcher:
                            delta):
         """One BSI Range-count inline: batch of one through
         ``run_range_batch``.  None = fall back to the window."""
+        _stage("dispatch")
         t0 = time.perf_counter()
         try:
             out = self.fused.run_range_batch(plane, (spec,),
                                              tuple(operands),
                                              delta=delta)
+            _stage("read")
             val = int(np.asarray(out).astype(np.int64)[0])
+            _stage("deliver")
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
@@ -630,11 +709,14 @@ class CountBatcher:
         fall back to the window."""
         from pilosa_tpu.exec import groupby as gb
         planes, ci, lp, fw, ap, dl = args
+        _stage("dispatch")
         t0 = time.perf_counter()
         try:
             out = self.fused.run_groupby_batch(planes, ci, lp, fw, ap,
                                                agg_kind, delta=dl)
+            _stage("read")
             host = np.asarray(out)
+            _stage("deliver")
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
@@ -918,6 +1000,7 @@ class CountBatcher:
             self._deliver_error(p, err)
 
     def _collect_once(self) -> None:
+        _phase("collect")  # waiting for items, or for the window to close
         self._kick.wait()
         if self._superseded():
             return
@@ -946,6 +1029,7 @@ class CountBatcher:
                 self._busy -= 1
 
     def _process_batch(self, batch: list, backlog: int) -> None:
+        _phase("group", batch)
         if self.adaptive:
             if len(batch) > 1 or backlog > len(batch):
                 self._win = min(max(self._win * 2, self.ADAPT_MIN),
@@ -1028,8 +1112,11 @@ class CountBatcher:
         # correctness, is what degrades).  No pipeline, no fast lane,
         # no shared readback to stall.
         if not self.governor.admit():
+            _phase("dispatch", batch)
+            now = time.perf_counter()
             for p in batch:
                 p.stage = "dispatch"
+                p.t_dispatch = now
             for key, group in groups.items():
                 if key[0] == "distinct":
                     self._run_distinct(group)
@@ -1082,6 +1169,7 @@ class CountBatcher:
         # group.  Distinct stays on
         # the pool: its presence scan is a multi-dispatch host
         # loop that cannot join a single readback.
+        _phase("dispatch", batch)
         pending = []
         distinct_futs = []
         program_groups = []
@@ -1103,8 +1191,10 @@ class CountBatcher:
             self._pipe_slots.acquire()
             slot_held = True
         w = self._register_window(batch, slot_held)
+        now = time.perf_counter()
         for p in batch:
             p.stage = "dispatch"
+            p.t_dispatch = now
         if len(program_groups) == 1:
             # the common (and solo-path) case skips the pool
             # round-trip: one group, dispatch inline — a hang here
@@ -1206,8 +1296,10 @@ class CountBatcher:
             w.stage = "readback"
             w.t0 = time.monotonic()
         self.flight.record("readback", f"w{w.wid}")
+        now = time.perf_counter()
         for p in batch:
             p.stage = "readback"
+            p.t_readback = now
         if slot_held:
             # PIPELINED READBACK (r17): hand the dispatched window
             # to the readback worker and immediately collect the
@@ -1450,6 +1542,7 @@ class CountBatcher:
         while True:
             if self._read_thread is not threading.current_thread():
                 return  # superseded by a quarantine restart (r18)
+            _phase(None)
             w = self._readq.get()
             if w is None or w.done:
                 continue  # wake sentinel / already-quarantined window
@@ -1474,6 +1567,7 @@ class CountBatcher:
         """Read one dispatched window back and finish its items — the
         half of the old loop tail that runs on the readback worker
         when pipelining is on (inline when off)."""
+        _phase("read", w.items)
         if fault.ACTIVE:
             # chaos seam (r18): a stalled device→host read
             fault.fire("exec.readback_hang")
@@ -1653,7 +1747,9 @@ class CountBatcher:
         if len(pending) == 1:
             key, group, out, finish = pending[0]
             try:
-                finish(np.asarray(out))
+                host = np.asarray(out)
+                _phase("deliver", group)
+                finish(host)
             except Exception:  # noqa: BLE001 — per-item fallback
                 w.faulted = True
                 self.governor.record_fault()
@@ -1682,6 +1778,7 @@ class CountBatcher:
             self.stats.count("batcher_readback_groups", len(pending))
         except Exception:  # noqa: BLE001 — per-group reads
             packed = packed_dev = None
+        _phase("deliver", w.items)
         off = 0
         for key, group, out, finish in pending:
             try:

@@ -34,6 +34,9 @@ from pilosa_tpu.exec.result import (ExtractResult, GroupCountsResult,
                                     RowResult, ValCount)
 from pilosa_tpu.obs.ledger import (clear_query_context,
                                    set_query_context)
+from pilosa_tpu.obs.metrics import (StageTimer, current_timer,
+                                    set_current_timer)
+from pilosa_tpu.obs.metrics import enter_stage as _stage  # per-request clock
 from pilosa_tpu.obs.tracing import current_trace_id
 from pilosa_tpu.pql import parse_cached
 from pilosa_tpu.pql.ast import (BETWEEN_OPS, Call, Condition, Query,
@@ -383,6 +386,9 @@ class Executor:
         from pilosa_tpu.tenancy import (PlanePager, ResidencyGovernor,
                                         TenantQos)
         self.stats = stats or NopStats()
+        # a validated plan that served nothing (see _execute_planned);
+        # registered at 0 so the series prints before the first one
+        self.stats.count("plan_cache_fallthrough_total", 0)
         # device-cost ledger + flight recorder (r19): one ledger and
         # one event ring per executor, threaded into every layer that
         # spends device time (planes, pager, fused cache, batcher,
@@ -541,9 +547,11 @@ class Executor:
 
     def serving_path(self) -> str:
         """Which path answered the serving thread's LAST query —
-        ``fused`` / ``op-at-a-time fallback`` / ``paged`` /
-        ``row-directory oracle`` / ``degraded governor``.  Read by the
-        slow-query log so every slow entry names its path."""
+        ``fused`` / ``generic per-row`` (a cached plan fell through:
+        its plane is not resident) / ``op-at-a-time fallback`` /
+        ``paged`` / ``row-directory oracle`` / ``degraded governor``.
+        Read by the slow-query log so every slow entry names its
+        path."""
         return getattr(self._tls, "spath", "fused")
 
     def device_health(self) -> dict:
@@ -672,13 +680,21 @@ class Executor:
         # subtrees — shares the outer query's lease set and in-flight
         # slot): register for OOM-recovery coordination
         depth = getattr(self._tls, "depth", 0)
-        timer = None
+        own_timer = False
         qos_held = False
         if depth == 0:
-            from pilosa_tpu.obs import StageTimer
-            # stage marks double as `stage.*` child spans on the traced
-            # query (per-request tracer when given, else the shared one)
-            timer = StageTimer(self.stats, tracer=tracer or self.tracer)
+            # the stage clock: the HTTP edge's when the request came in
+            # over it, else this call's own.  Stages double as
+            # `stage.*` child spans on the traced query (per-request
+            # tracer when given, else the shared one)
+            timer = current_timer()
+            own_timer = timer is None
+            if own_timer:
+                timer = StageTimer(self.stats, "admit",
+                                   tracer=tracer or self.tracer)
+            else:
+                timer.attach(tracer or self.tracer)
+                timer.enter("admit")
             # per-tenant QoS FIRST (r17 tenancy): an over-quota tenant
             # sheds with a structured 503 BEFORE taking an executor
             # slot, so its retries queue at the client — never in
@@ -735,8 +751,8 @@ class Executor:
                 if qos_held:
                     self.qos.release(index_name)
                 raise
-            timer.mark("admit")
-            self._tls.stage_timer = timer
+            if own_timer:
+                set_current_timer(timer)
             # deadline propagation (r18): remember this query's cutoff
             # on the serving thread so every batcher submit down-stack
             # carries it — wait() then blocks with a BOUNDED timeout
@@ -759,27 +775,28 @@ class Executor:
                 # fails the query after admission.  Inside the main
                 # try: a raise here must still release the slot.
                 fault.fire("exec.execute", index=index_name)
-                timer.reset()  # injected delay is no stage's fault
             if isinstance(query, str):
                 if depth == 0:
                     # plan-cache fast path: a repeated all-Count serving
                     # shape skips parse AND plan (r6 tentpole)
+                    _stage("plan_cache")
                     out = self._execute_planned(
                         index, index_name, query, shards, translate_output,
-                        tracer, deadline, timer)
+                        tracer, deadline)
                     if out is not None:
                         return out
+                    _stage("parse")
                 # memoized: repeated serving shapes skip the parser (the
                 # AST is never mutated in place — rewriters copy first)
                 query = parse_cached(query)
-                if timer is not None:
-                    timer.mark("parse")
             return self._execute_calls(index, index_name, query, shards,
                                        translate_output, tracer, deadline)
         finally:
             self._tls.depth = depth
             if depth == 0:
-                self._tls.stage_timer = None
+                if own_timer:
+                    set_current_timer(None)
+                    timer.finish()
                 self._tls.deadline = None
                 # ledger context clears here; the serving-path tag
                 # survives until the NEXT admission on this thread —
@@ -805,6 +822,7 @@ class Executor:
         i = 0
         calls = query.calls
         while i < len(calls):
+            _stage("plan")
             run_end = i
             while (run_end < len(calls) and calls[run_end].name == "Count"
                    and len(calls[run_end].children) == 1):
@@ -864,30 +882,23 @@ class Executor:
                 all_leaves.extend(leaves)
         except Unfusable:
             return None
-        timer = getattr(self._tls, "stage_timer", None)
-        if timer is not None:
-            timer.mark("plan")
-        return self._dispatch_count_run(tuple(nodes), tuple(all_leaves),
-                                        timer)
+        return self._dispatch_count_run(tuple(nodes), tuple(all_leaves))
 
-    def _dispatch_count_run(self, nodes: tuple, leaves: tuple,
-                            timer) -> list[int]:
+    def _dispatch_count_run(self, nodes: tuple, leaves: tuple) -> list[int]:
         """One request's planned Count run → per-call totals (the one
         dispatch tail shared by the plan-cached and freshly-planned
         paths).  With the batcher, the whole request is ONE batch item:
-        concurrent requests share a dispatch + read."""
+        concurrent requests share a dispatch + read.  Every batcher
+        submit books queue / dispatch / read / deliver on the caller's
+        stage clock and returns in ``assemble``."""
         if self.batcher is not None:
-            out = self.batcher.submit_many(
+            return self.batcher.submit_many(
                 nodes, leaves, deadline=self._query_deadline())
-            if timer is not None:
-                timer.mark("read")
-            return out
+        _stage("dispatch")
         per_shard = self.fused.run_count_batch(nodes, leaves)
-        if timer is not None:
-            timer.mark("dispatch")
+        _stage("read")
         host = np.asarray(per_shard).astype(np.int64)  # one read
-        if timer is not None:
-            timer.mark("read")
+        _stage("assemble")
         return [int(row.sum()) for row in host]
 
     def _count_batch_plane(self, ctx: _Ctx, calls: list[Call]) \
@@ -933,8 +944,7 @@ class Executor:
                                             VIEW_STANDARD, ctx.shards)
         if ps is None:
             return None
-        return self._plane_count_rows(
-            ps, row_ids, getattr(self._tls, "stage_timer", None))
+        return self._plane_count_rows(ps, row_ids)
 
     def _plain_row_parse(self, ctx: _Ctx, calls: list[Call]):
         """``(field, values)`` when every call is ``Count(Row(f=v))``
@@ -1019,16 +1029,16 @@ class Executor:
         self._note_path("paged")
         row_ids = [self._row_id(ctx, field, v, create=False)
                    for v in values]
-        timer = getattr(self._tls, "stage_timer", None)
         totals = [0] * len(row_ids)
         for page_shards in pages:
+            _stage("plan")  # residency / page-in of the next page
             ps = self.pager.resident_page(ctx.index.name, field,
                                           VIEW_STANDARD, page_shards)
             if ps is None:
                 ps = self.pager.page_in(ctx.index.name, field,
                                         VIEW_STANDARD, page_shards)
             if ps is not None:
-                part = self._plane_count_rows(ps, row_ids, timer)
+                part = self._plane_count_rows(ps, row_ids)
             else:
                 # quota denied the page-in: host truth answers this
                 # page exactly (directory sums, no bit expansion)
@@ -1037,8 +1047,7 @@ class Executor:
                     field, VIEW_STANDARD, page_shards, row_ids)
             for i, v in enumerate(part):
                 totals[i] += int(v)
-        if timer is not None:
-            timer.mark("read")
+        _stage("assemble")
         return totals
 
     # -------------------------------------------------- BSI range (r20)
@@ -1114,11 +1123,9 @@ class Executor:
             field, op_keys, offsets = it
             items.append((field, op_keys, offsets,
                           self._bsirange_operands(field, offsets)))
-        return self._run_bsirange_items(
-            ctx, items, getattr(self._tls, "stage_timer", None))
+        return self._run_bsirange_items(ctx, items)
 
-    def _run_bsirange_items(self, ctx: _Ctx, items: list,
-                            timer) -> list[int]:
+    def _run_bsirange_items(self, ctx: _Ctx, items: list) -> list[int]:
         """Dispatch resolved bsirange items — ``(field, op_keys,
         offsets, operands)`` per Count — through the batcher: the one
         place that builds the batcher's spec/sig tuples and decides
@@ -1126,14 +1133,13 @@ class Executor:
         before waiting on any).  Planes resolve up front, so a
         failing resolution can never abandon already-enqueued
         neighbors in the window."""
+        _stage("plan")
         deadline = self._query_deadline()
         planes: dict[str, object] = {}
         for field, _ops, _offs, _operands in items:
             if field.name not in planes:
                 planes[field.name] = self.planes.bsi_plane_delta(
                     ctx.index.name, field, ctx.shards)
-        if timer is not None:
-            timer.mark("plan")
         if len(items) == 1:
             field, op_keys, offsets, operands = items[0]
             ps = planes[field.name]
@@ -1150,8 +1156,6 @@ class Executor:
                     (op_keys, offsets, 0), delta=ps.delta,
                     deadline=deadline))
             out = [self.batcher.wait(h) for h in handles]
-        if timer is not None:
-            timer.mark("read")  # coalesced wait: window+dispatch+read
         return out
 
     # -------------------------------------------------- whole-tree (r16)
@@ -1179,8 +1183,7 @@ class Executor:
                      for c in calls]
         except Unfusable:
             return None
-        return self._run_tree_specs(
-            ctx, specs, getattr(self._tls, "stage_timer", None))
+        return self._run_tree_specs(ctx, specs)
 
     def _tree_stats(self, spec) -> None:
         self.stats.observe("tree_fusion_depth", float(spec.depth))
@@ -1189,7 +1192,7 @@ class Executor:
         if spec.static_ops:
             self.stats.count("tree_static_ops_total", spec.static_ops)
 
-    def _run_tree_specs(self, ctx: _Ctx, specs, timer) -> list[int] | None:
+    def _run_tree_specs(self, ctx: _Ctx, specs) -> list[int] | None:
         """Materialize + dispatch lowered tree specs: row ids resolve
         to plane slots FRESH per hit (so plan-cached specs keep
         serving current truth), extras re-fetch through the plane
@@ -1203,10 +1206,11 @@ class Executor:
             if hit is None:
                 return None
             resolved.append(hit)
+        # runnable: what the plan cache spent until here was its own
+        # (a cached plan's residency checks); from here it is planning
+        _stage("plan")
         for spec in specs:
             self._tree_stats(spec)
-        if timer is not None:
-            timer.mark("plan")
         if self.batcher is not None:
             if len(resolved) == 1:
                 # single tree: the blocking submit rides the solo fast
@@ -1223,8 +1227,6 @@ class Executor:
                     deadline=self._query_deadline())
                            for ps, item in resolved]
                 out = [self.batcher.wait(h) for h in handles]
-            if timer is not None:
-                timer.mark("read")  # coalesced window+dispatch+read
             return out
         # no batcher: one fused program per (plane, overlay) group
         from pilosa_tpu.exec.tree import assemble_items
@@ -1240,15 +1242,14 @@ class Executor:
             ps = group_ps[k]
             slots, progs, extras = assemble_items(
                 [resolved[i][1] for i in idxs])
+            _stage("dispatch")
             dev = self.fused.run_tree_counts(ps.plane, slots, progs,
                                              extras, delta=ps.delta)
-            if timer is not None:
-                timer.mark("dispatch")
+            _stage("read")
             vals = np.asarray(dev).astype(np.int64)
+            _stage("assemble")
             for j, i in enumerate(idxs):
                 out[i] = int(vals[j])
-        if timer is not None:
-            timer.mark("read")
         return out
 
     def _tree_item(self, ctx: _Ctx, spec):
@@ -1363,7 +1364,7 @@ class Executor:
     # conservative
     _SELECTED_ROWS_FRACTION = 4  # use gather when n_sel * 4 <= R_pad
 
-    def _plane_count_rows(self, ps, row_ids, timer=None) -> list[int]:
+    def _plane_count_rows(self, ps, row_ids) -> list[int]:
         """Per-call totals for resolved ``row_ids`` (None = absent row
         -> 0) over a resident plane, choosing between the two
         multi-query fused kernels:
@@ -1381,14 +1382,13 @@ class Executor:
         r_pad = ps.plane.shape[-2]
         if (live and len(ps.shards) <= self._REDUCE_SHARD_MAX
                 and len(live) * self._SELECTED_ROWS_FRACTION <= r_pad):
-            by_slot = self._plane_selected_totals(ps, tuple(live), timer)
+            by_slot = self._plane_selected_totals(ps, tuple(live))
             return [int(by_slot[s]) if s is not None else 0
                     for s in slots]
-        totals = self._plane_totals(ps, timer)
+        totals = self._plane_totals(ps)
         return [int(totals[s]) if s is not None else 0 for s in slots]
 
-    def _plane_selected_totals(self, ps, slots: tuple,
-                               timer=None) -> dict:
+    def _plane_selected_totals(self, ps, slots: tuple) -> dict:
         """slot -> int64 total for the selected plane rows: one
         row-gather + popcount program, shard axis reduced on device
         (callers gate on ``_REDUCE_SHARD_MAX``), coalesced across
@@ -1399,19 +1399,16 @@ class Executor:
             vals = self.batcher.submit_selected(
                 ps.plane, slots, delta=ps.delta,
                 deadline=self._query_deadline())
-            if timer is not None:
-                timer.mark("read")  # coalesced wait: window+dispatch+read
         else:
+            _stage("dispatch")
             out = self.fused.run_selected_counts(ps.plane, slots,
                                                  delta=ps.delta)
-            if timer is not None:
-                timer.mark("dispatch")
+            _stage("read")
             vals = np.asarray(out).astype(np.int64)[:len(slots)]
-            if timer is not None:
-                timer.mark("read")
+        _stage("assemble")
         return dict(zip(slots, (int(v) for v in vals)))
 
-    def _plane_totals(self, ps, timer=None) -> np.ndarray:
+    def _plane_totals(self, ps) -> np.ndarray:
         """Whole-plane per-row totals int64[R_pad]: one program + one
         read, coalesced ACROSS concurrent requests via the batcher
         (identical planes dedupe to one computation per window).
@@ -1425,11 +1422,9 @@ class Executor:
         small = len(ps.shards) <= self._REDUCE_SHARD_MAX
         delta = ps.delta
         if self.batcher is not None and small:
-            totals = self.batcher.submit_rowcounts(
+            return self.batcher.submit_rowcounts(
                 ps.plane, delta=delta, deadline=self._query_deadline())
-            if timer is not None:
-                timer.mark("read")  # coalesced wait: window+dispatch+read
-            return totals
+        _stage("dispatch")
         if small:
             if delta is not None:
                 out = self.fused.run_rowcounts_delta(ps.plane, delta)
@@ -1440,11 +1435,8 @@ class Executor:
                     key, lambda: (lambda p: jnp.sum(
                         kernels.row_counts(p), axis=0, dtype=jnp.int32)))
                 out = fn(ps.plane)
-            if timer is not None:
-                timer.mark("dispatch")
+            _stage("read")
             totals = np.asarray(out).astype(np.int64)  # one read
-            if timer is not None:
-                timer.mark("read")
         else:
             if delta is not None:
                 out = self.fused.run_rowcounts_delta(ps.plane, delta,
@@ -1453,12 +1445,9 @@ class Executor:
                 key = (("countbatch-plane", ps.plane.shape), "count")
                 fn = self.fused._cached(key, lambda: kernels.row_counts)
                 out = fn(ps.plane)
-            if timer is not None:
-                timer.mark("dispatch")
-            host = np.asarray(out).astype(np.int64)
-            if timer is not None:
-                timer.mark("read")
-            totals = host.sum(axis=0)
+            _stage("read")
+            totals = np.asarray(out).astype(np.int64).sum(axis=0)
+        _stage("assemble")
         return totals
 
     # ---------------------------------------------------------- plan cache
@@ -1475,7 +1464,7 @@ class Executor:
 
     def _execute_planned(self, index, index_name: str, query: str, shards,
                          translate_output: bool, tracer,
-                         deadline: float | None, timer) -> list | None:
+                         deadline: float | None) -> list | None:
         """Plan-cache fast path for all-Count queries (the dominant
         serving family).  Returns the results list, or None to fall
         through to the parse path (unplannable shape, stale entry, or
@@ -1546,8 +1535,16 @@ class Executor:
                        for fname, sig in entry.bsi_sigs)):
             self._drop_plan(skey, entry)
             return None
-        return self._run_plan(index, index_name, entry, translate_output,
-                              tracer, deadline, timer)
+        out = self._run_plan(index, index_name, entry, translate_output,
+                             tracer, deadline)
+        if out is None:
+            # a validated plan that served nothing (its plane is not
+            # resident): the un-cached path plans this request again,
+            # and what answers is its generic per-row program unless a
+            # path down-stack says otherwise
+            self.stats.count("plan_cache_fallthrough_total", 1)
+            self._note_path("generic per-row")
+        return out
 
     def _drop_plan(self, skey, entry) -> None:
         self.stats.count("plan_cache_invalidations", 1)
@@ -1899,7 +1896,7 @@ class Executor:
 
     def _run_plan(self, index, index_name: str, entry: "_PlanEntry",
                   translate_output: bool, tracer,
-                  deadline: float | None, timer) -> list | None:
+                  deadline: float | None) -> list | None:
         """Run a validated plan; None = not runnable right now (plane
         not resident) — the caller falls through to the normal path,
         keeping admission decisions there."""
@@ -1911,23 +1908,22 @@ class Executor:
                          calls=entry.n_calls, shards=len(ctx.shards)):
             t0 = time.perf_counter()
             out = self._with_oom_retry(
-                lambda: self._run_plan_inner(ctx, entry, timer))
+                lambda: self._run_plan_inner(ctx, entry))
             if out is not None:
                 self.stats.timing("query_seconds",
                                   time.perf_counter() - t0,
                                   call="CountBatch")
         return out
 
-    def _run_plan_inner(self, ctx: _Ctx, entry: "_PlanEntry",
-                        timer) -> list | None:
+    def _run_plan_inner(self, ctx: _Ctx, entry: "_PlanEntry") -> list | None:
+        """A residency check that fails returns None with the clock
+        still in ``plan_cache``: an attempt that serves nothing is the
+        plan cache's cost.  ``plan`` is entered where the leaf fetch
+        begins."""
         if entry.kind == "tree":
             if not self.tree_fusion:  # knob flipped after caching
                 return None
-            out = self._run_tree_specs(ctx, list(entry.tree_specs),
-                                       timer)
-            if out is not None and timer is not None:
-                timer.mark("assemble")
-            return out
+            return self._run_tree_specs(ctx, list(entry.tree_specs))
         if entry.kind == "bsirange":
             if self.batcher is None:  # knob flipped after caching
                 return None
@@ -1937,10 +1933,7 @@ class Executor:
                 if field is None:
                     return None
                 items.append((field, op_keys, offsets, operands))
-            out = self._run_bsirange_items(ctx, items, timer)
-            if timer is not None:
-                timer.mark("assemble")
-            return out
+            return self._run_bsirange_items(ctx, items)
         if entry.kind == "plane":
             field = ctx.index.field(entry.field_name)
             if field is None:
@@ -1954,21 +1947,13 @@ class Executor:
                                                 VIEW_STANDARD, ctx.shards)
             if ps is None:
                 return None
-            if timer is not None:
-                timer.mark("plan")
-            out = self._plane_count_rows(ps, entry.row_ids, timer)
-            if timer is not None:
-                timer.mark("assemble")
-            return out
+            _stage("plan")
+            return self._plane_count_rows(ps, entry.row_ids)
+        _stage("plan")  # leaf fetch through the plane cache
         leaves = self._leaves_from_specs(ctx, entry.leaf_specs)
         if leaves is None:
             return None
-        if timer is not None:
-            timer.mark("plan")
-        out = self._dispatch_count_run(entry.nodes, tuple(leaves), timer)
-        if timer is not None:
-            timer.mark("assemble")
-        return out
+        return self._dispatch_count_run(entry.nodes, tuple(leaves))
 
     def _shards_for(self, index: Index, shards,
                     call: Call | None) -> tuple[int, ...]:
@@ -2640,7 +2625,9 @@ class Executor:
         return out
 
     def _to_row_result(self, ctx: _Ctx, words: jax.Array) -> RowResult:
+        _stage("read")
         host = np.asarray(words)
+        _stage("assemble")
         parts = [offs.astype(np.uint64) + np.uint64(s * SHARD_WIDTH)
                  for _, s, offs in self._shard_offsets(ctx, host)]
         columns = (np.concatenate(parts) if parts
@@ -2696,7 +2683,10 @@ class Executor:
         # fused: bitwise tree + per-shard popcount in one XLA program;
         # the tiny cross-shard total finishes in int64 on host
         per_shard = self._fused_bitmap(ctx, call.children[0], want="count")
-        return int(kernels.shard_totals(per_shard))
+        _stage("read")
+        total = int(kernels.shard_totals(per_shard))
+        _stage("assemble")
+        return total
 
     def _execute_distinct(self, ctx: _Ctx, call: Call):
         """Distinct(filter?, field=f): sorted distinct values of a BSI
@@ -2789,9 +2779,12 @@ class Executor:
             flags = (filter_words is not None,)
             filters = ((filter_words,)
                        if filter_words is not None else ())
-            out = np.asarray(self.fused.run_sum_plane_batch(
-                ps.plane, flags, filters, delta=ps.delta))[0]
-            total, cnt = bsik.decode_sum_packed(out)
+            _stage("dispatch")
+            out = self.fused.run_sum_plane_batch(
+                ps.plane, flags, filters, delta=ps.delta)
+            _stage("read")
+            total, cnt = bsik.decode_sum_packed(np.asarray(out)[0])
+            _stage("assemble")
         value = total + field.options.base * cnt
         return ValCount(value=field.from_stored(value) if cnt else 0,
                         count=cnt)
@@ -2814,9 +2807,12 @@ class Executor:
             flags = (filter_words is not None,)
             filters = ((filter_words,)
                        if filter_words is not None else ())
-            out = np.asarray(self.fused.run_minmax_plane_batch(
-                ps.plane, flags, filters, delta=ps.delta))[0]
-            per_shard = bsik.decode_minmax_packed(out)
+            _stage("dispatch")
+            out = self.fused.run_minmax_plane_batch(
+                ps.plane, flags, filters, delta=ps.delta)
+            _stage("read")
+            per_shard = bsik.decode_minmax_packed(np.asarray(out)[0])
+            _stage("assemble")
         # reduce across the shard axis on host (one tuple per shard;
         # a delta-dirty plane appends one zero-or-live tuple per
         # overlay-touched word column — same combine)
@@ -3502,6 +3498,7 @@ class Executor:
             deadline = self._query_deadline()
 
             def run(pl, ci, lp, fw, ap, agg, ad):
+                _stage("plan")
                 # ci arrives as the HOST combo array (see iter_blocks)
                 # — the digest costs no device round trip
                 meta = (int(ci.shape[0]) if pl else 1,
@@ -3519,6 +3516,7 @@ class Executor:
                 agg_delta=(None if minmax_host or agg_plane is None
                            else agg_plane.delta)):
             ctx.check_deadline()  # large combination trees stream
+            _stage("assemble")
             counts = np.asarray(out["counts"])  # (C, slots)
             slots = np.asarray(last_slots, np.int64)
             sub = counts[:, slots].astype(np.int64)  # (C, L)

@@ -25,6 +25,7 @@ import numpy as np
 
 from pilosa_tpu.engine import bsi as bsik
 from pilosa_tpu.engine import kernels
+from pilosa_tpu.obs import metrics as _metrics
 
 _log = _logging.getLogger("pilosa_tpu.exec")
 
@@ -468,7 +469,8 @@ class FusedCache:
 
         def first(*args, **kw):
             t0 = _time.perf_counter()
-            out = fn(*args, **kw)
+            with _metrics.span("compile", family=family):
+                out = fn(*args, **kw)
             if not once:
                 once.append(True)
                 dt = _time.perf_counter() - t0

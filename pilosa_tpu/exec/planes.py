@@ -27,6 +27,7 @@ import numpy as np
 
 from pilosa_tpu.engine.bsi import OFFSET_ROW
 from pilosa_tpu.engine.words import WORDS_PER_SHARD
+from pilosa_tpu.obs import metrics as _metrics
 from pilosa_tpu.store.field import Field
 
 PAD_SHARD = -1  # shard-list padding entry (meshed execution): all-zero words
@@ -540,8 +541,9 @@ class PlaneCache:
             return None  # caller stays on the oracle path
         import time as _time
         t0 = _time.perf_counter()
-        tps = timeviews.build_time_plane(field, shards, self.place,
-                                         plan=plan)
+        with _metrics.span("plane_build", field=field.name):
+            tps = timeviews.build_time_plane(field, shards, self.place,
+                                             plan=plan)
         dt = _time.perf_counter() - t0
         self.builds += 1
         self.build_seconds_total += dt
@@ -763,12 +765,13 @@ class PlaneCache:
         r_pad = _pow2(max(1, len(row_ids)))
         slot_of = {int(r): i for i, r in enumerate(row_ids)}
         slab = r_pad * WORDS_PER_SHARD * 4
-        if slab <= self.BUILD_CHUNK_BYTES:
-            ps = self._build_shard_chunks(field, view_name, shards,
-                                          row_ids, r_pad, slot_of)
-        else:
-            ps = self._build_row_chunks(field, view_name, shards,
-                                        row_ids, r_pad, slot_of)
+        with _metrics.span("plane_build", field=field.name):
+            if slab <= self.BUILD_CHUNK_BYTES:
+                ps = self._build_shard_chunks(field, view_name, shards,
+                                              row_ids, r_pad, slot_of)
+            else:
+                ps = self._build_row_chunks(field, view_name, shards,
+                                            row_ids, r_pad, slot_of)
         dt = _time.perf_counter() - t0
         nbytes = ps.plane.size * 4
         with self._lock:  # concurrent background builds both tally

@@ -31,6 +31,7 @@ r14 (the cluster-observability pane, ISSUE 9) adds:
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import defaultdict
@@ -543,22 +544,95 @@ class NopStats:
         return ""
 
 
+# -- the second sink: the profiler's trace -----------------------------------
+#
+# While a ``POST /debug/profile`` capture is open (``capture_open`` is
+# set by the handler around ``jax.profiler.start_trace`` / ``stop_trace``,
+# nothing an operator configures), every stage also opens a
+# ``jax.profiler.TraceAnnotation`` named ``pilosa.<stage>`` on the thread
+# doing the work, so host activity and device ops share the profiler's
+# clock (``python -m pilosa_tpu.obs.gaps`` reads them back).  With the
+# flag down a stage costs one test of it: no object, no allocation.
+
+capture_open = False
+
+
+def swap_span(span, name: str | None, trace_id: str | None = None):
+    """Close ``span`` (an entered annotation, or None) and, while a
+    capture is open, open ``pilosa.<name>`` and return it — None
+    otherwise, or when ``name`` is None.  The request's trace id rides
+    the event as metadata, so one request's spans on the serving
+    thread and on the batcher's threads can be joined."""
+    if span is not None:
+        span.__exit__(None, None, None)
+    if name is None or not capture_open:
+        return None
+    from jax.profiler import TraceAnnotation
+    span = TraceAnnotation("pilosa." + name, trace_id=trace_id or "")
+    span.__enter__()
+    return span
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **meta):
+    """A context manager around work that is no stage of its own (a
+    compile, a plane build): ``pilosa.<name>`` in the profiler's trace
+    while a capture is open, else a shared null context."""
+    if not capture_open:
+        return _NO_SPAN
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation("pilosa." + name, **meta)
+
+
+_tls = threading.local()
+
+
+def current_timer() -> "StageTimer | None":
+    """The :class:`StageTimer` of the request this thread is serving."""
+    return getattr(_tls, "timer", None)
+
+
+def set_current_timer(timer: "StageTimer | None") -> None:
+    _tls.timer = timer
+
+
+def enter_stage(stage: str) -> None:
+    """Enter ``stage`` on the clock of the request this thread is
+    serving, if it has one (a batcher thread, a test calling a layer
+    directly: no clock, nothing to do)."""
+    timer = getattr(_tls, "timer", None)
+    if timer is not None:
+        timer.enter(stage)
+
+
 class StageTimer:
-    """Per-request overhead attribution: ``mark(stage)`` charges the
-    monotonic time since the previous mark to that stage as one
-    ``query_stage_seconds{stage=...}`` histogram observation.
+    """The per-request clock: ``enter(stage)`` ends whatever stage was
+    open and opens ``stage``; ``finish()`` ends the last one.  Every
+    microsecond between the timer's creation and ``finish()`` therefore
+    belongs to exactly one stage, each booked as one
+    ``query_stage_seconds{stage=...}`` histogram observation when it
+    ends — a path with no instrumentation shows up as a long stage
+    with an honest name instead of vanishing.
 
-    Stages on the serving path: ``admit`` (execution-slot acquisition +
-    recovery gate), ``parse`` (PQL text → AST), ``plan`` (AST → leaf
-    arrays/program structure, incl. plan-cache validation), ``dispatch``
-    (program enqueue), ``read`` (device → host; on batcher-coalesced
-    requests the whole coalesced wait — window + dispatch + read — is
-    charged here, there is no per-request dispatch to time), and
-    ``assemble`` (host result construction).  The per-stage sums are
-    the attribution bench/config18 prints — the residual product/raw
-    concurrency gap is measured per stage, not guessed.
+    Stages of a served request, contiguous on the serving thread (a
+    multi-call request repeats ``plan`` … ``assemble`` per call):
+    ``http_in`` (body read, route match, decode), ``admit`` (QoS,
+    execution slot, recovery gate), ``plan_cache`` (plan-cache lookup,
+    build and validation — and the whole attempt when it serves
+    nothing), ``parse`` (PQL text → AST), ``plan`` (AST → leaf arrays /
+    program structure, residency checks), ``queue`` (item enqueued →
+    its window's dispatch begins; absent on the solo fast lane),
+    ``dispatch`` (program enqueue), ``read`` (blocked on device →
+    host), ``deliver`` (value on host → the caller runs again),
+    ``assemble`` (host result construction), ``encode`` (results →
+    JSON / protobuf bytes) and ``http_out`` (socket write).  The HTTP
+    edge creates the timer and publishes it on the thread
+    (:func:`current_timer`); an in-process ``Executor.execute`` creates
+    its own.
 
-    With a ``tracer`` attached, every mark ALSO lands as a completed
+    With a ``tracer`` attached, every stage ALSO lands as a completed
     ``stage.<name>`` child span under the innermost open span of the
     traced query — the per-stage children a distributed profile tree
     carries on every node (no-op outside any span) — and the query's
@@ -566,31 +640,81 @@ class StageTimer:
     every observation as the bucket's exemplar, so a slow bucket on
     ``/metrics`` names a trace an operator can resolve whenever the
     retention policy kept it (sampled/profiled/slow-captured — a fast
-    unsampled query's exemplar id was never ring-buffered)."""
+    unsampled query's exemplar id was never ring-buffered).  While a
+    profiler capture is open (``capture_open``) the open stage is also
+    a ``pilosa.<stage>`` annotation in the profiler's trace."""
 
-    __slots__ = ("_stats", "_metric", "_last", "tracer", "trace_id")
+    __slots__ = ("_stats", "_metric", "_stage", "_start", "_span",
+                 "tracer", "trace_id")
 
-    def __init__(self, stats, metric: str = STAGE_METRIC,
-                 tracer=None):
+    def __init__(self, stats, stage: str, metric: str = STAGE_METRIC,
+                 tracer=None, at: float | None = None):
+        """Opens ``stage`` now, or as of ``at`` (a ``perf_counter()``
+        reading taken before the timer could be built)."""
         self._stats = stats
         self._metric = metric
+        self._stage = None
+        self._span = None
+        self._start = time.perf_counter() if at is None else at
+        self.attach(tracer)
+        self._open(stage, self._start)
+
+    @property
+    def stage(self) -> str | None:
+        return self._stage
+
+    def attach(self, tracer) -> None:
+        """Take the trace identity (and the ``stage.*`` hand-off) of
+        the tracer the request runs under."""
         self.tracer = tracer
         tid = getattr(tracer, "trace_id", None)
         if tid is None and tracer is not None:
             cur = tracer.current_span()
             tid = cur.trace_id if cur is not None else None
         self.trace_id = tid
-        self._last = time.perf_counter()
 
-    def mark(self, stage: str) -> None:
-        now = time.perf_counter()
-        self._stats.observe(self._metric, now - self._last,
+    def _book(self, stage: str, seconds: float) -> None:
+        self._stats.observe(self._metric, seconds,
                             trace_id=self.trace_id, stage=stage)
         if self.tracer is not None:
-            self.tracer.stage("stage." + stage, now - self._last)
-        self._last = now
+            self.tracer.stage("stage." + stage, seconds)
 
-    def reset(self) -> None:
-        """Restart the clock without charging anything (skip a gap that
-        belongs to no stage)."""
-        self._last = time.perf_counter()
+    def _open(self, stage: str | None, at: float) -> None:
+        if self._stage is not None and at > self._start:
+            self._book(self._stage, at - self._start)
+        self._stage = stage
+        self._start = at
+        if capture_open or self._span is not None:
+            self._span = swap_span(self._span, stage, self.trace_id)
+
+    def enter(self, stage: str, at: float | None = None) -> None:
+        """End the open stage and open ``stage`` (entering the stage
+        that is open changes nothing).  ``at``: a ``perf_counter()``
+        reading taken earlier — honoured when it falls inside the open
+        stage, so a caller can date a stage from a stamp it took
+        before it knew the stage's name would be needed."""
+        if stage == self._stage:
+            return
+        now = time.perf_counter()
+        self._open(stage, at if at is not None
+                   and self._start <= at <= now else now)
+
+    def recut(self, cuts) -> None:
+        """The open stage was spent blocked on work other threads did:
+        ``cuts`` = ((stage, began_at), ...) in order, stamped by those
+        threads on this clock (None = never reached).  Re-cut the
+        interval at the stamps — each stage is booked from its stamp to
+        the next — and leave the last one open."""
+        stage, lo = self._stage, self._start
+        for nxt, t in cuts:
+            if t is None:
+                continue
+            if t > lo:
+                self._book(stage, t - lo)
+                lo = t
+            stage = nxt
+        self._stage = None  # booked above: nothing left for _open
+        self._open(stage, lo)
+
+    def finish(self) -> None:
+        self._open(None, time.perf_counter())

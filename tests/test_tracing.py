@@ -171,8 +171,8 @@ class TestSlowQueryCapture:
             # answered — triage starts with "was this on the fast
             # path at all"
             assert entry["path"] in (
-                "fused", "op-at-a-time fallback", "paged",
-                "row-directory oracle", "degraded governor")
+                "fused", "generic per-row", "op-at-a-time fallback",
+                "paged", "row-directory oracle", "degraded governor")
             spans = list(walk(entry["profile"]))
             assert any(s["name"] == "executor.Count" for s in spans)
             # slow traces are retained: the id resolves in the ring
