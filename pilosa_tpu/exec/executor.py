@@ -100,6 +100,14 @@ _EAGER_OPS = {"or": kernels.union, "and": kernels.intersect,
               "andnot": kernels.difference, "xor": kernels.xor}
 
 
+# leaf-spec kinds (``Executor._plan_spec``) that bake nothing a write
+# can stale: rows, the existence row and BSI planes re-fetch through
+# the plane cache on every hit, consts are predicate masks.  ``zeros``
+# (an absent keyed row) and ``bsi-exists`` (a saturation verdict) are
+# verdicts about the data: they stay generation-checked.
+_REFETCHED_LEAVES = frozenset({"row", "exists", "const", "bsi-plane"})
+
+
 def _bsi_signature(options) -> tuple:
     """Everything a baked BSI predicate depends on.  A cached plan
     resolved its offsets (``to_stored(value) - base``) and saturation
@@ -203,6 +211,14 @@ class _PlanEntry:
       hit, and the anchor plane's delta overlay keeps answers fresh
       under sustained ingest.
 
+    A ``"plane"`` or ``"tree"`` entry whose calls also lower through
+    ``_plan_spec`` carries the ``"generic"`` form beside its own
+    (``nodes`` / ``leaf_specs``; ``deps`` / ``gens`` / ``bsi_sigs``
+    cover both): where the whole-field plane is not resident and the
+    selectivity rule says it will not become so (a tiny slice of a
+    huge row set), the hit is answered by that per-row form instead
+    of falling through (``Executor._run_plan_inner``).
+
     Validity: ``shards`` must equal the current shard set and ``gens``
     must equal the dependency views' generations — a write to any
     source fragment (including creating a row key that planned as a
@@ -246,6 +262,22 @@ class _PlanEntry:
     # entry survives sustained ingest like the unkeyed-plane kinds;
     # ``bsi_sigs`` pins depth/base so the baked offsets stay valid)
     range_items: tuple = ()
+
+
+class _PlanHit:
+    """One plan-cache hit's scratch, for an entry that carries a
+    per-row form: the generation tuple of each (field, view) the hit
+    reads — swept once, shared by the selectivity rule's ``plane_bytes``
+    and every row fetch — and ``by_rule``: whether what kept the
+    whole-plane form from running was the selectivity rule (so the
+    per-row form IS the resident form and serves) rather than an
+    admission decision, which stays on the un-cached path."""
+
+    __slots__ = ("gens", "by_rule")
+
+    def __init__(self):
+        self.gens: dict[tuple, tuple] = {}
+        self.by_rule = False
 
 
 class QueryTimeoutError(ExecutionError):
@@ -386,9 +418,12 @@ class Executor:
         from pilosa_tpu.tenancy import (PlanePager, ResidencyGovernor,
                                         TenantQos)
         self.stats = stats or NopStats()
-        # a validated plan that served nothing (see _execute_planned);
-        # registered at 0 so the series prints before the first one
+        # a validated plan that served nothing (the un-cached path had
+        # an admission decision to make: see _execute_planned), and one
+        # that answered by its per-row form (_run_plan_inner);
+        # registered at 0 so the series print before the first one
         self.stats.count("plan_cache_fallthrough_total", 0)
+        self.stats.count("plan_cache_row_serves_total", 0)
         # device-cost ledger + flight recorder (r19): one ledger and
         # one event ring per executor, threaded into every layer that
         # spends device time (planes, pager, fused cache, batcher,
@@ -547,8 +582,11 @@ class Executor:
 
     def serving_path(self) -> str:
         """Which path answered the serving thread's LAST query —
-        ``fused`` / ``generic per-row`` (a cached plan fell through:
-        its plane is not resident) / ``op-at-a-time fallback`` /
+        ``fused`` / ``plan-cached per-row`` (a cached plane or tree
+        plan answered by its per-row form: the whole-field plane is
+        not resident, by the selectivity rule) / ``generic per-row``
+        (a cached plan fell through to the un-cached path, which had
+        an admission decision to make) / ``op-at-a-time fallback`` /
         ``paged`` / ``row-directory oracle`` / ``degraded governor``.
         Read by the slow-query log so every slow entry names its
         path."""
@@ -932,8 +970,7 @@ class Executor:
                 return self._paged_count(ctx, field, values)
             if est > self.planes.budget:
                 return None
-            r_est = max(1, est // (len(ctx.shards) * WORDS_PER_SHARD * 4))
-            if len(calls) * 4 < r_est:
+            if self._tiny_slice(est, len(ctx.shards), len(calls)):
                 return None
         row_ids = [self._row_id(ctx, field, v, create=False)
                    for v in values]
@@ -945,6 +982,16 @@ class Executor:
         if ps is None:
             return None
         return self._plane_count_rows(ps, row_ids)
+
+    @staticmethod
+    def _tiny_slice(est: int, n_shards: int, n_rows: int) -> bool:
+        """The selectivity rule: a request touching ``n_rows`` rows of
+        a field whose whole plane is estimated at ``est`` bytes reads
+        a tiny slice of a huge row set — whole-plane residency would
+        waste bandwidth and HBM, so per-row entries are the resident
+        form (the plane, tree and plan-cache paths all ask here)."""
+        r_est = max(1, est // (n_shards * WORDS_PER_SHARD * 4))
+        return max(1, n_rows) * 4 < r_est
 
     def _plain_row_parse(self, ctx: _Ctx, calls: list[Call]):
         """``(field, values)`` when every call is ``Count(Row(f=v))``
@@ -1192,20 +1239,23 @@ class Executor:
         if spec.static_ops:
             self.stats.count("tree_static_ops_total", spec.static_ops)
 
-    def _run_tree_specs(self, ctx: _Ctx, specs) -> list[int] | None:
+    def _run_tree_specs(self, ctx: _Ctx, specs,
+                        hit: "_PlanHit | None" = None) -> list[int] | None:
         """Materialize + dispatch lowered tree specs: row ids resolve
         to plane slots FRESH per hit (so plan-cached specs keep
         serving current truth), extras re-fetch through the plane
         cache, and a delta-dirty anchor plane answers base⊕delta
         inside the same program.  None = an anchor plane isn't
         resident/admittable or a field vanished — admission decisions
-        stay on the un-cached path."""
+        stay on the un-cached path; ``hit`` (a plan-cache hit whose
+        entry has a per-row form) learns whether it was the
+        selectivity rule instead."""
         resolved = []
         for spec in specs:
-            hit = self._tree_item(ctx, spec)
-            if hit is None:
+            item = self._tree_item(ctx, spec, hit)
+            if item is None:
                 return None
-            resolved.append(hit)
+            resolved.append(item)
         # runnable: what the plan cache spent until here was its own
         # (a cached plan's residency checks); from here it is planning
         _stage("plan")
@@ -1252,12 +1302,13 @@ class Executor:
                 out[i] = int(vals[j])
         return out
 
-    def _tree_item(self, ctx: _Ctx, spec):
+    def _tree_item(self, ctx: _Ctx, spec, hit: "_PlanHit | None" = None):
         """One spec's runtime form: ``(PlaneSet, (slots, prog,
         extras))`` with PUSH args rewritten against the LIVE slot map
         (absent rows become zero pushes) and extra operands
         materialized.  None = not runnable on the device path right
-        now (caller falls back / invalidates)."""
+        now (caller falls back / invalidates); ``hit.by_rule`` is set
+        where the selectivity rule, not admission, is why."""
         from pilosa_tpu.engine.kernels import TREE_PUSH, TREE_ZERO
         field = ctx.index.field(spec.field)
         if field is None or field.options.type in BSI_TYPES:
@@ -1269,12 +1320,14 @@ class Executor:
             # admission mirrors _count_batch_plane: budget walk only
             # when the plane isn't resident, and skip whole-plane
             # residency for a tiny slice of a huge row set
-            est = self.planes.plane_bytes(field, VIEW_STANDARD,
-                                          ctx.shards)
+            est = self.planes.plane_bytes(
+                field, VIEW_STANDARD, ctx.shards,
+                gens=self._hit_gens(ctx, hit, field, VIEW_STANDARD))
             if est > self.planes.budget:
                 return None
-            r_est = max(1, est // (len(ctx.shards) * WORDS_PER_SHARD * 4))
-            if max(1, len(spec.rows)) * 4 < r_est:
+            if self._tiny_slice(est, len(ctx.shards), len(spec.rows)):
+                if hit is not None:
+                    hit.by_rule = True
                 return None
         ps = self.planes.field_plane_nowait(ctx.index.name, field,
                                             VIEW_STANDARD, ctx.shards)
@@ -1467,9 +1520,13 @@ class Executor:
                          deadline: float | None) -> list | None:
         """Plan-cache fast path for all-Count queries (the dominant
         serving family).  Returns the results list, or None to fall
-        through to the parse path (unplannable shape, stale entry, or
-        a plane that isn't resident — admission decisions stay on the
-        un-cached path)."""
+        through to the parse path: an unplannable shape, a stale
+        entry, or a plane that isn't resident where the un-cached path
+        has something to DO about it (admit and start the build, page,
+        refuse on budget) — admission decisions stay there.  A plane
+        that isn't resident because the selectivity rule keeps the
+        field per-row is no fall-through: the entry's per-row form
+        answers (:meth:`_run_plan_inner`)."""
         # strip() only — whitespace INSIDE the query can be inside a
         # quoted row key, where collapsing it would alias two distinct
         # queries onto one plan (wrong answers, not a perf bug)
@@ -1539,8 +1596,9 @@ class Executor:
                              tracer, deadline)
         if out is None:
             # a validated plan that served nothing (its plane is not
-            # resident): the un-cached path plans this request again,
-            # and what answers is its generic per-row program unless a
+            # resident and admission has a say, or it has no per-row
+            # form): the un-cached path plans this request again, and
+            # what answers is its generic per-row program unless a
             # path down-stack says otherwise
             self.stats.count("plan_cache_fallthrough_total", 1)
             self._note_path("generic per-row")
@@ -1566,34 +1624,44 @@ class Executor:
         ctx = _Ctx(index, self._shards_for(index, shards, None),
                    translate_output)
         try:
-            entry = self._plan_plane_entry(ctx, calls)
-            if entry is not None:
-                return entry
-            entry = self._plan_bsirange_entry(ctx, calls)
-            if entry is not None:
-                return entry
-            entry = self._plan_tree_entry(ctx, calls)
-            if entry is not None:
-                return entry
-            specs: list = []
-            deps: dict[tuple, None] = {}
-            depths: dict[str, tuple] = {}
-            nodes = []
-            for call in calls:
-                nodes.append(self._plan_spec(ctx, call.children[0],
-                                             specs, deps, depths))
+            entry = (self._plan_plane_entry(ctx, calls)
+                     or self._plan_bsirange_entry(ctx, calls)
+                     or self._plan_tree_entry(ctx, calls))
         except (Unfusable, ExecutionError):
-            # execution errors re-raise identically on the normal path;
-            # a later schema change that would make the query plannable
-            # is served (correctly) by the normal path forever — a
-            # perf-only conservatism
             return None
-        deps = tuple(deps)
-        return _PlanEntry("generic", ctx.shards, deps,
-                          self._dep_gens(index, deps, ctx.shards),
-                          len(calls), nodes=tuple(nodes),
-                          leaf_specs=tuple(specs),
-                          bsi_sigs=tuple(depths.items()))
+        if entry is not None and entry.kind == "bsirange":
+            return entry
+        specs: list = []
+        deps: dict[tuple, None] = dict.fromkeys(entry.deps) if entry else {}
+        depths: dict[str, tuple] = dict(entry.bsi_sigs) if entry else {}
+        try:
+            nodes = tuple(self._plan_spec(ctx, call.children[0], specs,
+                                          deps, depths)
+                          for call in calls)
+        except (Unfusable, ExecutionError):
+            # no per-row form (time ranges, ConstRow, data-dependent
+            # row sets): a plane / tree entry keeps its own form only.
+            # Without one either, execution errors re-raise identically
+            # on the normal path; a later schema change that would
+            # make the query plannable is served (correctly) by the
+            # normal path forever — a perf-only conservatism
+            return entry
+        if entry is None:
+            entry = _PlanEntry("generic", ctx.shards, (), (), len(calls))
+        elif entry.unkeyed_plane and not all(
+                spec[0] in _REFETCHED_LEAVES for spec in specs):
+            # the entry skips the per-hit generation compare: a per-row
+            # form may ride on it only where every leaf re-fetches
+            # through the plane cache (which revalidates each row's
+            # generations itself) or is a pure function of the query
+            # text under the pinned ``bsi_sigs``
+            return entry
+        # the per-row form, under the union of both forms' validity
+        entry.nodes, entry.leaf_specs = nodes, tuple(specs)
+        entry.deps = tuple(deps)
+        entry.gens = self._dep_gens(index, entry.deps, ctx.shards)
+        entry.bsi_sigs = tuple(depths.items())
+        return entry
 
     def _plan_plane_entry(self, ctx: _Ctx, calls) -> "_PlanEntry | None":
         """Match the same-field plain-row batch shape that
@@ -1855,10 +1923,25 @@ class Executor:
         i_neg = len(specs) - 1
         return ("bsi", i_plane, i_masks, i_neg, op_key)
 
-    def _leaves_from_specs(self, ctx: _Ctx, specs: tuple) -> list | None:
+    def _hit_gens(self, ctx: _Ctx, hit: _PlanHit | None, field: Field,
+                  view_name: str) -> tuple | None:
+        """The (field, view)'s generation tuple, swept once per hit
+        (None without one: the plane cache then sweeps for itself)."""
+        if hit is None:
+            return None
+        key = (field.name, view_name)
+        gens = hit.gens.get(key)
+        if gens is None:
+            gens = hit.gens[key] = self.planes.generations(
+                field, view_name, ctx.shards)
+        return gens
+
+    def _leaves_from_specs(self, ctx: _Ctx, specs: tuple,
+                           hit: _PlanHit | None = None) -> list | None:
         """Materialize plan-cached leaf specs through the plane cache
-        (each fetch revalidates its own generations).  None = a spec no
-        longer resolves (field gone) — caller invalidates."""
+        (each fetch revalidates its row's generations — against the
+        hit's one sweep of the view where ``hit`` is given).  None = a
+        spec no longer resolves (field gone) — caller invalidates."""
         out: list = []
         bsi_cache: dict = {}
         for spec in specs:
@@ -1868,8 +1951,9 @@ class Executor:
                 field = ctx.index.field(fname)
                 if field is None:
                     return None
-                out.append(self.planes.row_words(ctx.index.name, field,
-                                                 vname, rid, ctx.shards))
+                out.append(self.planes.row_words(
+                    ctx.index.name, field, vname, rid, ctx.shards,
+                    gens=self._hit_gens(ctx, hit, field, vname)))
             elif kind == "zeros":
                 out.append(self._zeros(ctx))
             elif kind == "exists":
@@ -1898,8 +1982,9 @@ class Executor:
                   translate_output: bool, tracer,
                   deadline: float | None) -> list | None:
         """Run a validated plan; None = not runnable right now (plane
-        not resident) — the caller falls through to the normal path,
-        keeping admission decisions there."""
+        not resident, and either admission has a say or the entry has
+        no per-row form) — the caller falls through to the normal
+        path, keeping admission decisions there."""
         ctx = _Ctx(index, entry.shards, translate_output,
                    deadline=deadline)
         ctx.check_deadline()
@@ -1916,14 +2001,20 @@ class Executor:
         return out
 
     def _run_plan_inner(self, ctx: _Ctx, entry: "_PlanEntry") -> list | None:
-        """A residency check that fails returns None with the clock
-        still in ``plan_cache``: an attempt that serves nothing is the
-        plan cache's cost.  ``plan`` is entered where the leaf fetch
-        begins."""
-        if entry.kind == "tree":
-            if not self.tree_fusion:  # knob flipped after caching
-                return None
-            return self._run_tree_specs(ctx, list(entry.tree_specs))
+        """Pick the entry's form by what can be observed.  A plane or
+        tree entry runs its whole-plane program where the plane is
+        resident (or delta-dirty and absorbable), exactly as before it
+        carried a second form.  Not resident, and the selectivity rule
+        (:meth:`_tiny_slice`, under the budget and no paging) says it
+        never will be: the per-row entries ARE the resident form, and
+        the entry's per-row form answers — same ``nodes`` as
+        :meth:`_plan` gives, same ``_dispatch_count_run`` tail, so the
+        same compiled program as the un-cached path.  Not resident and
+        the un-cached path would act (admit and build, page, refuse):
+        None.  A residency check that fails returns None with the
+        clock still in ``plan_cache``: an attempt that serves nothing
+        is the plan cache's cost.  ``plan`` is entered where the leaf
+        fetch begins."""
         if entry.kind == "bsirange":
             if self.batcher is None:  # knob flipped after caching
                 return None
@@ -1934,26 +2025,55 @@ class Executor:
                     return None
                 items.append((field, op_keys, offsets, operands))
             return self._run_bsirange_items(ctx, items)
-        if entry.kind == "plane":
-            field = ctx.index.field(entry.field_name)
-            if field is None:
-                return None
-            # residency only — admission (budget walks) stays on the
-            # un-cached path, exactly like _count_batch_plane
-            if not self.planes.has_plane(ctx.index.name, field,
-                                         VIEW_STANDARD, ctx.shards):
-                return None
-            ps = self.planes.field_plane_nowait(ctx.index.name, field,
-                                                VIEW_STANDARD, ctx.shards)
-            if ps is None:
-                return None
-            _stage("plan")
-            return self._plane_count_rows(ps, entry.row_ids)
+        hit = None
+        if entry.kind != "generic":
+            hit = _PlanHit() if entry.nodes else None
+            out = self._run_whole_plane(ctx, entry, hit)
+            if out is not None or hit is None or not hit.by_rule:
+                return out
         _stage("plan")  # leaf fetch through the plane cache
-        leaves = self._leaves_from_specs(ctx, entry.leaf_specs)
+        leaves = self._leaves_from_specs(ctx, entry.leaf_specs, hit)
         if leaves is None:
             return None
-        return self._dispatch_count_run(entry.nodes, tuple(leaves))
+        out = self._dispatch_count_run(entry.nodes, tuple(leaves))
+        if hit is not None:
+            self.stats.count("plan_cache_row_serves_total", 1)
+            self._note_path("plan-cached per-row")
+        return out
+
+    def _run_whole_plane(self, ctx: _Ctx, entry: "_PlanEntry",
+                         hit: "_PlanHit | None") -> list | None:
+        """A plane / tree entry's own program over the resident
+        whole-field plane.  None = not runnable right now; where the
+        entry has a per-row form, ``hit.by_rule`` says whether the
+        selectivity rule is why."""
+        if entry.kind == "tree":
+            if not self.tree_fusion:  # knob flipped after caching
+                return None
+            return self._run_tree_specs(ctx, list(entry.tree_specs), hit)
+        field = ctx.index.field(entry.field_name)
+        if field is None:
+            return None
+        # residency only — admission (budget walks) stays on the
+        # un-cached path, exactly like _count_batch_plane
+        if not self.planes.has_plane(ctx.index.name, field,
+                                     VIEW_STANDARD, ctx.shards):
+            if hit is not None:
+                est = self.planes.plane_bytes(
+                    field, VIEW_STANDARD, ctx.shards,
+                    gens=self._hit_gens(ctx, hit, field, VIEW_STANDARD))
+                hit.by_rule = (
+                    not self._paging_engaged(est)
+                    and est <= self.planes.budget
+                    and self._tiny_slice(est, len(ctx.shards),
+                                         len(entry.row_ids)))
+            return None
+        ps = self.planes.field_plane_nowait(ctx.index.name, field,
+                                            VIEW_STANDARD, ctx.shards)
+        if ps is None:
+            return None
+        _stage("plan")
+        return self._plane_count_rows(ps, entry.row_ids)
 
     def _shards_for(self, index: Index, shards,
                     call: Call | None) -> tuple[int, ...]:

@@ -1148,24 +1148,40 @@ class PlaneCache:
             row_cards=row_cards, shards=shards, nbytes=nbytes,
             n_rows_pad=r_pad)
 
+    def generations(self, field: Field, view_name: str,
+                    shards: tuple[int, ...]) -> tuple:
+        """One lock-free sweep of a view's fragment generations — what
+        a caller that fetches several rows of one view per request
+        reads ONCE and hands to :meth:`row_words` /
+        :meth:`plane_bytes` (``gens=``), instead of each of them
+        sweeping the shard axis again."""
+        return self._gens_fast(field, view_name, shards)
+
     def row_words(self, index: str, field: Field, view_name: str,
-                  row_id: int, shards: tuple[int, ...]) -> jax.Array:
+                  row_id: int, shards: tuple[int, ...],
+                  gens: tuple | None = None) -> jax.Array:
         """One row across shards: uint32[n_shards, W] (Row-call fast path —
-        avoids materializing the whole plane for wide fields)."""
+        avoids materializing the whole plane for wide fields).  With
+        ``gens`` (this request's own :meth:`generations` sweep) a
+        fresh resident row answers without a second sweep."""
         key = ("row", index, field.name, view_name, row_id, shards)
         ps = self._get(key, field, view_name, shards,
-                       lambda f, v, s: self._build_row(f, v, s, row_id))
+                       lambda f, v, s: self._build_row(f, v, s, row_id),
+                       gens=gens)
         return ps.plane
 
     def plane_bytes(self, field: Field, view_name: str,
-                    shards: tuple[int, ...]) -> int:
+                    shards: tuple[int, ...],
+                    gens: tuple | None = None) -> int:
         """Estimated dense-plane footprint (for budget decisions).
 
         Generation-cached: the estimate runs on EVERY query of the
         field (admission check), and recomputing it for a 5M-row
         sparse field measured ~7 s/query at 954 shards (config10 —
-        the same class as the r3 warm-path metadata fixes)."""
-        gens = self._gens(field, view_name, shards)
+        the same class as the r3 warm-path metadata fixes).  ``gens``
+        is the caller's own sweep of the view (:meth:`generations`)."""
+        if gens is None:
+            gens = self._gens(field, view_name, shards)
         key = (field.path, view_name, shards)
         with self._lock:
             hit = self._bytes_cache.get(key)
@@ -1347,7 +1363,8 @@ class PlaneCache:
             self._leases[tid] = lease | {key}
 
     def _get(self, key, field: Field, view_name: str,
-             shards: tuple[int, ...], build) -> PlaneSet:
+             shards: tuple[int, ...], build,
+             gens: tuple | None = None) -> PlaneSet:
         # cost-ledger plane attribution (r19): stamp the serving
         # thread with the plane this query is about to scan — one
         # thread-local write, nothing else on the fast path
@@ -1357,10 +1374,13 @@ class PlaneCache:
         # no cache lock, no view lock.  Delta-dirty entries never
         # return here: every _get caller needs a CLEAN plane (the
         # delta-aware consumers go through field_plane_nowait), so a
-        # pending overlay folds first.
+        # pending overlay folds first.  ``gens``: the caller's own
+        # sweep of the view for this request (:meth:`generations`),
+        # compared in place of another one.
         hit = self._entries.get(key)
-        if hit is not None and hit[0] == self._gens_fast(field, view_name,
-                                                         shards) \
+        if hit is not None and hit[0] == (
+                gens if gens is not None
+                else self._gens_fast(field, view_name, shards)) \
                 and getattr(hit[1], "delta", None) is None:
             self._touch(key)
             self._lease_fast(key)

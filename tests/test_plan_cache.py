@@ -328,3 +328,251 @@ def test_bsi_depth_growth_outside_shard_subset(tmp_path):
     assert e.execute("i", pql, shards=[0]) == [2]
     assert e.execute("i", pql) == [3]  # full-shard query sees all
     holder.close()
+
+
+# -- a cached plan that serves: the per-row form (PR 27) ----------------------
+#
+# A plane / tree entry whose whole-field plane is not resident — and, by
+# the selectivity rule (a request touching under a quarter of the
+# field's rows), never will be — answers by the per-row form it carries,
+# instead of falling through to be planned a second time.
+
+WIDE_ROWS = 32          # one or two of 32 rows: 4 or 8 < 32, per-row by rule
+
+
+def _wide(tmp_path, keys: bool = False, n_shards: int = 2, **kw):
+    """A ``WIDE_ROWS``-row field ``w`` (keyed: ``"k0"`` … ``"k31"``)
+    over ``n_shards`` shards beside a four-row field ``f``; returns
+    ``(holder, executor, truth)`` with ``truth[field][row]`` the set of
+    columns — the op-at-a-time reference is Python's set algebra."""
+    from pilosa_tpu.engine.words import SHARD_WIDTH
+    from pilosa_tpu.obs import Stats
+    holder = Holder(str(tmp_path)).open()
+    idx = holder.create_index("i")
+    idx.create_field("w", FieldOptions(keys=keys))
+    idx.create_field("f")
+    e = Executor(holder, stats=Stats(), **kw)
+    truth = {"w": {}, "f": {}}
+    sets = []
+    for r in range(WIDE_ROWS):
+        cols = {s * SHARD_WIDTH + (r * 7 + j * 5) % 97
+                for s in range(n_shards) for j in range(1 + r % 3)}
+        truth["w"][r] = cols
+        name = f'"k{r}"' if keys else r
+        sets += [f"Set({c}, w={name})" for c in sorted(cols)]
+    for r in range(4):
+        cols = {s * SHARD_WIDTH + r + 4 * j
+                for s in range(n_shards) for j in range(6)}
+        truth["f"][r] = cols
+        sets += [f"Set({c}, f={r})" for c in sorted(cols)]
+    e.execute("i", " ".join(sets))
+    return holder, e, truth
+
+
+def _row(keys: bool, r: int) -> str:
+    return f'Row(w="k{r}")' if keys else f"Row(w={r})"
+
+
+def _plan_counters(e) -> dict:
+    return {n: _counters(e, n) for n in (
+        "plan_cache_hits", "plan_cache_misses", "plan_cache_fallthrough_total",
+        "plan_cache_row_serves_total", "plan_cache_invalidations")}
+
+
+def _entry(e, pql: str):
+    return e._plans[("i", pql, None, True)]
+
+
+def _moved(before: dict, after: dict) -> dict:
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
+
+
+@pytest.mark.parametrize("shape", ["row", "intersect", "two_counts"])
+def test_tiny_slice_of_a_wide_field_is_served_by_the_cached_plan(
+        shape, tmp_path, monkeypatch):
+    """(a) One or two rows of a 32-row field: the second request is a
+    plan-cache hit answered by the entry's per-row form — no
+    fall-through, nothing planned or lowered again, the exact answer."""
+    from pilosa_tpu.exec import tree as treemod
+    holder, e, truth = _wide(tmp_path)
+    w = truth["w"]
+    pql, want = {
+        "row": ("Count(Row(w=3))", [len(w[3])]),
+        "intersect": ("Count(Intersect(Row(w=2), Row(w=5)))",
+                      [len(w[2] & w[5])]),
+        "two_counts": ("Count(Row(w=2)) Count(Row(w=8))",
+                       [len(w[2]), len(w[8])]),
+    }[shape]
+    assert e.execute("i", pql) == want        # builds the plan, serves it
+    assert e.serving_path() == "plan-cached per-row"
+    planned, lowered = [], []
+    real_plan, real_lower = e._plan, treemod.lower_count_tree
+    monkeypatch.setattr(e, "_plan", lambda *a, **k: (
+        planned.append(a), real_plan(*a, **k))[1])
+    monkeypatch.setattr(treemod, "lower_count_tree", lambda *a, **k: (
+        lowered.append(a), real_lower(*a, **k))[1])
+    before = _plan_counters(e)
+    assert e.execute("i", pql) == want
+    assert _moved(before, _plan_counters(e)) == {
+        "plan_cache_hits": 1, "plan_cache_row_serves_total": 1}
+    assert planned == [] and lowered == []
+    assert e.serving_path() == "plan-cached per-row"
+    assert e.planes.builds == 0               # no whole-plane build, ever
+    entry = _entry(e, pql)
+    assert entry.kind == ("tree" if shape == "intersect" else "plane")
+    assert entry.nodes and entry.leaf_specs
+    holder.close()
+
+
+@pytest.mark.parametrize("keys", [False, True], ids=["unkeyed", "keyed"])
+@pytest.mark.parametrize("shape", ["row", "intersect"])
+def test_a_set_between_two_row_served_hits_is_read_back(shape, keys,
+                                                        tmp_path):
+    """(b) An acknowledged write is read by the next hit.  The unkeyed
+    entry skips the per-hit generation compare and survives the write
+    (every leaf of its per-row form re-fetches through the plane cache,
+    which revalidates the row's generations); the keyed one is
+    generation-checked and re-plans."""
+    from pilosa_tpu.engine.words import SHARD_WIDTH
+    holder, e, truth = _wide(tmp_path, keys=keys)
+    w = truth["w"]
+    if shape == "row":
+        pql, want = f"Count({_row(keys, 3)})", len(w[3])
+    else:
+        pql = f"Count(Intersect({_row(keys, 2)}, {_row(keys, 5)}))"
+        want = len(w[2] & w[5])
+    assert e.execute("i", pql) == [want]
+    assert e.execute("i", pql) == [want]
+    assert e.serving_path() == "plan-cached per-row"
+    fresh = SHARD_WIDTH + 77                 # in neither row yet
+    assert fresh not in (w[2] | w[3] | w[5])
+    writes = " ".join(f"Set({fresh}, w={_row(keys, r)[6:-1]})"
+                      for r in ((3,) if shape == "row" else (2, 5)))
+    before = _plan_counters(e)
+    assert e.execute("i", writes) == [True] * (1 if shape == "row" else 2)
+    assert e.execute("i", pql) == [want + 1], "a hit served a stale count"
+    assert e.execute("i", pql) == [want + 1]
+    moved = _moved(before, _plan_counters(e))
+    assert "plan_cache_fallthrough_total" not in moved
+    if keys:    # generation-checked: the write drops the entry (that
+        #         request is answered un-cached); the next one re-plans
+        assert moved["plan_cache_invalidations"] == 1
+        assert moved["plan_cache_misses"] == 2   # the write, the re-plan
+        assert moved["plan_cache_row_serves_total"] == 1
+    else:       # survives: both requests after the write are hits
+        assert "plan_cache_invalidations" not in moved
+        assert moved["plan_cache_hits"] >= 2
+        assert moved["plan_cache_row_serves_total"] == 2
+    holder.close()
+
+
+def test_a_keyed_row_created_after_planning_surfaces(tmp_path):
+    """A ``zeros`` leaf (a key absent at planning time) stays
+    generation-checked in the per-row form: creating the key bumps the
+    view's generations and the entry re-plans."""
+    holder, e, truth = _wide(tmp_path, keys=True)
+    pql = 'Count(Union(Row(w="k1"), Row(w="nobody")))'
+    want = len(truth["w"][1])
+    assert e.execute("i", pql) == [want]
+    assert e.execute("i", pql) == [want]
+    assert e.serving_path() == "plan-cached per-row"
+    e.execute("i", 'Set(500, w="nobody")')
+    assert e.execute("i", pql) == [want + 1]
+    holder.close()
+
+
+@pytest.mark.parametrize("shape", ["two_counts", "intersect"])
+def test_a_quarter_of_the_rows_falls_through_once_then_runs_whole_plane(
+        shape, tmp_path):
+    """(c) A request touching at least a quarter of its field's rows is
+    an admission decision: the hit falls through once, the un-cached
+    path builds the whole-field plane, and from then on the entry's
+    own plane / tree program answers — never its per-row form."""
+    holder, e, truth = _wide(tmp_path)
+    e.planes.SYNC_BUILD_MAX = 0     # build in the background, as at size
+    f = truth["f"]
+    pql, want = {
+        "two_counts": ("Count(Row(f=1)) Count(Row(f=2))",
+                       [len(f[1]), len(f[2])]),
+        "intersect": ("Count(Union(Row(f=1), Row(f=2)))",
+                      [len(f[1] | f[2])]),
+    }[shape]
+    before = _plan_counters(e)
+    assert e.execute("i", pql) == want
+    assert e.serving_path() == "generic per-row"
+    e.planes.wait_builds()
+    assert _moved(before, _plan_counters(e)) == {
+        "plan_cache_misses": 1, "plan_cache_fallthrough_total": 1}
+    assert e.planes.builds == 1              # the whole-field plane
+    idx = holder.index("i")
+    assert e.planes.has_plane("i", idx.field("f"), "standard",
+                              e._shards_for(idx, None, None))
+    before = _plan_counters(e)
+    for _ in range(2):
+        assert e.execute("i", pql) == want
+        assert e.serving_path() == "fused"
+    assert _moved(before, _plan_counters(e)) == {"plan_cache_hits": 2}
+    holder.close()
+
+
+def test_a_plane_past_the_budget_still_pages(tmp_path):
+    """(d) est > budget: the rule does not apply, the hit falls through
+    and the un-cached path serves paged — admission stays there."""
+    holder, e, truth = _wide(tmp_path, n_shards=3,
+                             plane_budget=1200 * 1024,
+                             plane_page_bytes=1 << 20)
+    pql, want = "Count(Row(w=3))", [len(truth["w"][3])]
+    for _ in range(2):
+        before = _plan_counters(e)
+        assert e.execute("i", pql) == want
+        assert e.serving_path() == "paged"
+        moved = _moved(before, _plan_counters(e))
+        assert moved["plan_cache_fallthrough_total"] == 1
+        assert "plan_cache_row_serves_total" not in moved
+    assert e.tenancy_status()["pageIns"] >= 1
+    holder.close()
+
+
+@pytest.mark.parametrize("pql", [
+    "Count(Row(w=3))",
+    "Count(Intersect(Row(w=2), Row(w=5)))",
+    "Count(Union(Row(w=1), Not(Row(w=4))))",
+    "Count(Row(w=2)) Count(Difference(Row(w=8), Row(w=9)))",
+])
+def test_cached_nodes_are_the_uncached_plan(pql, tmp_path):
+    """(e) The cached ``nodes`` have the structure ``_plan`` gives the
+    un-cached path, so the programs it compiled are the ones a hit
+    runs: ``costs.compileCount`` does not move between the un-cached
+    answers and the cached ones.  (Three of each: the solo fast lane's
+    first dispatch has no retired output to donate, so one shape is two
+    programs on either path.)"""
+    from pilosa_tpu.exec.executor import _Ctx
+    from pilosa_tpu.exec.fused import shift_leaves
+    holder, e, _ = _wide(tmp_path)
+    idx = holder.index("i")
+    with_cache = e._execute_planned
+    e._execute_planned = lambda *a, **k: None           # un-cached
+    try:
+        want = e.execute("i", pql)
+        assert [e.execute("i", pql) for _ in range(2)] == [want] * 2
+    finally:
+        e._execute_planned = with_cache
+    assert e.serving_path() == "fused"
+    assert ("i", pql, None, True) not in e._plans
+    compiles = e.cost_status()["compileCount"]
+    assert compiles >= 1
+    assert [e.execute("i", pql) for _ in range(3)] == [want] * 3
+    assert _counters(e, "plan_cache_row_serves_total") == 3
+    assert e.serving_path() == "plan-cached per-row"
+    assert e.cost_status()["compileCount"] == compiles
+    ctx = _Ctx(idx, e._shards_for(idx, None, None))
+    nodes, n_leaves = [], 0
+    for call in parse_cached(pql).calls:
+        leaves: list = []
+        nodes.append(shift_leaves(e._plan(ctx, call.children[0], leaves),
+                                  n_leaves))
+        n_leaves += len(leaves)
+    entry = _entry(e, pql)
+    assert entry.nodes == tuple(nodes)
+    assert len(entry.leaf_specs) == n_leaves
+    holder.close()

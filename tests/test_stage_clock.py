@@ -299,6 +299,48 @@ def test_a_plan_whose_plane_is_not_resident_falls_through_once(tmp_path):
         holder.close()
 
 
+def test_a_plan_whose_field_stays_per_row_is_planned_once(tmp_path):
+    """The twin of the fall-through above: one row of a 32-row field is
+    a tiny slice of a huge row set, so the selectivity rule keeps the
+    field per-row and there is no admission decision to leave to the
+    un-cached path — the hit is answered by the entry's per-row form,
+    ``plan_cache`` and ``plan`` are entered once each, and nothing is
+    parsed."""
+    holder = Holder(str(tmp_path)).open()
+    try:
+        idx = holder.create_index("i")
+        idx.create_field("f")
+        stats = Stats()
+        ex = Executor(holder, stats=stats, count_batch_window="adaptive")
+        api = API(holder, ex)
+        api.import_bits("i", "f", row_ids=list(range(32)) + [1],
+                        col_ids=list(range(32)) + [99])
+
+        def counter(name):
+            return sum(stats.snapshot()["counters"].get(name, {}).values())
+        assert counter("plan_cache_row_serves_total") == 0  # registered
+        assert "plan_cache_row_serves_total 0" in stats.prometheus_text()
+        for pql, want in (("Count(Row(f=1))", [2]),
+                          ("Count(Intersect(Row(f=1), Row(f=2)))", [0])):
+            assert ex.execute("i", pql) == want     # builds + serves
+            before = _stages(stats)
+            hits0, falls0, serves0 = (counter("plan_cache_hits"),
+                                      counter("plan_cache_fallthrough_total"),
+                                      counter("plan_cache_row_serves_total"))
+            assert ex.execute("i", pql) == want
+            got = _delta(before, _stages(stats))
+            assert counter("plan_cache_hits") == hits0 + 1
+            assert counter("plan_cache_fallthrough_total") == falls0
+            assert counter("plan_cache_row_serves_total") == serves0 + 1
+            assert got["plan_cache"][0] == 1 and got["plan"][0] == 1
+            assert "parse" not in got
+            assert {"dispatch", "read", "assemble"} <= set(got)
+            assert ex.serving_path() == "plan-cached per-row"
+        assert ex.planes.builds == 0
+    finally:
+        holder.close()
+
+
 # -- (d) the second sink: the profiler's trace --------------------------------
 
 def _trace_events(trace_dir):
