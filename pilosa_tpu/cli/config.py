@@ -86,6 +86,12 @@ class Config:
     count_batch_window: str = "adaptive"
     query_timeout: float = 0.0         # seconds per query; 0 = unlimited
                                        # (?timeout= overrides per request)
+    # The plane cache's TOTAL over every device it places planes on,
+    # not one chip's share: under the mesh placement (mesh=true on a
+    # multi-chip host) a plane's bytes are spread evenly over the chips,
+    # so a four-chip v5e host that should hold 12 GiB of planes a chip
+    # sets 48 GiB (51539607552).  A plane estimated above the budget
+    # is never built: its queries take the streaming path.
     plane_budget_bytes: int = 4 << 30
     # Ingest delta planes (r15): writes to a resident whole-view plane
     # absorb into a bounded device-side overlay the query kernels

@@ -616,8 +616,15 @@ class Executor:
     def mesh_status(self) -> dict | None:
         """The ``/status`` ``mesh`` block (ISSUE 16): device count,
         shard axis, per-device resident plane bytes and padded-shard
-        count — None when serving single-device."""
-        return self.planes.mesh_stats()
+        count, and the launch lock's wait (``launchWait``: every
+        meshed launch's time from the call to ``_MESH_LAUNCH_LOCK``'s
+        acquisition) — None when serving single-device."""
+        block = self.planes.mesh_stats()
+        if block is not None:
+            block["launchWait"] = self.stats.histogram_summary(
+                "mesh_launch_wait_seconds").get(
+                    "total", {"count": 0, "sum": 0.0, "mean": 0.0})
+        return block
 
     def time_status(self) -> dict:
         """The ``/status`` ``timeViews`` block (r23): resident

@@ -82,20 +82,34 @@ def sharding_key(arr) -> object:
 _MESH_LAUNCH_LOCK = _threading.Lock()
 
 
-def mesh_serialized(fn):
+def mesh_serialized(fn, stats=None):
     """Wrap a meshed jitted program so launches serialize (and, on the
     CPU backend, complete) under ``_MESH_LAUNCH_LOCK``.  Applied at
     cache-insert time by ``FusedCache`` instances serving a placement,
     so every fused family — including the readback pack — flows
-    through the one choke point."""
+    through the one choke point.  ``stats`` counts every launch
+    (``mesh_launches_total``) and observes the time from the call to
+    the lock's acquisition (``mesh_launch_wait_seconds``, /status
+    ``mesh.launchWait``); while a capture is open the wait is also
+    ``pilosa.mesh.launch_wait`` in the profiler's trace."""
+    from pilosa_tpu.obs import NopStats
+    stats = stats or NopStats()
     drain = jax.default_backend() == "cpu"
 
     def call(*args, **kw):
-        with _MESH_LAUNCH_LOCK:
+        t0 = _time.perf_counter()
+        with _metrics.span("mesh.launch_wait"):
+            _MESH_LAUNCH_LOCK.acquire()
+        try:
+            stats.observe("mesh_launch_wait_seconds",
+                          _time.perf_counter() - t0)
+            stats.count("mesh_launches_total", 1)
             out = fn(*args, **kw)
             if drain:
                 jax.block_until_ready(out)
             return out
+        finally:
+            _MESH_LAUNCH_LOCK.release()
 
     return call
 
@@ -500,7 +514,7 @@ class FusedCache:
                 # part of the program, hence part of the key.
                 fn = jax.jit(build(), donate_argnums=donate)
                 if self._mesh_guard:
-                    fn = mesh_serialized(fn)
+                    fn = mesh_serialized(fn, self._stats)
                 fn = self._timed_first_call(key, fn)
                 self._insert(key, fn)
         return fn
@@ -866,7 +880,7 @@ class FusedCache:
             fn = fn.lower(*avatars).compile()
             dt = _time.perf_counter() - t0
             if self._mesh_guard:
-                fn = mesh_serialized(fn)
+                fn = mesh_serialized(fn, self._stats)
             self._insert(key, fn)
         return dt
 

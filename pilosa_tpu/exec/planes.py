@@ -361,7 +361,7 @@ class PlaneCache:
                     shards: tuple[int, ...]) -> PlaneSet:
         """Whole-view plane (TopN / Rows / GroupBy path)."""
         key = ("plane", index, field.name, view_name, shards)
-        build = (self._build_plane_meshed if self.placement is not None
+        build = (self._build_plane_chunked if self.placement is not None
                  else self._build_plane)
         return self._get(key, field, view_name, shards, build)
 
@@ -474,11 +474,10 @@ class PlaneCache:
             self.misses += 1
             return None
         if est <= self.SYNC_BUILD_MAX or self.placement is not None:
-            # small plane, or meshed placement: inline — meshed builds
-            # go through _build_plane_meshed (parallel expansion, one
-            # sharded device_put, the pipeline's build metrics); the
-            # chunked donated-update pipeline isn't wired for mesh
-            # shardings
+            # small plane, or meshed placement: inline, on the
+            # requesting thread — a meshed build is the chunked
+            # pipeline too (field_plane), but the streaming fallback a
+            # background build would answer through is not sharded
             return self.field_plane(index, field, view_name, shards)
         self.misses += 1
         with self._lock:
@@ -810,25 +809,62 @@ class PlaneCache:
             if misses:
                 self._stats.count("plane_cache_warm_misses_total", misses)
 
+    def _plane_accumulator(self, shape: tuple, axis: int):
+        """-> (all-zero device plane of ``shape``, donated
+        ``update(full, chunk, start)`` that writes ``chunk`` at offset
+        ``start`` along ``axis``).  Under a placement the plane is
+        born sharded and every device writes its own part of the
+        chunk into its own part of the plane (``shard_map``: offsets
+        are local, no collective, no gather)."""
+        import jax.numpy as jnp
+
+        def put(full, chunk, start):
+            at = [0] * len(shape)
+            at[axis] = start
+            return jax.lax.dynamic_update_slice(full, chunk, at)
+
+        p = self.placement
+        if p is None:
+            full = jnp.zeros(shape, dtype=jnp.uint32)
+        else:
+            from jax import shard_map
+            from jax.sharding import PartitionSpec
+            sharding = p.sharding(len(shape))
+            full = jnp.zeros(shape, dtype=jnp.uint32, device=sharding)
+            put = shard_map(put, mesh=p.mesh,
+                            in_specs=(sharding.spec, sharding.spec,
+                                      PartitionSpec()),
+                            out_specs=sharding.spec)
+        return full, jax.jit(put, donate_argnums=(0,))
+
     def _build_shard_chunks(self, field: Field, view_name: str,
                             shards: tuple[int, ...], row_ids: np.ndarray,
                             r_pad: int, slot_of: dict) -> PlaneSet:
         """Shard-major pipeline: each chunk is a group of whole shards,
         so every fragment expands exactly once (all rows, one native
-        call) and its dense sidecar is written/read in one piece."""
-        import jax.numpy as jnp
+        call) and its dense sidecar is written/read in one piece.
+
+        Under a placement the shard axis is cut into ``n`` equal runs,
+        one per device along it, and a chunk holds the same ``glen``
+        local shards of every run: one sharded ``device_put`` feeds
+        all the devices at once and each writes its block into its own
+        part of the plane (``shard_map``: no collective, no gather), so
+        the host never holds more than two chunks of a plane of any
+        size."""
         from concurrent.futures import ThreadPoolExecutor
         from functools import partial
 
         slab = r_pad * WORDS_PER_SHARD * 4
-        spc = max(1, min(len(shards), self.BUILD_CHUNK_BYTES // slab))
-        full = jnp.zeros((len(shards), r_pad, WORDS_PER_SHARD),
-                         dtype=jnp.uint32)
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def update(full, chunk, start):
-            return jax.lax.dynamic_update_slice(
-                full, chunk, (start, 0, 0))
+        p = self.placement
+        n = p.n_devices if p is not None else 1
+        if len(shards) % n:
+            raise ValueError(
+                f"plane build: {len(shards)} shards do not divide over "
+                f"a {n}-device shard axis (executor pads via placement)")
+        local = len(shards) // n   # shards of one device's run
+        spc = max(1, min(local, self.BUILD_CHUNK_BYTES // (slab * n)))
+        full, update = self._plane_accumulator(
+            (len(shards), r_pad, WORDS_PER_SHARD), axis=0)
 
         view = field.view(view_name)
         slots = np.arange(len(row_ids), dtype=np.uint64)
@@ -863,13 +899,13 @@ class PlaneCache:
         inflight: dict[int, object] = {}
         try:
             with ThreadPoolExecutor(max_workers=self.BUILD_WORKERS) as pool:
-                for gi, s0 in enumerate(range(0, len(shards), spc)):
-                    glen = min(spc, len(shards) - s0)
+                for gi, s0 in enumerate(range(0, local, spc)):
+                    glen = min(spc, local - s0)
                     par = gi % 2
                     buf = bufs.get((par, glen))
                     if buf is None:
                         buf = bufs[(par, glen)] = np.zeros(
-                            (glen, r_pad, WORDS_PER_SHARD), np.uint32)
+                            (n * glen, r_pad, WORDS_PER_SHARD), np.uint32)
                     else:
                         # reusing a staging buffer: its previous H2D
                         # copy must have completed — the placed chunk
@@ -879,15 +915,16 @@ class PlaneCache:
                         buf[:] = 0
                     tasks = []
                     if view is not None and len(row_ids):
-                        for li in range(glen):
-                            s = shards[s0 + li]
+                        for bi in range(n * glen):
+                            run, li = divmod(bi, glen)
+                            s = shards[run * local + s0 + li]
                             if s == PAD_SHARD:
                                 continue
                             frag = view.fragment(s)
                             if frag is None:
                                 continue
                             tasks.append(partial(
-                                frag.expand_rows_into, row_ids, buf[li],
+                                frag.expand_rows_into, row_ids, buf[bi],
                                 slots, sidecar=self.sidecars,
                                 sidecar_submit=submit))
                     self._expand_tasks(pool, tasks)
@@ -915,7 +952,6 @@ class PlaneCache:
         full row set (so images could never be written), and warm
         reads would re-open + re-crc the entire multi-hundred-MB image
         once per chunk — O(chunks × image bytes) of redundant work."""
-        import jax.numpy as jnp
         from concurrent.futures import ThreadPoolExecutor
         from functools import partial
 
@@ -924,13 +960,8 @@ class PlaneCache:
         # pow2 ≤ r_pad so chunks tile evenly — dynamic_update_slice
         # CLAMPS an out-of-bounds start, which would misplace the tail
         block = min(r_pad, 1 << max(0, block.bit_length() - 1))
-        full = jnp.zeros((len(shards), r_pad, WORDS_PER_SHARD),
-                         dtype=jnp.uint32)
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def update(full, chunk, start):
-            return jax.lax.dynamic_update_slice(
-                full, chunk, (0, start, 0))
+        full, update = self._plane_accumulator(
+            (len(shards), r_pad, WORDS_PER_SHARD), axis=1)
 
         view = field.view(view_name)
         bufs: list = [None, None]
@@ -1895,52 +1926,6 @@ class PlaneCache:
         self._stats.count("plane_build_bytes_total", host.nbytes)
         return ps
 
-    def _build_plane_meshed(self, field: Field, view_name: str,
-                            shards: tuple[int, ...]) -> PlaneSet:
-        """Meshed inline build (ISSUE 16 satellite): fragments expand
-        CONCURRENTLY on the builder pool (native decode straight into
-        the host slab, dense sidecars honored) and the slab lands in
-        ONE sharded ``device_put`` — the chunked donated-update
-        pipeline assumes a single-device layout, so meshed builds get
-        their own path that still pays into the PR 5 build telemetry
-        (``plane_build_seconds``/``plane_build_bytes_total``) instead
-        of bypassing it silently."""
-        import time as _time
-        from concurrent.futures import ThreadPoolExecutor
-        from functools import partial
-        t0 = _time.perf_counter()
-        view = field.view(view_name)
-        row_ids = self._union_row_ids(field, view_name, shards)
-        r_pad = _pow2(max(1, len(row_ids)))
-        host = np.zeros((len(shards), r_pad, WORDS_PER_SHARD),
-                        dtype=np.uint32)
-        slot_of = {int(r): i for i, r in enumerate(row_ids)}
-        slots = np.arange(len(row_ids), dtype=np.uint64)
-        tasks = []
-        if view is not None and len(row_ids):
-            for si, s in enumerate(shards):
-                if s == PAD_SHARD:
-                    continue  # padding stays all-zero words
-                frag = view.fragment(s)
-                if frag is None:
-                    continue
-                tasks.append(partial(
-                    frag.expand_rows_into, row_ids, host[si], slots,
-                    sidecar=self.sidecars))
-        if tasks:
-            with ThreadPoolExecutor(
-                    max_workers=self.BUILD_WORKERS) as pool:
-                self._expand_tasks(pool, tasks)
-        ps = PlaneSet(self.place(host), shards, row_ids, slot_of)
-        dt = _time.perf_counter() - t0
-        with self._lock:
-            self.builds += 1
-            self.build_seconds_total += dt
-            self.build_bytes_total += host.nbytes
-        self._stats.observe("plane_build_seconds", dt)
-        self._stats.count("plane_build_bytes_total", host.nbytes)
-        return ps
-
     def mesh_stats(self) -> dict | None:
         """/status ``mesh`` block (ISSUE 16): device count, shard
         axis, per-device resident plane bytes, padded-shard count —
@@ -1976,7 +1961,12 @@ class PlaneCache:
         axis = getattr(p, "axis", None) or getattr(p, "shard_axis",
                                                    "shard")
         return {"devices": n_dev, "axis": axis,
-                "perDeviceBytes": per_dev, "paddedShards": padded}
+                "perDeviceBytes": per_dev,
+                # scalars beside the dict: the fullest and the emptiest
+                # chip, for readers that cannot index by device name
+                "maxDeviceBytes": max(per_dev.values(), default=0),
+                "minDeviceBytes": min(per_dev.values(), default=0),
+                "paddedShards": padded}
 
     def _build_bsi(self, field: Field, view_name: str,
                    shards: tuple[int, ...]) -> PlaneSet:
