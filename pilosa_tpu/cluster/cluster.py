@@ -1179,7 +1179,7 @@ class Cluster:
                 with frag.lock:
                     if not check():
                         return False
-                    view_obj.fragments.pop(shard, None)
+                    view_obj.remove_fragment(shard)
                     path = frag.path
                     frag.close()
                     for suffix in ("", ".oplog"):

@@ -534,6 +534,9 @@ class Executor:
         # at 5k qps, BENCH_r05)
         self._plans: OrderedDict = OrderedDict()
         self._plans_lock = threading.Lock()
+        # index name -> (its kept shard tuple, that tuple as served:
+        # (0,) when empty, padded under a placement) — see _shards_for
+        self._served_shards: dict[str, tuple] = {}
         # cross-query OOM recovery (r4 → r5): one recovery at a time
         # through the gate; the in-flight count lets the exclusive
         # stage drain concurrent queries instead of evicting the
@@ -1581,7 +1584,8 @@ class Executor:
         # entries skip the generation compare (nothing in them can
         # stale; the PlaneSet revalidates independently) so the plan
         # cache keeps hitting under sustained ingest.
-        if (self._shards_for(index, shards, None) != entry.shards
+        now = self._shards_for(index, shards, None)
+        if ((now is not entry.shards and now != entry.shards)
                 or (not entry.unkeyed_plane
                     and self._dep_gens(index, entry.deps,
                                        entry.shards) != entry.gens)
@@ -2084,6 +2088,11 @@ class Executor:
 
     def _shards_for(self, index: Index, shards,
                     call: Call | None) -> tuple[int, ...]:
+        """The shards a call covers: ``Options(shards=[...])``, else an
+        explicit ``shards`` argument, else the index's kept shard set —
+        the same tuple object on every call until a write changes the
+        set (``Index.available_shards``), padded for a placement once
+        per such change."""
         opts = (call.args.get("shards")
                 if call is not None and call.name == "Options" else None)
         if opts is not None:
@@ -2092,10 +2101,19 @@ class Executor:
             out = tuple(shards)
         else:
             avail = index.available_shards()
-            out = tuple(avail) if avail else (0,)
-        if self.placement is not None:
-            out = self.placement.pad_shards(out)
-        return out
+            if avail and self.placement is None:
+                return avail
+            served = self._served_shards.get(index.name)
+            if served is None or served[0] is not avail:
+                served = (avail, self._pad(avail or (0,)))
+                self._served_shards[index.name] = served
+            return served[1]
+        return self._pad(out)
+
+    def _pad(self, shards: tuple[int, ...]) -> tuple[int, ...]:
+        if self.placement is None:
+            return shards
+        return self.placement.pad_shards(shards)
 
     # ------------------------------------------------------------- dispatch
 

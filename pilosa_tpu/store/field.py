@@ -25,6 +25,7 @@ import numpy as np
 
 from pilosa_tpu.engine.bsi import EXISTS_ROW, OFFSET_ROW, SIGN_ROW
 from pilosa_tpu.store import timeq
+from pilosa_tpu.store.fragment import no_index
 from pilosa_tpu.store.view import VIEW_BSI_PREFIX, VIEW_STANDARD, View
 
 TYPE_SET = "set"
@@ -87,7 +88,7 @@ class FieldOptions:
 class Field:
     def __init__(self, path: str, index_name: str, name: str,
                  options: FieldOptions | None = None, *, fsync: bool = False,
-                 snapshot_submit=None, health=None):
+                 snapshot_submit=None, health=None, shards_changed=None):
         self.path = path
         self.index_name = index_name
         self.name = name
@@ -95,6 +96,8 @@ class Field:
         self.fsync = fsync
         self.snapshot_submit = snapshot_submit
         self.health = health
+        # the index's shard-set epoch bump, handed on to every view
+        self.shards_changed = shards_changed or no_index
         self.views: dict[str, View] = {}
         self._row_attrs = None
         self._lock = threading.RLock()
@@ -112,7 +115,8 @@ class Field:
                 v = View(os.path.join(views_dir, name), name,
                          fsync=self.fsync,
                          snapshot_submit=self.snapshot_submit,
-                         health=self.health)
+                         health=self.health,
+                         shards_changed=self.shards_changed)
                 self.views[name] = v.open()
         return self
 
@@ -160,8 +164,13 @@ class Field:
                 v = View(os.path.join(self.path, "views", name), name,
                          fsync=self.fsync,
                          snapshot_submit=self.snapshot_submit,
-                         health=self.health).open()
+                         health=self.health,
+                         shards_changed=self.shards_changed).open()
                 self.views[name] = v
+                # AFTER the insert (time-quantum views land here too):
+                # the view's own bump in open() came before any walk
+                # could reach it
+                self.shards_changed()
             return v
 
     @property

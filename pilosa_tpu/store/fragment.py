@@ -42,11 +42,17 @@ HASH_BLOCK_SIZE = 100
 _SW = np.uint64(SHARD_WIDTH)
 
 
+def no_index() -> None:
+    """``shards_changed`` of a fragment, view or field that no index
+    holds (unit tests build them bare)."""
+
+
 class Fragment:
     """Bits of one (field, view, shard)."""
 
     def __init__(self, path: str, shard: int, *, max_op_n: int = MAX_OP_N,
-                 fsync: bool = False, snapshot_submit=None, health=None):
+                 fsync: bool = False, snapshot_submit=None, health=None,
+                 shards_changed=None):
         self.path = path                      # snapshot file
         self.shard = shard
         self.max_op_n = max_op_n
@@ -58,6 +64,15 @@ class Fragment:
         # (reference: the fragment snapshot queue in holder.go) instead
         # of running inline on the write path
         self._snapshot_submit = snapshot_submit
+        # called (no arguments) whenever ``present`` flips either way:
+        # the index's shard-set epoch bump, threaded down the same way
+        self._shards_changed = shards_changed or no_index
+        # does this fragment hold any row?  KEPT, not computed: every
+        # method that can empty or fill the three row tiers ends in
+        # _sync_presence() under the fragment lock, so lock-free
+        # readers (the index's shard walk, backup inventory) never see
+        # the transient emptiness inside a flush or a compaction
+        self.present = False
         self.rows: dict[int, RowBits] = {}    # materialized/overlay rows
         self.op_n = 0
         self.generation = 0                   # bumped per mutation; device
@@ -129,6 +144,7 @@ class Fragment:
                 self._apply(op, aux, positions)
                 self.op_n += 1
             self._open = True
+            self._sync_presence()
         return self
 
     # r19 snapshot frame: versioned header + crc32 of the roaring blob
@@ -215,6 +231,7 @@ class Fragment:
             self.generation += 1
             self._recent.clear()
             self._recent.append((self.generation, None))
+            self._sync_presence()
 
     def _mark_corrupt(self, kind: str, detail: str) -> None:
         """Quarantine this fragment after an end-to-end checksum (or
@@ -224,6 +241,7 @@ class Fragment:
         empty beats silently-wrong bits."""
         self._drop_snapshot()
         self._snap_crc = None
+        self._sync_presence()
         h = self._health
         if h is not None:
             h.quarantine(self.path, kind, detail)
@@ -338,6 +356,7 @@ class Fragment:
             self._drop_snapshot()
             self._oplog.close()
             self._open = False
+            self._sync_presence()
 
     # -- reads --------------------------------------------------------------
 
@@ -378,16 +397,20 @@ class Fragment:
             out[len(live) + len(self._snap_pending):] = pend
             return out
 
-    @property
-    def present(self) -> bool:
-        """Cheap row-presence check WITHOUT expanding snapshot bits:
-        overlay rows, rows still resident in the mmap'd snapshot, or
-        pending-tier bits.  (``rows`` alone misses lazily-opened
+    def _sync_presence(self) -> None:
+        """Bring ``present`` in line with the row tiers — overlay rows,
+        rows still resident in the mmap'd snapshot, pending-tier bits;
+        nothing is expanded (``rows`` alone misses lazily-opened
         snapshot fragments — a cold-reopened multi-shard index would
         report no shards and queries would silently cover only
-        shard 0.)"""
-        return (bool(self.rows) or bool(self._snap_pending)
-                or len(self._pend_pos) > 0)
+        shard 0) — and tell the index when it flipped.  O(1); callers
+        hold the lock and call it BEFORE the write is acknowledged, so
+        the next ``Index.available_shards()`` walks again."""
+        now = (bool(self.rows) or bool(self._snap_pending)
+               or len(self._pend_pos) > 0)
+        if now != self.present:
+            self.present = now
+            self._shards_changed()
 
     def max_row_id(self) -> int:
         ids = self.row_ids()
@@ -894,6 +917,7 @@ class Fragment:
             if changed:
                 self.generation += 1
                 self._note_delta(delta)
+                self._sync_presence()
                 self._log(op, 0, np.concatenate(parts))
             return changed
 
@@ -1006,6 +1030,7 @@ class Fragment:
                 # state over the good file): fall back to eager load
                 # from the blob just written
                 self._load_positions(roaring.deserialize(blob))
+            self._sync_presence()
             self._oplog.truncate()
             self.op_n = 0
             # compaction preserves CONTENT, so a sidecar that matched
@@ -1186,6 +1211,7 @@ class Fragment:
                 if len(new):
                     self.generation += 1
                     self._note_delta_positions(new)
+                    self._sync_presence()
                 return len(new)
             # probe cache over cap: classic per-row path below
         # every classic path below mutates merged truth: a probe cache
@@ -1253,6 +1279,7 @@ class Fragment:
         if changed:
             self.generation += 1
             self._note_delta(delta)
+        self._sync_presence()
         return changed
 
     def _check_rows(self, positions: np.ndarray) -> None:
@@ -1303,6 +1330,7 @@ class Fragment:
             self.op_n = 0
             self._load_positions(positions)
             self.generation += 1
+            self._sync_presence()
             # device-plane journals cannot describe a wholesale
             # replacement: force the rebuild path
             self._recent.clear()

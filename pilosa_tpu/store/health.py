@@ -185,6 +185,12 @@ class StorageHealth:
     def close(self) -> None:
         self._stop.set()
 
+    def count(self, name: str, value: float = 1) -> None:
+        """Count into the registry the server wired: the store's one
+        way to ``/metrics`` (``Index`` counts its shard-set rebuilds
+        through it)."""
+        self._stats.count(name, value)
+
     # -- quarantine registry --------------------------------------------------
 
     def key_of_path(self, path: str) -> tuple | None:

@@ -4,6 +4,18 @@ Reference: ``index.go`` (SURVEY.md §3.1) — per-index options ``keys`` and
 ``trackExistence``; when existence is tracked, an internal ``_exists``
 field (one row, row 0) records which columns exist, enabling ``Not`` and
 ``All`` (``executor.go#executeNot``).
+
+The shard set — which shards hold any row of any field — is KEPT, not
+walked per query: ``available_shards`` returns one immutable sorted
+tuple until the shard-set epoch moves.  Whatever can change the answer
+bumps the epoch AFTER the change is visible to a walk and BEFORE the
+write that caused it is acknowledged: a fragment whose ``present``
+flips either way (``Fragment._sync_presence``), a fragment entering or
+leaving a view (``View.fragment(create=True)``, ``View.open``,
+``View.remove_fragment``), a view entering a field
+(``Field.view(create=True)``), ``create_field`` / ``delete_field``,
+``open``.  A new mutation path follows the same rule or the served
+reads go stale.
 """
 
 from __future__ import annotations
@@ -36,6 +48,12 @@ class Index:
         self.fields: dict[str, Field] = {}
         self._column_attrs = None
         self._lock = threading.RLock()
+        # the kept shard set: (epoch it was walked at, sorted tuple).
+        # The epoch lock is a leaf — bumps arrive holding fragment,
+        # view or field locks and take nothing else under it
+        self._shard_epoch = 0
+        self._shard_epoch_lock = threading.Lock()
+        self._shard_set: tuple[int, tuple[int, ...]] = (-1, ())
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -53,9 +71,11 @@ class Index:
                 self.fields[entry] = Field(
                     fpath, self.name, entry, fsync=self.fsync,
                     snapshot_submit=self.snapshot_submit,
-                    health=self.health).open()
+                    health=self.health,
+                    shards_changed=self.shards_changed).open()
         if self.track_existence and EXISTENCE_FIELD not in self.fields:
             self._create_existence()
+        self.shards_changed()
         return self
 
     def save_meta(self) -> None:
@@ -87,10 +107,12 @@ class Index:
             f = Field(os.path.join(self.path, name), self.name, name,
                       options, fsync=self.fsync,
                       snapshot_submit=self.snapshot_submit,
-                      health=self.health)
+                      health=self.health,
+                      shards_changed=self.shards_changed)
             os.makedirs(f.path, exist_ok=True)
             f.save_meta()
             self.fields[name] = f
+            self.shards_changed()
             return f
 
     def ensure_field(self, name: str, options: FieldOptions | None = None) -> Field:
@@ -106,6 +128,7 @@ class Index:
             f = self.fields.pop(name, None)
             if f is None:
                 raise KeyError(name)
+            self.shards_changed()
             f.close()
             shutil.rmtree(f.path, ignore_errors=True)
 
@@ -137,11 +160,39 @@ class Index:
             ef.import_bits(np.zeros(len(cols), np.uint64),
                            np.asarray(cols, np.uint64))
 
-    def available_shards(self) -> list[int]:
+    def shards_changed(self) -> None:
+        """Bump the shard-set epoch: the next ``available_shards``
+        walks again.  Handed down to every field, view and fragment
+        at construction, like ``snapshot_submit`` and ``health``."""
+        with self._shard_epoch_lock:
+            self._shard_epoch += 1
+
+    def available_shards(self) -> tuple[int, ...]:
+        """Shards in which any field holds a row, sorted — the SAME
+        tuple object until the epoch moves, so the executor's contexts,
+        plan entries and plane-cache keys share it."""
+        epoch, kept = self._shard_set
+        if epoch == self._shard_epoch:
+            return kept
+        # the epoch is read BEFORE the walk: a bump that lands while
+        # the walk runs leaves what is stored stale-marked, never
+        # fresh-marked
+        epoch = self._shard_epoch
+        shards = self.walk_shards()
+        if shards == kept:
+            shards = kept  # most bumps change nothing: keep the object
+        if self.health is not None:
+            self.health.count("shard_set_rebuilds_total")
+        self._shard_set = (epoch, shards)
+        return shards
+
+    def walk_shards(self) -> tuple[int, ...]:
+        """The shard set asked of every fragment of every view of
+        every field: the one slow path, taken once per epoch."""
         shards: set[int] = set()
-        for f in self.fields.values():
+        for f in list(self.fields.values()):
             shards.update(f.available_shards())
-        return sorted(shards)
+        return tuple(sorted(shards))
 
     # -- write facade (used by API/executor) --------------------------------
 

@@ -11,7 +11,7 @@ from __future__ import annotations
 import os
 import threading
 
-from pilosa_tpu.store.fragment import Fragment
+from pilosa_tpu.store.fragment import Fragment, no_index
 
 VIEW_STANDARD = "standard"
 VIEW_BSI_PREFIX = "bsi_"
@@ -19,12 +19,16 @@ VIEW_BSI_PREFIX = "bsi_"
 
 class View:
     def __init__(self, path: str, name: str, *, fsync: bool = False,
-                 snapshot_submit=None, health=None):
+                 snapshot_submit=None, health=None, shards_changed=None):
         self.path = path  # <field>/views/<name>
         self.name = name
         self.fsync = fsync
         self.snapshot_submit = snapshot_submit
         self.health = health  # disk-health governor (r19), holder's
+        # the index's shard-set epoch bump: a fragment calls it when
+        # its presence flips, the view after a fragment enters or
+        # leaves ``fragments``
+        self.shards_changed = shards_changed or no_index
         self.fragments: dict[int, Fragment] = {}
         self._lock = threading.RLock()
 
@@ -44,8 +48,10 @@ class View:
                 frag = Fragment(os.path.join(frag_dir, str(shard)), shard,
                                 fsync=self.fsync,
                                 snapshot_submit=self.snapshot_submit,
-                                health=self.health)
+                                health=self.health,
+                                shards_changed=self.shards_changed)
                 self.fragments[shard] = frag.open()
+        self.shards_changed()
         return self
 
     def fragment(self, shard: int, create: bool = False) -> Fragment | None:
@@ -56,8 +62,23 @@ class View:
                 os.makedirs(os.path.dirname(path), exist_ok=True)
                 frag = Fragment(path, shard, fsync=self.fsync,
                                 snapshot_submit=self.snapshot_submit,
-                                health=self.health).open()
+                                health=self.health,
+                                shards_changed=self.shards_changed).open()
                 self.fragments[shard] = frag
+                # AFTER the insert: open() may have replayed bits in
+                # (and bumped) while no walk could see the fragment yet
+                self.shards_changed()
+            return frag
+
+    def remove_fragment(self, shard: int) -> Fragment | None:
+        """Take a fragment out of the view (the caller closes it and
+        unlinks its files).  The one way out of ``fragments``: a bare
+        ``fragments.pop`` would leave the index's kept shard set
+        counting the shard."""
+        with self._lock:
+            frag = self.fragments.pop(shard, None)
+            if frag is not None:
+                self.shards_changed()
             return frag
 
     def available_shards(self) -> list[int]:

@@ -717,7 +717,7 @@ class TestColdReopenShardDiscovery:
         h2 = Holder(str(tmp_path)).open()
         try:
             idx2 = h2.index("i")
-            assert idx2.available_shards() == [0, 1, 2]
+            assert idx2.available_shards() == (0, 1, 2)
             # end-to-end: a shard-unrestricted Count must cover them all
             from pilosa_tpu.exec import Executor
             ex = Executor(h2)
