@@ -12,9 +12,9 @@ program already exposes (``/status``, ``?profile=true``, ``/debug/slow``,
 ``/metrics``), so a pass with the device idle is not possible.
 
 One process per chip: this script is the client and the oracle and never
-initialises a JAX backend.  Each leg (cold boot + writes, warm restart,
-Pallas tier) is one server child that owns the chip alone and is stopped
-with SIGTERM before the next starts.
+initialises a JAX backend.  Each leg (cold boot + writes, warm restart)
+is one server child that owns the chip alone and is stopped with SIGTERM
+before the next starts.
 
     python chip_smoke.py                 # one chip, the whole smoke
     python chip_smoke.py --chips 4       # one index meshed over a host
@@ -308,18 +308,6 @@ class Server:
     def status(self) -> dict:
         return json.loads(self.request("/status", timeout=60))
 
-    def metric_total(self, name: str) -> float:
-        """Sum of one counter's series in the Prometheus text."""
-        total = 0.0
-        for line in self.request("/metrics", timeout=60).decode() \
-                .splitlines():
-            if line.startswith("#"):
-                continue
-            series, _, value = line.rpartition(" ")
-            if series.split("{", 1)[0].endswith(name):
-                total += float(value)
-        return total
-
     def wait_up(self, timeout: float = 300.0) -> None:
         deadline = time.monotonic() + timeout
         while True:
@@ -447,8 +435,6 @@ def assert_healthy(srv: Server, status: dict) -> None:
         "tenancy.residentPages": (ten.get("residentPages", 0), 0),
         "tenancy.oracleServes": (ten.get("oracleServes", 0), 0),
         "admission.shedTotal": (status["admission"]["shedTotal"], 0),
-        "pallas_fallback_total":
-            (srv.metric_total("pallas_fallback_total"), 0),
     }
     bad = {k: got for k, (got, want) in facts.items() if got != want}
     if bad:
@@ -868,26 +854,6 @@ def main() -> int:
             raise AssertionError(
                 f"warm boot compiled {len(new)} programs the cold boot "
                 f"had not cached: {new[:8]}")
-
-        # -- leg 3: the Pallas tier (one chip; a placement refuses it) ----
-        if device["count"] == 1:
-            env = {"PILOSA_KERNEL_TIER": "pallas"}
-            if args.rehearse and device["platform"] != "tpu":
-                env["PILOSA_PALLAS_INTERPRET"] = "1"
-            srv = boot("pallas", **env)
-            ph = report["phases"]["pallas"]
-            st = serve_pass(srv, after, args.shards, ph)
-            tier = st["deviceHealth"]["kernelTier"]
-            want_tier = ("pallas-interpret"
-                         if "PILOSA_PALLAS_INTERPRET" in env else "pallas")
-            if tier != want_tier:
-                raise AssertionError(
-                    f"pallas leg served tier {tier!r}, not {want_tier!r}")
-            ph["kernelTier"] = tier
-            say(f"  kernel tier {tier}, pallas_fallback_total 0")
-            stop(srv)
-            ph["cache_entries_new"] = len(
-                cache_entries(cache_dir) - cache_1)
     finally:
         for s in servers:
             s.stop()

@@ -813,7 +813,7 @@ class API:
                 # ingest visibility (r15): device delta overlays
                 # (fill %, compaction backlog + last duration) and
                 # bulk-import volume — the mixed read/write serving
-                # pane (bench/config26)
+                # pane
                 "ingest": {
                     "deltaFillRatio": delta.get("deltaFillRatio", 0.0),
                     "deltaCells": delta.get("deltaCells", 0),
@@ -831,7 +831,7 @@ class API:
                 # self-healing pipeline visibility (r18): governor
                 # state (healthy/degraded/probing), watchdog knob,
                 # quarantine counts — the serving-through-a-sick-device
-                # pane (bench/config28)
+                # pane
                 "deviceHealth": ex.device_health(),
                 # mesh serving (ISSUE 16): device count, shard axis,
                 # per-device resident plane bytes, padded shards —
@@ -889,7 +889,7 @@ class API:
                 "timeViews": ex.time_status(),
                 # per-stage overhead attribution (parse/plan/admit/
                 # dispatch/read/assemble) — the diagnostics dump behind
-                # bench/config18's concurrency-gap breakdown
+                # a concurrency-gap breakdown
                 "queryStages": self.executor.stats.histogram_summary(
                     "query_stage_seconds")}
 

@@ -56,7 +56,7 @@ class Client:
     keep-alive connection out of a small idle pool (concurrent callers
     each get their own; at most ``MAX_IDLE`` are kept) — the cluster
     fan-out previously paid a fresh TCP handshake per internode RPC
-    (config12 r4 measured ~1.2 ms/node; connection reuse is the first
+    (r4 measured ~1.2 ms/node; connection reuse is the first
     lever the r4 verdict named)."""
 
     MAX_IDLE = 8

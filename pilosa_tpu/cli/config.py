@@ -139,22 +139,6 @@ class Config:
     # admit ONE window back onto the fused pipeline as a probe;
     # success restores healthy serving.
     device_health_probe_seconds: float = 5.0
-    # Serving kernel tier (r24): "xla" (default) compiles every fused
-    # family through the XLA oracle tier; "pallas" routes the
-    # whole-plane scans (count chains, filtered row-count reduces —
-    # delta-overlay variants included) through hand-written Pallas TPU
-    # kernels.  "pallas" is a start-up error on a non-TPU backend and
-    # under a mesh placement (set mesh=false).  A shape whose Pallas
-    # program fails to compile serves XLA, logged at ERROR with the
-    # compiler's message and counted in pallas_fallback_total;
-    # degraded serving always runs the per-item XLA fallback.
-    kernel_tier: str = "xla"
-    # On-device dispatch loops (r24): the batcher collapses a
-    # collection window's same-shape selected-count groups into ONE
-    # jitted fori_loop/scan dispatch over stacked operands instead of
-    # one program launch per group (dispatch_loop_iters histogram
-    # proves the collapse; per-item fallback covers failures).
-    dispatch_loop_fusion: bool = False
     # Compile-ladder warm-up (r24): when a plane becomes resident, a
     # background single-flight warmer pre-compiles the delta-aware
     # fused program ladder (one program per pow2 overlay bucket per
@@ -208,7 +192,7 @@ class Config:
     # concurrent device scratch; 0 = off).  Size against HBM headroom:
     # resident planes (plane_budget_bytes) + slots × ~0.5 GB scratch
     # must fit the chip — at an 8 GB budget on a 16 GB chip, 16 slots
-    # measurably OOM'd and 6 served cleanly (bench/config14 r5).
+    # measurably OOM'd and 6 served cleanly (r5).
     max_concurrent_queries: int = 8
     max_map_count: int = 32768          # live snapshot mmaps before LRU
                                         # heap demotion (syswrap parity)

@@ -72,7 +72,7 @@ class Cluster:
         # placement only advances when a resize job has finished
         # streaming fragments for the new set — otherwise a joining
         # node instantly "owns" shards whose data hasn't arrived and
-        # queries silently undercount (config17 r5).
+        # queries silently undercount (r5).
         self.placement_ids: list[str] = [self.node_id]
         # monotonic (wall-clock) version of the ACTIVE placement: rides
         # every heartbeat both ways, so a node that missed the one
@@ -1281,7 +1281,7 @@ class Cluster:
             if e.status == 404:
                 # peer lost the whole fragment (or never had it): that
                 # is maximal divergence, not "peer down" — diff against
-                # empty so every block streams over (config17 r5: the
+                # empty so every block streams over (r5: the
                 # swallowed 404 left deleted replicas unrepaired)
                 theirs = {}
             else:

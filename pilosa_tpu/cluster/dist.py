@@ -171,7 +171,7 @@ class DistributedExecutor:
                 # query per node — a 32-Count batch costs (nodes-1)
                 # RPCs, not 32*(nodes-1) (reference: executor.go runs
                 # the whole query per shard in one mapReduce; per-call
-                # fan-out was the r5 config12 finding, +80 ms/request
+                # fan-out was an r5 finding, +80 ms/request
                 # at 4 nodes)
                 if self._batchable(call):
                     j = i

@@ -24,8 +24,8 @@ import jax
 # JAX_COMPILATION_CACHE_DIR, which jax reads itself — then no code sets
 # a directory.  Otherwise it lives at a FIXED path under the checkout:
 # the path is part of what a warm start needs to find again, so never a
-# temp name, pid or time.  Server children, bench.py and chip_smoke.py
-# all import this module and therefore share one cache.  The two
+# temp name, pid or time.  Every server child imports
+# this module, so all of them share one cache.  The two
 # thresholds drop to "persist everything": the serving program set is
 # small and every entry saves a first-query compile on the next boot.
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(

@@ -271,7 +271,7 @@ def combine_min_max(out: dict) -> list[tuple[int, int, int, int]]:
 # Shards per distinct_presence scan step: bounds the program's scratch
 # (per-column decoded values are 4 B/col — an UNBLOCKED expansion of a
 # 1B-col field materialized ~4 GB values + ~9 GB masks/indices and
-# OOM'd a 16 GB chip; found by bench/config16 r5).  32 shards ≈ 0.5 GB
+# OOM'd a 16 GB chip; found in r5).  32 shards ≈ 0.5 GB
 # peak per step.
 DISTINCT_BLOCK = 32
 

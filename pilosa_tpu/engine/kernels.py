@@ -17,13 +17,6 @@ shard is 2^20 columns — and finished in int64 on the host where
 cluster-wide totals could overflow (:func:`shard_totals`).  TPUs have no
 native int64; keeping the device path int32 avoids ~1000x emulation
 overhead on the popcount matrix (see ``engine._jaxcfg``).
-
-Kernel tiers (r24): this module is the XLA ORACLE tier — the default
-serving tier, the bit-exactness reference every other tier is tested
-against, and the path degraded serving and Pallas lowering failures
-always fall back to.  ``engine.pallas_kernels`` carries the optional
-hand-written Pallas tier the executor's ``kernel_tier="pallas"`` knob
-selects for the hottest fused families.
 """
 
 from __future__ import annotations
@@ -76,16 +69,10 @@ def popcount(words: jax.Array) -> jax.Array:
 # serial int32 accumulation chain per (shard, row); splitting the axis
 # into COUNT_TILE-word tiles reduced innermost-first gives the
 # vectorizer W/COUNT_TILE independent partial sums to interleave
-# (measured per-kind in bench/config23's before/after detail).  Exact
+# (measured per kind, before and after, in r17).  Exact
 # at any tiling: every partial sum of per-word popcounts (<=32 each)
 # stays far under int32.
 COUNT_TILE = 512
-
-
-def count_ref(words: jax.Array) -> jax.Array:
-    """Flat single-pass reduce — the pre-r17 :func:`count`, kept as
-    the before-side of config23's per-kernel before/after sweep."""
-    return jnp.sum(popcount(words), axis=-1, dtype=jnp.int32)
 
 
 def count(words: jax.Array) -> jax.Array:

@@ -232,8 +232,7 @@ class CountBatcher:
                  watchdog_s: float = 5.0,
                  probe_after_s: float = 5.0,
                  placement_key=None,
-                 ledger=None, flight=None,
-                 loop_fusion: bool = False):
+                 ledger=None, flight=None):
         from pilosa_tpu.exec.fused import PingPong
         from pilosa_tpu.exec.health import DeviceHealthGovernor
         from pilosa_tpu.obs import NULL_FLIGHT, NULL_LEDGER, NopStats
@@ -262,12 +261,6 @@ class CountBatcher:
         # PQL-surface bench reasons about (a kind stuck at 1 under
         # concurrency is not co-batching)
         self.stats.set_buckets("pipeline_window_fill", COUNT_BUCKETS)
-        # on-device dispatch loops (r24): merge a window's same-shape
-        # selcounts groups (distinct planes/overlays) into ONE jitted
-        # loop program — N same-shape scans, one enqueue.  Off by
-        # default: today's per-group dispatch is the proven path.
-        self.loop_fusion = bool(loop_fusion)
-        self.stats.set_buckets("dispatch_loop_iters", COUNT_BUCKETS)
         # lifetime co-batched BSI aggregate items (mirror of the
         # bsi_batch_hits_total counter) for /status
         self._bsi_batch_hits = 0
@@ -328,8 +321,7 @@ class CountBatcher:
         # serving runs windows on the per-item fallback path
         self.governor = DeviceHealthGovernor(
             stats=self.stats, probe_after_s=probe_after_s,
-            flight=self.flight,
-            tier=getattr(fused, "effective_tier", "xla"))
+            flight=self.flight)
         # solo fast lane (r17 tentpole): with no queue pressure, a
         # width-1 request skips window formation entirely and rides a
         # pre-bound dispatch chain on the CALLER's thread — no enqueue,
@@ -1041,7 +1033,7 @@ class CountBatcher:
         self.stats.count("batcher_items", len(batch))
         self.stats.gauge("batcher_window_seconds", self._win)
         # window occupancy + fill ratio (r14 device telemetry):
-        # the coalescing histograms the config23 roofline reasons
+        # the coalescing histograms a roofline reading reasons
         # about — how many items a window actually collects and
         # how close it runs to max_batch
         self.stats.observe("batcher_window_items", float(len(batch)))
@@ -1104,8 +1096,6 @@ class CountBatcher:
                 self.stats.count("bsi_batch_hits_total",
                                  len(group) - 1, kind=key[0])
                 self._bsi_batch_hits += len(group) - 1
-        if self.loop_fusion:
-            groups = self._fuse_selcounts_loops(groups)
         # DEGRADED serving (r18 governor): the device is suspect —
         # every group runs inline per item on the proven op-at-a-time
         # fallback path (answers stay exact; throughput, not
@@ -1124,37 +1114,6 @@ class CountBatcher:
                     self._run_fallback(key, group)
             return
         self._dispatch_window(batch, groups)
-
-    def _fuse_selcounts_loops(self, groups: dict) -> dict:
-        """The r24 loop-fusion grouping rule: selcounts groups key on
-        plane IDENTITY, so a window touching K same-shape planes (or K
-        overlay snapshots of one plane) costs K dispatches — merge ≥2
-        groups sharing (plane shape, overlay pow2 bucket) into ONE
-        ``selcounts-loop`` super-group that
-        :meth:`fused.FusedCache.run_selected_counts_loop` serves as a
-        single jitted loop program.  Items keep their per-group slot
-        unions inside the dispatch; the merged kind routes to the same
-        per-item selcounts fallback on any failure."""
-        sigs: dict[tuple, list] = {}
-        for key, group in groups.items():
-            if key[0] != "selcounts":
-                continue
-            p0 = group[0]
-            d = p0.delta
-            sigs.setdefault(
-                (p0.leaves[0].shape,
-                 d.rows.shape[0] if d is not None else None,
-                 key[-1]),  # placement identity stays unmixed
-                []).append(key)
-        for sig, keys in sigs.items():
-            if len(keys) < 2:
-                continue
-            merged: list = []
-            for k in keys:
-                merged.extend(groups.pop(k))
-            groups[("selcounts-loop", sig[0], sig[1],
-                    self.placement_key)] = merged
-        return groups
 
     def _dispatch_window(self, batch: list, groups: dict) -> None:
         """The fused pipeline: one dispatch per group, the window's
@@ -1264,10 +1223,10 @@ class CountBatcher:
         # bytes the window's fused programs read from HBM (r14):
         # per-kind scan-volume counters feed capacity math, and
         # bytes / (readback-start -> readback-complete) is the
-        # LIVE bandwidth the config23 roofline bench measures
-        # offline — the gauge tracks how far serving sits from
-        # that roof (see _finish_window for why the clock starts
-        # at the read, not the dispatch)
+        # LIVE bandwidth a device trace measures offline — the
+        # gauge tracks how far serving sits from that roof (see
+        # _finish_window for why the clock starts at the read, not
+        # the dispatch)
         win_bytes = 0
         for key, group, _, _ in pending:
             nbytes = self._group_bytes(key[0], group)
@@ -1634,8 +1593,6 @@ class CountBatcher:
             ret = self._dispatch_rowcounts_delta(group)
         elif kind == "selcounts":
             ret = self._dispatch_selcounts(group)
-        elif kind == "selcounts-loop":
-            ret = self._dispatch_selcounts_loop(group)
         elif kind == "tree":
             ret = self._dispatch_tree(group)
         elif kind == "bsirange":
@@ -1663,18 +1620,6 @@ class CountBatcher:
             plane = group[0].leaves[0]
             rows = {s for p in group for s in p.nodes}
             return len(rows) * plane.shape[0] * plane.shape[-1] * 4
-        if kind == "selcounts-loop":
-            # per-(plane, overlay) pair: that pair's slot union
-            unions: dict[tuple, set] = {}
-            planes: dict[tuple, object] = {}
-            for p in group:
-                k = (id(p.leaves[0]),
-                     id(p.delta) if p.delta is not None else 0)
-                unions.setdefault(k, set()).update(p.nodes)
-                planes[k] = p.leaves[0]
-            return sum(
-                len(rows) * planes[k].shape[0] * planes[k].shape[-1] * 4
-                for k, rows in unions.items())
         if kind == "tree":
             # one gather of the slot UNION + each unique extra once
             plane = group[0].leaves[0]
@@ -1722,8 +1667,7 @@ class CountBatcher:
             self._fallback_counts(group)
         elif key[0] in ("rowcounts", "rowcounts-delta"):
             self._fallback_rowcounts(group)
-        elif key[0] in ("selcounts", "selcounts-loop"):
-            # the loop super-group degrades to the same per-item path
+        elif key[0] == "selcounts":
             self._fallback_selcounts(group)
         elif key[0] == "tree":
             self._fallback_tree(group)
@@ -1863,40 +1807,6 @@ class CountBatcher:
                 if self._skip(p):
                     continue
                 self._deliver(p, host[[pos[s] for s in p.nodes]])
-        return out, finish
-
-    def _dispatch_selcounts_loop(self, group: list[_Pending]):
-        """A merged same-shape selcounts super-group (r24 loop fusion):
-        re-split by (plane, overlay) identity into the original
-        per-pair slot unions, then ONE loop program serves every pair
-        — K same-shape scans, one enqueue, one packed readback.  The
-        iteration count lands in the ``dispatch_loop_iters``
-        histogram."""
-        pairs: dict[tuple, list[_Pending]] = {}
-        for p in group:
-            pairs.setdefault(
-                (id(p.leaves[0]),
-                 id(p.delta) if p.delta is not None else 0),
-                []).append(p)
-        subs = list(pairs.values())
-        orders = [sorted({s for p in sub for s in p.nodes})
-                  for sub in subs]
-        out = self.fused.run_selected_counts_loop(
-            tuple(sub[0].leaves[0] for sub in subs),
-            tuple(tuple(o) for o in orders),
-            tuple(sub[0].delta for sub in subs),
-            sorted_idx=True)
-        self.stats.observe("dispatch_loop_iters", float(len(subs)))
-        poss = [{s: i for i, s in enumerate(o)} for o in orders]
-
-        def finish(host: np.ndarray) -> None:
-            host = host.astype(np.int64)
-            for j, sub in enumerate(subs):
-                pos = poss[j]
-                for p in sub:
-                    if self._skip(p):
-                        continue
-                    self._deliver(p, host[j][[pos[s] for s in p.nodes]])
         return out, finish
 
     def _dispatch_tree(self, group: list[_Pending]):

@@ -125,11 +125,10 @@ def _is_device_oom(e: Exception) -> bool:
     status as XlaRuntimeError/JaxRuntimeError on direct dispatch, but
     an async execution that fails on device surfaces at the host READ
     as a plain ValueError carrying the same RESOURCE_EXHAUSTED text
-    (seen from a PJRT plug-in backend under 32-way concurrency,
-    config14 r5).  The type
-    gate stays: an ExecutionError merely QUOTING user input (e.g. PQL
-    ``RESOURCE_EXHAUSTED()``) must not trigger a cache-dropping
-    recovery."""
+    (seen from a PJRT plug-in backend under 32-way concurrency, r5).
+    The type gate stays: an ExecutionError merely QUOTING user input
+    (e.g. PQL ``RESOURCE_EXHAUSTED()``) must not trigger a
+    cache-dropping recovery."""
     return ("RESOURCE_EXHAUSTED" in str(e)
             and type(e).__name__ in ("XlaRuntimeError", "JaxRuntimeError",
                                      "ValueError"))
@@ -344,9 +343,6 @@ class Executor:
                  tenant_qps_quota: float = 0.0,
                  tenant_slot_quota: int = 0,
                  tenant_device_seconds_quota: float = 0.0,
-                 cost_observability: bool = True,
-                 kernel_tier: str = "xla",
-                 dispatch_loop_fusion: bool = False,
                  fused_warmup: bool = False):
         """``placement`` (a :class:`pilosa_tpu.parallel.MeshPlacement`)
         shards every plane's leading axis over the device mesh and pads
@@ -387,26 +383,12 @@ class Executor:
         measured device seconds (the cost ledger's decayed window,
         ~60s half-life) — sheds by what queries actually COST on
         device, not how many arrived (0 = off).
-        ``cost_observability`` (r19): False swaps the cost ledger and
-        flight recorder for null objects — the instrumentation-off
-        tier the overhead bench (config34) measures against.
 
-        Kernel tier (r24): ``kernel_tier`` routes the whole-plane
-        scans (count-batch, rowcounts) through hand-written Pallas
-        kernels (``"pallas"``) instead of the XLA-compiled oracle tier
-        (``"xla"``, default).  ``"pallas"`` raises here unless the
-        backend is TPU and there is no ``placement``.  A shape whose
-        Pallas program still fails to compile serves XLA, logged at
-        ERROR with the compiler's message and counted in
-        ``pallas_fallback_total``; XLA remains the bit-exact oracle
-        and the governor's degraded-serving path.
-        ``dispatch_loop_fusion`` (r24) lets the batcher collapse a
-        collection window's same-shape selected-count groups into ONE
-        jitted on-device loop dispatch.  ``fused_warmup`` (r24) runs
-        the compile-ladder warmer: delta-aware fused programs for a
-        newly resident plane shape pre-compile on a background thread
-        so the first post-ingest query serves from a warm cache
-        (single-device only — disabled under a mesh placement)."""
+        ``fused_warmup`` (r24) runs the compile-ladder warmer:
+        delta-aware fused programs for a newly resident plane shape
+        pre-compile on a background thread so the first post-ingest
+        query serves from a warm cache (single-device only — disabled
+        under a mesh placement)."""
         self.holder = holder
         self.translate = translate or TranslateStore(
             holder.path, health=getattr(holder, "storage_health", None))
@@ -429,23 +411,14 @@ class Executor:
         # spends device time (planes, pager, fused cache, batcher,
         # governor) — attribution and incident capture are always on.
         # Flight dumps land under the holder's data dir.
-        from pilosa_tpu.obs import (NULL_FLIGHT, NULL_LEDGER, CostLedger,
-                                    FlightRecorder)
-        if cost_observability:
-            self.ledger = CostLedger(stats=self.stats)
-            self.flight = FlightRecorder(
-                dump_dir=f"{holder.path}/_flight", stats=self.stats)
-        else:
-            self.ledger = NULL_LEDGER
-            self.flight = NULL_FLIGHT
-        # built first: a kernel tier that cannot serve here (pallas
-        # off-TPU or under a placement) raises before any cache or
-        # thread exists
+        from pilosa_tpu.obs import CostLedger, FlightRecorder
+        self.ledger = CostLedger(stats=self.stats)
+        self.flight = FlightRecorder(
+            dump_dir=f"{holder.path}/_flight", stats=self.stats)
         from pilosa_tpu.exec.fused import FusedCache
         self.fused = FusedCache(stats=self.stats,
                                 mesh_guard=placement is not None,
-                                ledger=self.ledger, flight=self.flight,
-                                kernel_tier=kernel_tier)
+                                ledger=self.ledger, flight=self.flight)
         # tenancy (r17): the governor is always attached — with no
         # quotas and no telemetry its eviction ordering degrades to
         # the stamped LRU exactly, so the single-tenant default pays
@@ -518,8 +491,7 @@ class Executor:
                 probe_after_s=device_health_probe_seconds,
                 placement_key=(getattr(placement, "key", None)
                                if placement is not None else None),
-                ledger=self.ledger, flight=self.flight,
-                loop_fusion=dispatch_loop_fusion)
+                ledger=self.ledger, flight=self.flight)
         # mesh serving telemetry (ISSUE 16): how many chips the plane
         # axis spans (1 = single-device serving)
         self.stats.gauge(
@@ -609,8 +581,6 @@ class Executor:
                     "watchdogSeconds": 0.0, "quarantinedWindows": 0,
                     "inflightWindows": 0, "consecutiveFaults": 0,
                     "watchdogTrips": 0,
-                    "kernelTier": getattr(self.fused, "effective_tier",
-                                          "xla"),
                     "warmup": warm}
         payload = self.batcher.health_payload()
         payload["warmup"] = warm
@@ -754,7 +724,7 @@ class Executor:
             # live device scratch (program temps, per-query outputs);
             # with residency near budget, unbounded client threads
             # multiply scratch past HBM headroom (32 streams OOM'd
-            # every thread at 8.5 GB resident, config14 r5).  Queries
+            # every thread at 8.5 GB resident, r5).  Queries
             # queue here — the chip serializes execution anyway, so a
             # bounded pool costs no throughput.  Timed: a wedged
             # recovery holding every slot must not refuse service
@@ -2382,7 +2352,7 @@ class Executor:
             # first offset+limit columns — read and unpack ONLY those.
             # (Unbounded materialization of a 25% row at 1B cols cost
             # ~70 s/call on this host: 125 MB read + 250M-column
-            # unpack/concat for a limit=1000 answer — config16 r5.)
+            # unpack/concat for a limit=1000 answer — r5.)
             counts = np.asarray(_shard_popcounts(words))
             cum = np.cumsum(counts)
             n_shards = int(np.searchsorted(cum, end)) + 1

@@ -15,7 +15,6 @@ sign scalar, see ``engine.bsi.predicate_masks``), so ``amount > 5`` and
 
 from __future__ import annotations
 
-import logging as _logging
 import threading as _threading
 import time as _time
 
@@ -26,8 +25,6 @@ import numpy as np
 from pilosa_tpu.engine import bsi as bsik
 from pilosa_tpu.engine import kernels
 from pilosa_tpu.obs import metrics as _metrics
-
-_log = _logging.getLogger("pilosa_tpu.exec")
 
 # node encodings (hashable nested tuples):
 #   ("leaf", i)                      leaf i is uint32[..., W] words
@@ -284,8 +281,7 @@ class FusedCache:
     MAX_PROGRAMS = 256
 
     def __init__(self, stats=None, mesh_guard: bool = False,
-                 ledger=None, flight=None, kernel_tier: str = "xla"):
-        import os
+                 ledger=None, flight=None):
         import threading
         from pilosa_tpu.exec._lru import Stamps
         from pilosa_tpu.obs import NULL_FLIGHT, NULL_LEDGER, NopStats
@@ -313,119 +309,10 @@ class FusedCache:
         # caused it, not just as a climbing built counter
         self._ledger = ledger or NULL_LEDGER
         self.flight = flight or NULL_FLIGHT
-        # kernel tier (r24): "pallas" routes the whole-plane scans
-        # (rowcounts-batch/-delta, count-batch) through the
-        # Pallas/Mosaic kernels; "xla" (default) is the proven path
-        # and stays the correctness oracle + the governor's degraded
-        # fallback.  Asking for "pallas" where it cannot run is a
-        # start-up error, never a quiet XLA serve: Mosaic only targets
-        # TPU (the TEST-ONLY escape hatch PILOSA_PALLAS_INTERPRET=1
-        # runs the same kernels through the pallas interpreter so
-        # tier-1 can pin bit-exactness without a device), and a Mosaic
-        # call cannot be auto-partitioned over a mesh placement until
-        # the kernels are wrapped in shard_map.
-        self.kernel_tier = kernel_tier
-        self._pallas_interpret = False
-        if kernel_tier not in ("xla", "pallas"):
-            raise ValueError(
-                f"kernel_tier: expected 'xla' or 'pallas', got "
-                f"{kernel_tier!r}")
-        if kernel_tier == "pallas":
-            if mesh_guard:
-                raise ValueError(
-                    "kernel_tier='pallas' cannot serve a mesh placement "
-                    "(Mosaic kernels are not auto-partitioned); set "
-                    "mesh=false or kernel_tier='xla'")
-            if os.environ.get("PILOSA_PALLAS_INTERPRET",
-                              "") not in ("", "0", "false"):
-                self._pallas_interpret = True
-            elif jax.default_backend() != "tpu":
-                raise ValueError(
-                    "kernel_tier='pallas' needs the TPU backend, found "
-                    f"{jax.default_backend()!r}")
-        # tier token appended to pallas-built program keys (like
-        # sharding_key: same shape, different tier = different program);
-        # xla keys stay byte-identical to the pre-tier key space
-        self._tier_tok = ((("pallas-interpret" if self._pallas_interpret
-                            else "pallas"),)
-                          if kernel_tier == "pallas" else ())
-        self._pallas_bad: set = set()   # (family, shape) lowering fails
-        self.pallas_fallbacks = 0
-
-    @property
-    def effective_tier(self) -> str:
-        """The tier actually serving: "xla", "pallas", or
-        "pallas-interpret" (the test escape hatch)."""
-        if self.kernel_tier == "pallas":
-            return ("pallas-interpret" if self._pallas_interpret
-                    else "pallas")
-        return "xla"
 
     @property
     def program_count(self) -> int:
         return len(self._programs)
-
-    # -- kernel-tier routing (r24) ---------------------------------------
-
-    def _pallas_ok(self, sig) -> bool:
-        return (self.kernel_tier == "pallas"
-                and sig not in self._pallas_bad)
-
-    def _pallas_failed(self, sig, exc) -> None:
-        """A pallas-tier program failed to lower/compile/run for
-        ``sig``: that ``(family, shape)`` serves XLA from here on.
-        Logged ONCE per sig (``_pallas_bad`` gates re-entry) at ERROR
-        with the compiler's own message, and counted — chip_smoke.py
-        treats a non-zero ``pallas_fallback_total`` as fatal."""
-        self._pallas_bad.add(sig)
-        self.pallas_fallbacks += 1
-        self._stats.count("pallas_fallback_total", 1, reason="lowering")
-        self.flight.record("pallas_fallback", str(sig[0]),
-                           type(exc).__name__)
-        _log.error("pallas tier: %s %s failed, serving XLA for this "
-                   "shape: %s: %s", sig[0], sig[1],
-                   type(exc).__name__, exc)
-
-    def _tier_run(self, sig, dispatch):
-        """Dispatch through the pallas tier when it covers ``sig`` (a
-        ``(family, plane shape)`` pair); a Mosaic lowering failure
-        marks the shape bad, logs and counts it (see
-        :meth:`_pallas_failed`), and re-dispatches the XLA-tier
-        program."""
-        if self._pallas_ok(sig):
-            try:
-                return dispatch(True)
-            except Unfusable:
-                raise
-            except Exception as e:  # noqa: BLE001 — lowering/compile
-                self._pallas_failed(sig, e)
-        return dispatch(False)
-
-    def _rc_kernel(self, pallas: bool):
-        """The whole-plane row-counts base kernel for one tier:
-        ``(plane[, filter]) → int32[S, R]``."""
-        if pallas:
-            from pilosa_tpu.engine import pallas_kernels
-            interp = self._pallas_interpret
-            return lambda p, fw=None: pallas_kernels.row_counts(
-                p, fw, interpret=interp)
-        return kernels.row_counts
-
-    def _cnt_kernel(self, pallas: bool):
-        """The whole-bitmap count kernel for one tier.  The pallas form
-        is 2D-only; plan trees that fold to other ranks (zeros nodes
-        over BSI leaves) stay on the XLA reduce inside the same
-        program."""
-        if not pallas:
-            return kernels.count
-        from pilosa_tpu.engine import pallas_kernels
-        interp = self._pallas_interpret
-
-        def cnt(words):
-            if words.ndim != 2:
-                return kernels.count(words)
-            return pallas_kernels.count(words, interpret=interp)
-        return cnt
 
     def _get_fast(self, key):
         fn = self._programs.get(key)
@@ -548,23 +435,17 @@ class FusedCache:
         donate_ok = (scratch is not None
                      and tuple(scratch.shape) == out_shape)
 
-        def dispatch(pallas: bool):
-            cnt = self._cnt_kernel(pallas)
-
-            def build():
-                def program(*ls):
-                    return jnp.stack([cnt(_build(n, ls))
-                                      for n in nodes])
-                return program
-            tok = self._tier_tok if pallas else ()
-            key = ((nodes, donate_ok, sharding_key(leaves[0])) + tok,
-                   "count-batch")
-            if donate_ok:
-                return self._cached(key, build,
-                                    donate=(n_leaves,))(*leaves, scratch)
-            return self._cached(key, build)(*leaves)
-
-        return self._tier_run(("count", leaves[0].shape), dispatch)
+        def build():
+            def program(*ls):
+                return jnp.stack([kernels.count(_build(n, ls))
+                                  for n in nodes])
+            return program
+        key = ((nodes, donate_ok, sharding_key(leaves[0])),
+               "count-batch")
+        if donate_ok:
+            return self._cached(key, build,
+                                donate=(n_leaves,))(*leaves, scratch)
+        return self._cached(key, build)(*leaves)
 
     def run_rowcounts_batch(self, flags: tuple, leaves, scratch=None):
         """K whole-plane row-count items (same plane shape) in ONE
@@ -582,32 +463,26 @@ class FusedCache:
         donate_ok = (scratch is not None
                      and tuple(scratch.shape) == out_shape)
 
-        def dispatch(pallas: bool):
-            rc = self._rc_kernel(pallas)
-
-            def build():
-                def program(*ls):
-                    rows = []
-                    i = 0
-                    for has_filter in flags:
-                        plane = ls[i]
-                        flt = ls[i + 1] if has_filter else None
-                        i += 2 if has_filter else 1
-                        rows.append(jnp.sum(rc(plane, flt),
-                                            axis=0, dtype=jnp.int32))
-                    return jnp.stack(rows)
-                return program
-            tok = self._tier_tok if pallas else ()
-            key = (flags, leaves[0].shape, sharding_key(leaves[0]),
-                   donate_ok) + tok + ("rowcounts-batch",)
-            # (donate flag inside the key, tag kept LAST — callers
-            # introspect the program set by trailing tag)
-            if donate_ok:
-                return self._cached(key, build,
-                                    donate=(n_leaves,))(*leaves, scratch)
-            return self._cached(key, build)(*leaves)
-
-        return self._tier_run(("rowcounts", leaves[0].shape), dispatch)
+        def build():
+            def program(*ls):
+                rows = []
+                i = 0
+                for has_filter in flags:
+                    plane = ls[i]
+                    flt = ls[i + 1] if has_filter else None
+                    i += 2 if has_filter else 1
+                    rows.append(jnp.sum(kernels.row_counts(plane, flt),
+                                        axis=0, dtype=jnp.int32))
+                return jnp.stack(rows)
+            return program
+        # (donate flag inside the key, tag kept LAST — callers
+        # introspect the program set by trailing tag)
+        key = (flags, leaves[0].shape, sharding_key(leaves[0]),
+               donate_ok, "rowcounts-batch")
+        if donate_ok:
+            return self._cached(key, build,
+                                donate=(n_leaves,))(*leaves, scratch)
+        return self._cached(key, build)(*leaves)
 
     # bounded device-resident slot-index cache (r17 solo fast lane):
     # a repeating solo query shape re-dispatches the same slot tuple
@@ -709,101 +584,6 @@ class FusedCache:
             return program
         return build
 
-    def run_selected_counts_loop(self, planes: tuple, slot_lists: tuple,
-                                 deltas: tuple,
-                                 sorted_idx: bool = True) -> jax.Array:
-        """A window's same-shape selected-count sequence in ONE jitted
-        program (r24 on-device dispatch loops): K (plane, slots[,
-        overlay]) items collapse to one enqueue + one packed readback
-        instead of K dispatches.  Returns int32[K_pad, bucket]; pad
-        lanes repeat item 0 (rows) and each item's last slot (columns),
-        so callers slice ``[j, :len(slots_j)]``.
-
-        Two forms behind one key family: when every item reads the
-        SAME resident plane (interleaved-ingest overlay snapshots),
-        the program is a true ``lax.scan`` over the stacked slot /
-        overlay operands — the pattern ``engine/bsi.py`` proves for
-        percentile; distinct planes enter as separate traced operands
-        (stacking resident planes would copy HBM) and the chain
-        unrolls inside the jit, which still costs one enqueue.  The
-        batcher's loop-fusion rule guarantees one overlay pow2 bucket
-        (or none) across items."""
-        k = len(planes)
-        k_pad = pow2_bucket(k)
-        bucket = pow2_bucket(max(len(sl) for sl in slot_lists))
-        padded = [tuple(sl) + (sl[-1],) * (bucket - len(sl))
-                  for sl in slot_lists]
-        padded += [padded[0]] * (k_pad - k)
-        idx = jnp.stack([self._slot_idx(p) for p in padded])
-        planes = tuple(planes) + (planes[0],) * (k_pad - k)
-        deltas = tuple(deltas) + (deltas[0],) * (k_pad - k)
-        has_delta = deltas[0] is not None
-        dbucket = deltas[0].rows.shape[0] if has_delta else 0
-        same_plane = all(p is planes[0] for p in planes)
-        shape, shard = planes[0].shape, sharding_key(planes[0])
-
-        from pilosa_tpu.ingest.delta import adjusted_selected_counts
-        key = (("selcounts-loop", shape, shard, k_pad, bucket,
-                dbucket, sorted_idx, same_plane), "count")
-
-        def sel(p, ix):
-            return kernels.selected_row_counts(p, ix,
-                                               sorted_idx=sorted_idx)
-
-        if same_plane and has_delta:
-            drs = jnp.stack([d.rows for d in deltas])
-            dws = jnp.stack([d.words for d in deltas])
-            dvs = jnp.stack([d.vals for d in deltas])
-
-            def build():
-                def program(p, ix, dr, dw, dv):
-                    def step(c, xs):
-                        ixj, drj, dwj, dvj = xs
-                        return c, adjusted_selected_counts(
-                            p, ixj, drj, dwj, dvj,
-                            sorted_idx=sorted_idx)
-                    _, outs = jax.lax.scan(step, 0, (ix, dr, dw, dv))
-                    return outs
-                return program
-            return self._cached(key, build)(planes[0], idx,
-                                            drs, dws, dvs)
-        if same_plane:
-            def build():
-                def program(p, ix):
-                    def step(c, ixj):
-                        return c, jnp.sum(sel(p, ixj), axis=0,
-                                          dtype=jnp.int32)
-                    _, outs = jax.lax.scan(step, 0, ix)
-                    return outs
-                return program
-            return self._cached(key, build)(planes[0], idx)
-        if has_delta:
-            def build():
-                def program(ix, *rest):
-                    ps = rest[:k_pad]
-                    outs = []
-                    for j in range(k_pad):
-                        dr, dw, dv = rest[k_pad + 3 * j:
-                                          k_pad + 3 * j + 3]
-                        outs.append(adjusted_selected_counts(
-                            ps[j], ix[j], dr, dw, dv,
-                            sorted_idx=sorted_idx))
-                    return jnp.stack(outs)
-                return program
-            args = [idx] + list(planes)
-            for d in deltas:
-                args += [d.rows, d.words, d.vals]
-            return self._cached(key, build)(*args)
-
-        def build():
-            def program(ix, *ps):
-                return jnp.stack([
-                    jnp.sum(sel(ps[j], ix[j]), axis=0,
-                            dtype=jnp.int32)
-                    for j in range(k_pad)])
-            return program
-        return self._cached(key, build)(idx, *planes)
-
     def run_rowcounts_delta(self, plane, delta, filter_words=None,
                             reduce: bool = True) -> jax.Array:
         """Whole-plane per-row counts of base⊕delta in ONE program:
@@ -814,42 +594,32 @@ class FusedCache:
         operands; the program set is bounded per (plane shape, overlay
         bucket, filtered, reduce)."""
         has_filter = filter_words is not None
-
-        def dispatch(pallas: bool):
-            key = self._rowcounts_delta_key(
-                plane.shape, sharding_key(plane), delta.rows.shape[0],
-                has_filter, reduce, pallas)
-            build = self._rowcounts_delta_build(has_filter, reduce,
-                                                pallas)
-            args = (plane, delta.rows, delta.words, delta.vals)
-            if has_filter:
-                args += (filter_words,)
-            return self._cached(key, build)(*args)
-
-        return self._tier_run(("rowcounts", plane.shape), dispatch)
+        key = self._rowcounts_delta_key(
+            plane.shape, sharding_key(plane), delta.rows.shape[0],
+            has_filter, reduce)
+        build = self._rowcounts_delta_build(has_filter, reduce)
+        args = (plane, delta.rows, delta.words, delta.vals)
+        if has_filter:
+            args += (filter_words,)
+        return self._cached(key, build)(*args)
 
     def _rowcounts_delta_key(self, shape, shard, dbucket, has_filter,
-                             reduce, pallas: bool):
-        tok = self._tier_tok if pallas else ()
+                             reduce):
         return (("rowcounts-delta", shape, shard, dbucket, has_filter,
-                 reduce) + tok, "count")
+                 reduce), "count")
 
-    def _rowcounts_delta_build(self, has_filter: bool, reduce: bool,
-                               pallas: bool):
+    def _rowcounts_delta_build(self, has_filter: bool, reduce: bool):
         from pilosa_tpu.ingest.delta import adjusted_row_counts
-        rc = self._rc_kernel(pallas) if pallas else None
 
         def build():
             if has_filter:
                 def program(p, dr, dw, dv, fw):
                     return adjusted_row_counts(p, dr, dw, dv, fw,
-                                               reduce_shards=reduce,
-                                               row_counts_fn=rc)
+                                               reduce_shards=reduce)
             else:
                 def program(p, dr, dw, dv):
                     return adjusted_row_counts(p, dr, dw, dv, None,
-                                               reduce_shards=reduce,
-                                               row_counts_fn=rc)
+                                               reduce_shards=reduce)
             return program
         return build
 
@@ -900,24 +670,19 @@ class FusedCache:
         dw = sds((overlay_bucket,), jnp.int32)
         dv = sds((overlay_bucket,), jnp.uint32)
         jobs = []
-        sig = ("rowcounts", tuple(shape))
-        pall = self._pallas_ok(sig)
         for has_filter in (False, True):
             jobs.append((
-                sig,
                 self._rowcounts_delta_key(tuple(shape), shard,
                                           overlay_bucket, has_filter,
-                                          True, pall),
-                self._rowcounts_delta_build(has_filter, True, pall),
+                                          True),
+                self._rowcounts_delta_build(has_filter, True),
                 (plane_av, dr, dw, dv) + ((flt_av,) if has_filter
                                           else ()),
                 ()))
-        sig = None  # the selected-row gather has no pallas form
         b = self.WARM_SLOT_BUCKET
         ix_av, scr_av = sds((b,), jnp.int32), sds((b,), jnp.int32)
         for donate_ok in (False, True):
             jobs.append((
-                sig,
                 self._selcounts_delta_key(tuple(shape), shard, b,
                                           overlay_bucket, True,
                                           donate_ok),
@@ -931,28 +696,19 @@ class FusedCache:
                           overlay_bucket: int) -> tuple[int, float]:
         """Pre-compile the delta-aware serving programs for one plane
         shape × pow2 overlay bucket (r24 compile-ladder warm-up) —
-        returns (programs compiled, compile seconds).  A pallas-tier
-        lowering failure during warm-up marks the shape bad exactly
-        like a serving-path failure and the ladder re-warms the XLA
-        fallback programs, so the first post-ingest serve stays
-        compile-free either way."""
+        returns (programs compiled, compile seconds).  A rung that
+        fails to compile here is left to the serving path's own lazy
+        compile; the other rungs still warm."""
         n, secs = 0, 0.0
-        retry = False
-        for sig, key, build, avatars, donate in self._warm_jobs(
+        for key, build, avatars, donate in self._warm_jobs(
                 shape, overlay_bucket):
             try:
                 dt = self._warm_insert(key, build, avatars, donate)
-            except Exception as e:  # noqa: BLE001 — lowering/compile
-                if sig is not None and self._pallas_ok(sig):
-                    self._pallas_failed(sig, e)
-                    retry = True
+            except Exception:  # noqa: BLE001 — lowering/compile
                 continue
             if dt is not None:
                 n += 1
                 secs += dt
-        if retry:
-            n2, s2 = self.warm_delta_ladder(shape, overlay_bucket)
-            n, secs = n + n2, secs + s2
         return n, secs
 
     def _tree_cached(self, key, build):

@@ -56,14 +56,9 @@ class DeviceHealthGovernor:
     FAULT_THRESHOLD = 3
 
     def __init__(self, stats=None, probe_after_s: float = 5.0,
-                 flight=None, tier: str = "xla"):
+                 flight=None):
         from pilosa_tpu.obs import NULL_FLIGHT, NopStats
         self._stats = stats or NopStats()
-        # the serving kernel tier (r24): surfaced on /status so an
-        # operator reading a degrade can see which tier the fused
-        # pipeline was running — degraded serving itself always runs
-        # the per-item XLA fallback path, whatever the tier
-        self.tier = tier
         # flight recorder (r19): every state transition lands on the
         # incident timeline; a degrade ALSO triggers the ring dump —
         # the run-up to the breaker opening is the postmortem
@@ -166,5 +161,4 @@ class DeviceHealthGovernor:
                     time.monotonic() - self._since, 3),
                 "probeAfterSeconds": self.probe_after_s,
                 "faultThreshold": self.FAULT_THRESHOLD,
-                "kernelTier": self.tier,
             }

@@ -1208,8 +1208,8 @@ class PlaneCache:
 
         Generation-cached: the estimate runs on EVERY query of the
         field (admission check), and recomputing it for a 5M-row
-        sparse field measured ~7 s/query at 954 shards (config10 —
-        the same class as the r3 warm-path metadata fixes).  ``gens``
+        sparse field measured ~7 s/query at 954 shards (the same
+        class as the r3 warm-path metadata fixes).  ``gens``
         is the caller's own sweep of the view (:meth:`generations`)."""
         if gens is None:
             gens = self._gens(field, view_name, shards)

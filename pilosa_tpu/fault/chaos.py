@@ -94,7 +94,7 @@ class InvariantViolation(AssertionError):
 
 def prom_counter_total(text: str, name: str) -> float:
     """Sum one counter family across its labels from Prometheus
-    exposition text (shared by the harness and bench/config22)."""
+    exposition text."""
     total = 0.0
     for line in text.splitlines():
         if line.startswith(name) and line[len(name)] in "{ ":

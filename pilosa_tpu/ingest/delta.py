@@ -196,7 +196,7 @@ class DeltaMirror:
         (once per write gap a read observes), so the work must stay
         one ``np.unique`` + two fancy scatters — a python loop over
         the mirror measured O(cells) per READ under sustained ingest
-        and collapsed the config30 mixed phase."""
+        and collapsed a mixed read/write phase (r20)."""
         n = len(self._index)
         flat = self._rows[:n]
         word = self._words[:n]
@@ -256,8 +256,7 @@ def _cell_diffs(plane: jax.Array, d_rows: jax.Array, d_words: jax.Array,
 def adjusted_row_counts(plane: jax.Array, d_rows: jax.Array,
                         d_words: jax.Array, d_vals: jax.Array,
                         filter_words: jax.Array | None = None,
-                        reduce_shards: bool = True,
-                        row_counts_fn=None) -> jax.Array:
+                        reduce_shards: bool = True) -> jax.Array:
     """Whole-plane per-row popcounts of base⊕delta.
 
     plane uint32[S, R, W]; overlay arrays int32/uint32[C_pad] →
@@ -265,13 +264,11 @@ def adjusted_row_counts(plane: jax.Array, d_rows: jax.Array,
     byte-identical to the clean ``row_counts`` path; delta cells only
     adjust the touched (shard, row) entries, so N concurrent queries
     over the same (plane, overlay) pair still dedupe to one scan.
-    ``row_counts_fn`` swaps the base scan kernel (the pallas serving
-    tier routes here) — base⊕delta stays ONE program either way: the
-    adjustment traces into the same jit as the scan."""
+    base⊕delta stays ONE program: the adjustment traces into the same
+    jit as the scan."""
     from pilosa_tpu.engine import kernels
     s, r, _ = plane.shape
-    rc = row_counts_fn if row_counts_fn is not None else kernels.row_counts
-    counts = rc(plane, filter_words)  # int32[S, R]
+    counts = kernels.row_counts(plane, filter_words)  # int32[S, R]
     diff, _slot = _cell_diffs(plane, d_rows, d_words, d_vals,
                               filter_words)
     flat = counts.reshape(s * r)

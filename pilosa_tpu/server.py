@@ -101,8 +101,6 @@ class PilosaTPUServer:
             tenant_byte_quota=self.cfg.tenant_byte_quota,
             tenant_qps_quota=self.cfg.tenant_qps_quota,
             tenant_slot_quota=self.cfg.tenant_slot_quota,
-            kernel_tier=self.cfg.kernel_tier,
-            dispatch_loop_fusion=self.cfg.dispatch_loop_fusion,
             fused_warmup=self.cfg.fused_warmup)
         self._log_boot(placement)
         self.api = API(self.holder, self.executor,
@@ -183,12 +181,11 @@ class PilosaTPUServer:
         devs = jax.devices()
         self.logger.info(
             "boot: platform=%s device_kind=%r devices=%d serving=%s "
-            "kernel_tier=%s native_codec=%s compile_cache=%s "
+            "native_codec=%s compile_cache=%s "
             "jax=%s jaxlib=%s libtpu=%s",
             devs[0].platform, devs[0].device_kind, len(devs),
             (f"mesh({placement.n_devices})" if placement is not None
              else "single"),
-            self.executor.fused.effective_tier,
             "loaded" if native.available() else "python-fallback",
             _jaxcfg.compile_cache_dir(),
             version("jax"), version("jaxlib"), version("libtpu"))

@@ -1085,7 +1085,7 @@ class Fragment:
         ``fragment.Blocks``, SURVEY.md §4.6).
 
         Generation-cached: decoding every position of a dense fragment
-        is ~0.9 s on the bench host (config17 r5 — a no-op AAE round at
+        is ~0.9 s on the r5 host (a no-op AAE round at
         954 fragments cost 14 minutes, recomputed on BOTH ends).  An
         unchanged fragment answers from the cache, so steady-state
         sweeps only pay for fragments that actually mutated."""

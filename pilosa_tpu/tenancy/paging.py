@@ -16,7 +16,7 @@ Page-ins ride the warm ``.dense`` sidecar path (each fragment expands
 once, against the page's full row union, so sidecars are both honored
 and written) and deliberately do NOT count as plane *builds* — once
 sidecars are warm, a churning cache pages in at near-memcpy speed with
-zero full rebuilds, which config32's acceptance bar pins.
+zero full rebuilds (``tests/test_tenancy.py`` pins it).
 """
 
 from __future__ import annotations

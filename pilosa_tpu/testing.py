@@ -131,8 +131,8 @@ def run_cluster(n: int, base_dir: str, replicas: int = 1,
 
 
 def rss_mb() -> float:
-    """Current process resident set (MB) — the bench/soak probes'
-    shared helper."""
+    """Current process resident set (MB) — the soak probes' shared
+    helper."""
     with open("/proc/self/status") as f:
         for line in f:
             if line.startswith("VmRSS"):

@@ -1627,7 +1627,7 @@ class TestInternodeRpcLatency:
         """Regression: keep-alive internode sockets without TCP_NODELAY
         hit the classic Nagle + delayed-ACK interaction — a
         deterministic ~40 ms stall on EVERY persistent-connection RPC
-        (found by bench/config12 in r5; the whole suite passed with it).
+        (found in r5; the whole suite passed with it).
         0.5 ms is typical on loopback; 20 ms leaves slack for a loaded
         host while still catching the 40 ms stall class."""
         import time

@@ -29,8 +29,8 @@ class TestDeviceOomRetry:
     def test_oom_evicts_planes_and_retries(self, env, monkeypatch):
         """Device RESOURCE_EXHAUSTED on a call must evict unpinned
         planes and retry, not surface a 500 (regression: REST filtered
-        TopN OOM'd at 1B cols after BSI+sparse residency filled HBM —
-        bench/config10; r5 narrows the eviction to unpinned entries so
+        TopN OOM'd at 1B cols after BSI+sparse residency filled HBM;
+        r5 narrows the eviction to unpinned entries so
         concurrent queries' planes stay resident)."""
         _, _, ex = env
         q(ex, "Set(1, f=1) Set(2, f=1)")

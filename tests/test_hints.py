@@ -377,3 +377,98 @@ class TestReplayEndpointIdempotent:
                 assert out2["dropped"] == 1
             finally:
                 cl0._schema_ready.set()
+
+
+class TestAckedWriteReadBackThroughAKill:
+    """The deterministic form of what ``bench/config24`` drove with
+    timed windows (the script went in PR 30): three processes,
+    replicas=2, one replica holder killed with kill -9.  From the kill
+    on, every LIVE node answers the never-written rows exactly — for
+    longer than the shard universe is cached, the stretch in which one
+    run of that script once read a Count with a whole shard missing
+    (ROADMAP F1) — an acknowledged ``Set`` is read back from every
+    live node at once, and after the restart and the hint drain from
+    all three."""
+
+    N_SHARDS = 3
+    READ_ROWS = 4
+    WRITE_ROW = 9
+
+    def _seed(self, client):
+        import numpy as np
+
+        from pilosa_tpu.engine.words import SHARD_WIDTH
+        rng = np.random.default_rng(24)
+        client.create_index("wb")
+        client.create_field("wb", "f")
+        rows, cols = [], []
+        for s in range(self.N_SHARDS):
+            offs = rng.choice(SHARD_WIDTH, size=48, replace=False)
+            rr = rng.integers(0, self.READ_ROWS, size=48)
+            rows += [int(r) for r in rr]
+            cols += [s * SHARD_WIDTH + int(o) for o in offs]
+        client.import_bits("wb", "f", rowIDs=rows, columnIDs=cols)
+        return [rows.count(r) for r in range(self.READ_ROWS)]
+
+    def test_every_live_node_exact_before_and_after_the_drain(
+            self, tmp_path):
+        import time
+
+        from pilosa_tpu.engine.words import SHARD_WIDTH
+        from pilosa_tpu.testing import run_process_cluster
+
+        counts = "".join(f"Count(Row(f={r}))"
+                         for r in range(self.READ_ROWS))
+        written = f"Row(f={self.WRITE_ROW})"
+        with run_process_cluster(3, str(tmp_path), replicas=2,
+                                 anti_entropy=0.0) as cluster:
+            want = self._seed(cluster.client(0))
+            assert sum(want) == 48 * self.N_SHARDS
+            for i in range(3):
+                assert cluster.client(i).query("wb", counts) == want
+            status = cluster.client(0)._json("GET", "/status")
+            primary = next(nd["id"] for nd in status["nodes"]
+                           if nd.get("isPrimary"))
+            coord = next(i for i, nd in enumerate(cluster.nodes)
+                         if f"127.0.0.1:{nd.port}" == primary)
+            victim = next(i for i in range(3) if i != coord)
+            live = [i for i in range(3) if i != victim]
+            entry = cluster.client(live[0])
+
+            cluster.nodes[victim].kill9()
+            # acknowledged Sets in every shard while the corpse is
+            # still a member: each is read back from every live node
+            # AT ONCE, beside the rows no one writes
+            acked = []
+            deadline = time.monotonic() + 2.5  # > the universe's TTL
+            k = 0
+            while time.monotonic() < deadline or k < 2 * self.N_SHARDS:
+                col = (k % self.N_SHARDS) * SHARD_WIDTH + 700 + k
+                entry.query("wb", f"Set({col}, f={self.WRITE_ROW})")
+                acked.append(col)
+                k += 1
+                for i in live:
+                    c = cluster.client(i)
+                    assert c.query("wb", counts) == want, (
+                        f"node {i}: partial read {k} reads after the "
+                        f"kill")
+                    (got,) = c.query("wb", written)
+                    assert set(acked) <= set(got["columns"]), (
+                        f"node {i} lost an acknowledged Set")
+            assert entry.write_health().get("hintBacklogOps", 0) >= 1
+
+            node = cluster.nodes[victim]
+            node.stop()
+            node.start()
+            node.await_up()
+            cluster.await_membership(3, timeout=120)
+            deadline = time.monotonic() + 60
+            while entry.write_health().get("hintBacklogOps"):
+                assert time.monotonic() < deadline, "hints never drained"
+                time.sleep(0.1)
+            for i in range(3):
+                c = cluster.client(i)
+                assert c.query("wb", counts) == want, f"node {i}"
+                (got,) = c.query("wb", written)
+                assert sorted(got["columns"]) == sorted(acked), (
+                    f"node {i} after the drain")

@@ -76,13 +76,65 @@ def test_complement_against_existence():
     assert got == set(range(100)) - {5, 10, 99}
 
 
-def test_batched_axes(rng):
-    # kernels must be polymorphic over leading axes: [n_shards, W]
-    planes = rng.integers(0, 2**32, size=(4, W), dtype=np.uint32)
+def _np_popcount(ws):
+    return np.bitwise_count(ws).astype(np.int64)
+
+
+def _fill(rng, shape, fill):
+    if fill == "random":
+        return rng.integers(0, 2**32, size=shape, dtype=np.uint32)
+    return np.full(shape, 0xFFFFFFFF if fill == "ones" else 0, np.uint32)
+
+
+T = kernels.COUNT_TILE
+
+
+# kernels must be polymorphic over leading axes ([n_shards, W]) and
+# exact on both sides of count()'s tiling rule: widths under two
+# tiles, exact multiples of the tile (the tiled reduce), and widths
+# the tile does not divide (the flat reduce) — the shapes the Pallas
+# kernels were swept over, now against numpy alone
+@pytest.mark.parametrize("shape,fill", [
+    ((4, W), "random"),              # the original [n_shards, W] case
+    ((1, 64), "random"),
+    ((3, 200), "random"),
+    ((5, 1300), "random"),           # > 2 tiles, not divisible: flat
+    ((2, 2 * T), "random"),          # exactly two tiles: tiled
+    ((2, 2 * T - 1), "random"),      # one word short: flat
+    ((2, 4096), "random"),
+    ((11, 4 * T + 4101), "random"),  # ragged word blocks, odd shards
+    ((4, 130048), "random"),         # 254 tiles
+    ((3, 5, 2 * T + 96), "random"),  # a plane, width not a tile multiple
+    ((2, 96), "ones"),
+    ((2, 4 * T), "ones"),            # the largest partial sums there are
+    ((3, 160), "empty"),
+])
+def test_batched_axes(rng, shape, fill):
+    planes = _fill(rng, shape, fill)
     counts = np.asarray(kernels.count(planes))
-    assert counts.shape == (4,)
-    for i in range(4):
-        assert counts[i] == oracle_count(planes[i])
+    assert counts.shape == shape[:-1] and counts.dtype == np.int32
+    assert np.array_equal(counts, _np_popcount(planes).sum(-1))
+
+
+@pytest.mark.parametrize("s,r,w,filt", [
+    (3, 10, 2048, "random"),         # rows not a multiple of 8
+    (3, 10, 2048, None),
+    (2, 8, 2 * T + 96, "random"),    # width the tile does not divide
+    (11, 130, 2 * T + 96, "random"),  # every axis ragged at once
+    (11, 130, 2 * T + 96, None),
+    (1, 1, 1, "random"),
+    (3, 19, 299, "random"),          # nothing a power of two
+    (2, 5, 96, "empty"),             # an empty filter counts nothing
+    (2, 5, 96, "ones"),              # a full one changes nothing
+    (2, 8, 4 * T, "ones"),
+])
+def test_row_counts_shapes(rng, s, r, w, filt):
+    plane = _fill(rng, (s, r, w), "random")
+    fw = None if filt is None else _fill(rng, (s, w), filt)
+    got = np.asarray(kernels.row_counts(plane, fw))
+    masked = plane if fw is None else plane & fw[:, None, :]
+    assert got.shape == (s, r) and got.dtype == np.int32
+    assert np.array_equal(got, _np_popcount(masked).sum(-1))
 
 
 def test_row_counts_and_topn(rng):

@@ -332,6 +332,10 @@ class TestWatchdogQuarantine:
             f"caller held {elapsed:.2f}s — the watchdog never fired"
         assert _counter(stats, "pipeline_watchdog_trips_total") >= 1
         assert _counter(stats, "pipeline_quarantined_windows_total") >= 1
+        # the tight bound was for the injected hang alone: an honest
+        # recovery dispatch (a first compile of the probe window's
+        # shape) must not be quarantined by it
+        ex.batcher.watchdog_s = 5.0
         # the queue keeps draining on the fresh collector (degraded
         # serving answers exactly), and probing restores healthy
         deadline = time.monotonic() + 10
@@ -365,6 +369,7 @@ class TestWatchdogQuarantine:
         with pytest.raises(PipelineStalledError) as ei:
             ex.execute("i", "Count(Row(f=1))")
         assert ei.value.stage == "readback"
+        ex.batcher.watchdog_s = 5.0  # the tight bound was the hang's
         # recovery: fresh reader, exact answers, healthy again
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline:
@@ -448,13 +453,15 @@ class TestWatchdogQuarantine:
         assert ex.execute("i", "Count(Row(f=0))") == [int(oracle[0])]
         baseline_census = _pipeline_census()
         baseline = threading.active_count()
-        ex.batcher.watchdog_s = 0.08
         for _ in range(3):
+            # the tight bound holds around the injected hang only
+            ex.batcher.watchdog_s = 0.08
             fault.set_fault("exec.dispatch_hang", "delay", times=1,
                             match={"kind": "count"},
                             args={"seconds": 2.0})
             with pytest.raises(PipelineStalledError):
                 ex.execute("i", "Count(Row(f=0))")
+            ex.batcher.watchdog_s = 5.0
             # serve back to healthy before the next cycle
             deadline = time.monotonic() + 10
             while (ex.batcher.governor.state != "healthy"
