@@ -647,34 +647,31 @@ class CountBatcher:
         return (plane.nbytes + extra
                 + (delta.nbytes if delta is not None else 0))
 
-    def _fastlane_agg(self, kind: str, plane, filter_words, delta):
-        """One BSI Sum/Min/Max dispatched inline on the caller thread
-        (batch of one through the per-plane family — same program
-        bucketing as the windowed path).  None = fall back."""
-        from pilosa_tpu.engine import bsi as bsik
-        flags = (filter_words is not None,)
-        filters = (filter_words,) if filter_words is not None else ()
+    def _fastlane_agg(self, kind: str, plane, filters: list, delta):
+        """One request's K BSI Sum (or Min/Max) items over one plane
+        dispatched inline on the caller thread: ONE program at the
+        request's own width (no pow2 padding: a padded item is a scan
+        XLA cannot fold away), one read, K decodes.  None = fall back
+        to the window."""
         _stage("dispatch")
         t0 = time.perf_counter()
         try:
-            if kind == "sum":
-                out = self.fused.run_sum_plane_batch(
-                    plane, flags, filters, delta=delta)
-                _stage("read")
-                val = bsik.decode_sum_packed(np.asarray(out)[0])
-            else:
-                out = self.fused.run_minmax_plane_batch(
-                    plane, flags, filters, delta=delta)
-                _stage("read")
-                val = bsik.decode_minmax_packed(np.asarray(out)[0])
+            out, assign, decode = self.fused.run_agg_plane_batch(
+                kind, plane, filters, delta=delta)
+            _stage("read")
+            host = np.asarray(out)
+            vals = [decode(host[slot]) for slot in assign]
             _stage("deliver")
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
-        self._fastlane_done(kind, self._agg_bytes(
-            plane, sum(getattr(f, "nbytes", 0) for f in filters),
-            delta), wall=time.perf_counter() - t0)
-        return val
+        # each distinct item scans the plane under its own filter
+        scanned = {id(f): getattr(f, "nbytes", 0) for f in filters}
+        self._fastlane_done(
+            kind, len(scanned) * plane.nbytes + sum(scanned.values())
+            + (delta.nbytes if delta is not None else 0),
+            wall=time.perf_counter() - t0)
+        return vals
 
     def _fastlane_bsirange(self, plane, spec: tuple, operands: tuple,
                            delta):
@@ -748,45 +745,33 @@ class CountBatcher:
         return self._submit(_Pending("count", nodes, leaves,
                                      deadline=deadline))
 
-    def submit_sum(self, plane, filter_words, delta=None,
-                   deadline: float | None = None) -> tuple[int, int]:
-        """BSI Sum: (sum of offsets, non-null count), host-finished.
-        Concurrent items over the SAME plane co-batch into one
-        program (identical filters dedupe to one scan); ``delta`` (a
-        ``BsiOverlay``, r20) merges the plane's pending write columns
-        at dispatch — base⊕delta exact, no fold on the query path."""
+    def submit_aggs(self, kind: str, plane, filters: list, delta=None,
+                    deadline: float | None = None) -> list:
+        """One request's K BSI ``"sum"`` | ``"minmax"`` items over ONE
+        plane, each given by its filter (None = unfiltered); one
+        answer per item, in order — ``(sum of offsets, non-null
+        count)`` for a Sum; for Min/Max the ``(min, min_cnt, max,
+        max_cnt)`` tuples, one per shard plus one per overlay-touched
+        word column when the plane carries a delta (zero-count
+        entries; the host combine drops them).  On the fast lane the
+        K items are one launch and one read; otherwise they enqueue
+        TOGETHER (before any wait) and co-batch with concurrent
+        same-plane items in one collection window.  Identical filters
+        dedupe to one scan either way; ``delta`` (a ``BsiOverlay``,
+        r20) merges the plane's pending write columns at dispatch —
+        base⊕delta exact, no fold on the query path."""
         self._check_deadline(deadline)
         if self._fl_try_enter():
             try:
-                out = self._fastlane_agg("sum", plane, filter_words,
-                                         delta)
+                out = self._fastlane_agg(kind, plane, filters, delta)
             finally:
                 self._fl_leave()
             if out is not None:
                 return out
-        leaves = (plane,) if filter_words is None else (plane, filter_words)
-        return self._submit(_Pending("sum", None, leaves, delta=delta,
-                                     deadline=deadline))
-
-    def submit_minmax(self, plane, filter_words, delta=None,
-                      deadline: float | None = None):
-        """BSI Min/Max: (min, min_cnt, max, max_cnt) tuples — one per
-        shard, plus one per overlay-touched word column when the
-        plane carries a delta (zero-count entries; the host combine
-        drops them).  Same co-batch/dedupe/overlay contract as
-        :meth:`submit_sum`."""
-        self._check_deadline(deadline)
-        if self._fl_try_enter():
-            try:
-                out = self._fastlane_agg("minmax", plane, filter_words,
-                                         delta)
-            finally:
-                self._fl_leave()
-            if out is not None:
-                return out
-        leaves = (plane,) if filter_words is None else (plane, filter_words)
-        return self._submit(_Pending("minmax", None, leaves, delta=delta,
-                                     deadline=deadline))
+        handles = [self._enqueue(_Pending(
+            kind, None, (plane,) if f is None else (plane, f),
+            delta=delta, deadline=deadline)) for f in filters]
+        return [self.wait(h) for h in handles]
 
     def submit_bsirange(self, plane, spec: tuple, operands: tuple,
                         sig: tuple, delta=None,
@@ -1992,8 +1977,9 @@ class CountBatcher:
     @staticmethod
     def _dedupe_pad(items: list[_Pending], assign: list[int],
                     key_rank) -> tuple[list[_Pending], list[int]]:
-        """Canonical-order + pow2-pad a deduped item list (shared by
-        the per-plane aggregate dispatches): sort unique items by
+        """Canonical-order + pow2-pad a deduped item list (the
+        range-count dispatch; the Sum/Min/Max families keep theirs in
+        ``fused.run_agg_plane_batch``): sort unique items by
         ``key_rank`` so the static program shape is order-independent,
         remap the caller assignment, pad by repeating item 0."""
         from pilosa_tpu.exec.fused import pow2_bucket
@@ -2013,33 +1999,10 @@ class CountBatcher:
         a pending overlay merges in-program (base side excludes the
         touched word columns; the mini side answers them) — aggregates
         stay rebuild- and fold-free under sustained BSI ingest."""
-        from pilosa_tpu.engine import bsi as bsik
-        plane = group[0].leaves[0]
-        delta = group[0].delta
-        uniq: dict[int, int] = {}
-        items: list[_Pending] = []
-        assign: list[int] = []
-        for p in group:
-            k = id(p.leaves[1]) if len(p.leaves) == 2 else 0
-            slot = uniq.get(k)
-            if slot is None:
-                slot = uniq[k] = len(items)
-                items.append(p)
-            assign.append(slot)
-        padded, assign = self._dedupe_pad(items, assign,
-                                          lambda p: len(p.leaves))
-        flags = tuple(len(p.leaves) == 2 for p in padded)
-        filters = tuple(p.leaves[1] for p in padded
-                        if len(p.leaves) == 2)
-        if kind == "sum":
-            out = self.fused.run_sum_plane_batch(plane, flags, filters,
-                                                 delta=delta)
-            decode = bsik.decode_sum_packed
-        else:
-            out = self.fused.run_minmax_plane_batch(plane, flags,
-                                                    filters,
-                                                    delta=delta)
-            decode = bsik.decode_minmax_packed
+        out, assign, decode = self.fused.run_agg_plane_batch(
+            kind, group[0].leaves[0],
+            [p.leaves[1] if len(p.leaves) == 2 else None for p in group],
+            delta=group[0].delta, bucket=True)
 
         def finish(host: np.ndarray) -> None:
             for p, slot in zip(group, assign):

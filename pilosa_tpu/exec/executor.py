@@ -91,6 +91,43 @@ _BITMAP_CALLS = frozenset({
     "Shift", "UnionRows", "ConstRow", "Limit",
 })
 
+# every call that writes nothing: the bitmap calls and the handlers
+# that only read.  A request made of these alone may execute its calls
+# in any order (``Executor._execute_calls``); Set, Clear, ClearRow,
+# Store, the attribute writes and any call this list does not know
+# keep the request strictly ordered.
+_READ_CALLS = _BITMAP_CALLS | {
+    "Count", "Sum", "Min", "Max", "Percentile", "Distinct",
+    "IncludesColumn", "TopN", "Rows", "GroupBy", "Extract",
+}
+
+# request-level batch families -> the span / ``query_seconds`` name
+_FAMILY_SPAN = {"count": "CountBatch", "sum": "SumBatch",
+                "minmax": "MinMaxBatch"}
+
+
+def _is_read(call: Call) -> bool:
+    if call.name == "Options" and len(call.children) == 1:
+        call = call.children[0]
+    return call.name in _READ_CALLS
+
+
+def _call_family(call: Call) -> tuple | None:
+    """What a top-level call can execute together with, read from the
+    call itself: every single-child ``Count`` (``_count_batch``: one
+    program whatever the fields); ``Sum`` calls over one BSI field;
+    ``Min`` / ``Max`` calls over one BSI field (``_agg_batch``: one
+    K-item program over the field's plane).  None: the call runs
+    alone (TopN, GroupBy, Rows, an ``Options`` wrapper, ...)."""
+    if call.name == "Count":
+        return ("count",) if len(call.children) == 1 else None
+    if call.name in ("Sum", "Min", "Max"):
+        fname = call.args.get("field") or call.args.get("_field")
+        if isinstance(fname, str):
+            return ("sum" if call.name == "Sum" else "minmax", fname)
+    return None
+
+
 _SCALAR_TO_KEY = {"<": "lt", "<=": "le", ">": "gt", ">=": "ge",
                   "==": "eq", "!=": "ne"}
 
@@ -406,6 +443,12 @@ class Executor:
         # registered at 0 so the series print before the first one
         self.stats.count("plan_cache_fallthrough_total", 0)
         self.stats.count("plan_cache_row_serves_total", 0)
+        # same-family calls of one request launched as one program
+        # (_execute_calls), and the calls those groups carried
+        for kind in _FAMILY_SPAN:
+            self.stats.count("request_call_groups_total", 0, family=kind)
+            self.stats.count("request_grouped_calls_total", 0,
+                             family=kind)
         # device-cost ledger + flight recorder (r19): one ledger and
         # one event ring per executor, threaded into every layer that
         # spends device time (planes, pager, fused cache, batcher,
@@ -830,51 +873,94 @@ class Executor:
     def _execute_calls(self, index, index_name: str, query: Query,
                        shards, translate_output: bool, tracer,
                        deadline: float | None) -> list:
-        tracer = tracer or self.tracer
-        results = []
-        # spans per call + per-call-type latency counters (reference:
-        # executor span/stats emission, SURVEY.md §3.3 / §6).
-        # Runs of consecutive Count calls execute as ONE fused program
-        # with one result read (consecutive only: a write between counts
-        # must stay ordered).
-        i = 0
+        """One result per call, in call order.  Calls of one *family*
+        (:func:`_call_family`) execute together as ONE program with
+        one result read.  In a request made only of reads a call joins
+        its family's group wherever it stands — reads have no side
+        effects, so their order of execution is free; in any other
+        request only calls that directly follow one another group, so
+        a write between two reads stays between them."""
         calls = query.calls
-        while i < len(calls):
-            _stage("plan")
-            run_end = i
-            while (run_end < len(calls) and calls[run_end].name == "Count"
-                   and len(calls[run_end].children) == 1):
-                run_end += 1
-            if run_end - i > 1:
-                ctx = _Ctx(index, self._shards_for(index, shards, calls[i]),
+        if len(calls) > 1 and all(_is_read(c) for c in calls):
+            try:
+                return self._run_calls(index, index_name, calls, shards,
+                                       translate_output, tracer, deadline,
+                                       anywhere=True)
+            except ExecutionError as e:
+                if isinstance(e, (QueryTimeoutError, PipelineStalledError,
+                                  ExecutorSaturatedError)):
+                    raise
+                # a call failed, maybe not the first that would have
+                # in call order: run the request again in that order
+                # (safe: nothing was written), so that the error
+                # reported is the earliest failing call's
+        return self._run_calls(index, index_name, calls, shards,
+                               translate_output, tracer, deadline,
+                               anywhere=False)
+
+    def _run_calls(self, index, index_name: str, calls: list[Call],
+                   shards, translate_output: bool, tracer,
+                   deadline: float | None, anywhere: bool) -> list:
+        tracer = tracer or self.tracer
+        # groups in order of their first call: (family, call indexes)
+        groups: list[tuple] = []
+        open_group: dict[tuple, list[int]] = {}
+        for i, call in enumerate(calls):
+            family = _call_family(call)
+            idxs = open_group.get(family)
+            if idxs is not None and (anywhere or idxs[-1] == i - 1):
+                idxs.append(i)
+                continue
+            groups.append((family, [i]))
+            if family is not None:
+                open_group[family] = groups[-1][1]
+        results: list = [None] * len(calls)
+        # spans per call + per-call-type latency counters (reference:
+        # executor span/stats emission, SURVEY.md §3.3 / §6); a group
+        # is one span, one ``query_seconds`` observation and one pass
+        # through the stage clock's plan … assemble
+        for family, idxs in groups:
+            if len(idxs) > 1:
+                _stage("plan")
+                ctx = _Ctx(index, self._shards_for(index, shards, None),
                            translate_output, deadline=deadline)
                 ctx.check_deadline()
-                with tracer.span("executor.CountBatch",
-                                 index=index_name, calls=run_end - i,
-                                 shards=len(ctx.shards)):
+                kind = family[0]
+                name = _FAMILY_SPAN[kind]
+                run = (self._count_batch if kind == "count"
+                       else self._agg_batch)
+                members = [calls[i] for i in idxs]
+                with tracer.span("executor." + name, index=index_name,
+                                 calls=len(idxs), shards=len(ctx.shards)):
                     t0 = time.perf_counter()
                     batched = self._with_oom_retry(
-                        lambda: self._count_batch(ctx, calls[i:run_end]))
+                        lambda: run(ctx, members))
+                    self.stats.timing("query_seconds",
+                                      time.perf_counter() - t0, call=name)
+                if batched is not None:
+                    self.stats.count("request_call_groups_total", 1,
+                                     family=kind)
+                    self.stats.count("request_grouped_calls_total",
+                                     len(idxs), family=kind)
+                    for i, result in zip(idxs, batched):
+                        results[i] = result
+                    continue
+            # a call alone, or a Count group that is no fusable batch
+            for i in idxs:
+                _stage("plan")
+                call = calls[i]
+                ctx = _Ctx(index, self._shards_for(index, shards, call),
+                           translate_output, deadline=deadline)
+                ctx.check_deadline()
+                with tracer.span("executor." + call.name,
+                                 index=index_name,
+                                 shards=len(ctx.shards)):
+                    t0 = time.perf_counter()
+                    results[i] = self._with_oom_retry(
+                        lambda: self._call(ctx, call))
                     self.stats.timing("query_seconds",
                                       time.perf_counter() - t0,
-                                      call="CountBatch")
-                if batched is not None:
-                    results.extend(batched)
-                    i = run_end
-                    continue
-            call = calls[i]
-            ctx = _Ctx(index, self._shards_for(index, shards, call),
-                       translate_output, deadline=deadline)
-            ctx.check_deadline()
-            with tracer.span("executor." + call.name,
-                             index=index_name,
-                             shards=len(ctx.shards)):
-                t0 = time.perf_counter()
-                results.append(self._with_oom_retry(
-                    lambda: self._call(ctx, call)))
-                self.stats.timing("query_seconds",
-                                  time.perf_counter() - t0, call=call.name)
-            i += 1
+                                      call=call.name)
         return results
 
     def _count_batch(self, ctx: _Ctx, calls: list[Call]) -> list[int] | None:
@@ -928,8 +1014,9 @@ class Executor:
         leaf arrays and K reduce kernels, which measured ~4× slower at
         the 1B-col serving condition (BASELINE.md r3).  Returns None
         when the batch doesn't match (mixed fields, conditions, time
-        ranges, over-budget plane, or a tiny slice of a huge row set —
-        whole-plane counting would waste bandwidth there).  A plane
+        ranges, over-budget plane, a tiny slice of a huge row set —
+        whole-plane counting would waste bandwidth there — or rows that
+        are resident one by one while the plane is not).  A plane
         past the HBM budget (or its tenant's byte quota) no longer
         dead-ends: it reroutes to the PAGED residency path (r17) —
         resident shard pages answer on device, the host oracle covers
@@ -938,8 +1025,20 @@ class Executor:
         if hit is None:
             return None
         field, values = hit
+        row_ids = [self._row_id(ctx, field, v, create=False)
+                   for v in values]
         if not self.planes.has_plane(ctx.index.name, field, VIEW_STANDARD,
                                      ctx.shards):
+            # the rows asked for are on the device one by one already
+            # (filters of this request's aggregates, earlier Counts):
+            # a whole plane built beside them would hold the same
+            # words twice, and the generic batch counts the resident
+            # rows in one program and one read all the same
+            live = [r for r in row_ids if r is not None]
+            if live and self.planes.has_rows(ctx.index.name, field,
+                                             VIEW_STANDARD, live,
+                                             ctx.shards):
+                return None
             # admission decision only when the plane isn't resident yet:
             # plane_bytes walks every fragment's row set — O(shards)
             # host work that must stay OFF the per-request path (it
@@ -952,8 +1051,6 @@ class Executor:
                 return None
             if self._tiny_slice(est, len(ctx.shards), len(calls)):
                 return None
-        row_ids = [self._row_id(ctx, field, v, create=False)
-                   for v in values]
         # nowait: while the whole-field plane builds in the background
         # the generic per-row path serves (bounded per-row transfers)
         # instead of this batch stalling on full residency
@@ -2875,59 +2972,56 @@ class Executor:
         return ValCount(value=field.from_stored(value), count=int(out[1]))
 
     def _execute_sum(self, ctx: _Ctx, call: Call) -> ValCount:
-        field, filter_words = self._agg_args(ctx, call)
+        return self._agg_batch(ctx, [call])[0]
+
+    _execute_min = _execute_max = _execute_sum
+
+    def _agg_batch(self, ctx: _Ctx, calls: list[Call]) -> list[ValCount]:
+        """K ``Sum`` calls — or K ``Min`` / ``Max`` calls — over ONE
+        BSI field as one K-item aggregate: the field and its plane
+        resolve once, every call plans its own filter, and one program
+        over the resident plane answers them all with one read
+        (identical filters share a scan; a ``Min`` and a ``Max`` under
+        one filter are the same item).  A single call is K = 1."""
+        args = [self._agg_args(ctx, call) for call in calls]
+        field = args[0][0]
+        filters = [filter_words for _field, filter_words in args]
+        kind = "sum" if calls[0].name == "Sum" else "minmax"
         # delta-aware plane (r20): sustained ingest absorbs into the
         # plane's BsiOverlay and the aggregate kernels answer
         # base⊕delta — no fold, no rebuild on the query path
         ps = self.planes.bsi_plane_delta(ctx.index.name, field,
                                          ctx.shards)
         if self.batcher is not None:
-            # concurrent same-plane BSI aggregates co-batch into one
-            # program + one read per collection window (solo requests
-            # ride the fast lane)
-            total, cnt = self.batcher.submit_sum(
-                ps.plane, filter_words, delta=ps.delta,
+            # one launch + one read on the solo fast lane; under
+            # concurrency the items join the collection window, where
+            # same-plane aggregates of other requests co-batch
+            vals = self.batcher.submit_aggs(
+                kind, ps.plane, filters, delta=ps.delta,
                 deadline=self._query_deadline())
         else:
-            # same compiled one-read program, batch of one (eager
-            # bit_counts would pay one dispatch per op + 3 reads)
-            flags = (filter_words is not None,)
-            filters = ((filter_words,)
-                       if filter_words is not None else ())
+            # same compiled one-read program (eager bit_counts would
+            # pay one dispatch per op + 3 reads)
             _stage("dispatch")
-            out = self.fused.run_sum_plane_batch(
-                ps.plane, flags, filters, delta=ps.delta)
+            out, assign, decode = self.fused.run_agg_plane_batch(
+                kind, ps.plane, filters, delta=ps.delta)
             _stage("read")
-            total, cnt = bsik.decode_sum_packed(np.asarray(out)[0])
+            host = np.asarray(out)
             _stage("assemble")
+            vals = [decode(host[slot]) for slot in assign]
+        if kind == "sum":
+            return [self._sum_result(field, *val) for val in vals]
+        return [self._min_max_result(field, val, call.name == "Min")
+                for call, val in zip(calls, vals)]
+
+    @staticmethod
+    def _sum_result(field, total: int, cnt: int) -> ValCount:
         value = total + field.options.base * cnt
         return ValCount(value=field.from_stored(value) if cnt else 0,
                         count=cnt)
 
-    def _execute_min(self, ctx: _Ctx, call: Call) -> ValCount:
-        return self._min_max(ctx, call, want_min=True)
-
-    def _execute_max(self, ctx: _Ctx, call: Call) -> ValCount:
-        return self._min_max(ctx, call, want_min=False)
-
-    def _min_max(self, ctx: _Ctx, call: Call, want_min: bool) -> ValCount:
-        field, filter_words = self._agg_args(ctx, call)
-        ps = self.planes.bsi_plane_delta(ctx.index.name, field,
-                                         ctx.shards)
-        if self.batcher is not None:
-            per_shard = self.batcher.submit_minmax(
-                ps.plane, filter_words, delta=ps.delta,
-                deadline=self._query_deadline())
-        else:
-            flags = (filter_words is not None,)
-            filters = ((filter_words,)
-                       if filter_words is not None else ())
-            _stage("dispatch")
-            out = self.fused.run_minmax_plane_batch(
-                ps.plane, flags, filters, delta=ps.delta)
-            _stage("read")
-            per_shard = bsik.decode_minmax_packed(np.asarray(out)[0])
-            _stage("assemble")
+    @staticmethod
+    def _min_max_result(field, per_shard, want_min: bool) -> ValCount:
         # reduce across the shard axis on host (one tuple per shard;
         # a delta-dirty plane appends one zero-or-live tuple per
         # overlay-touched word column — same combine)

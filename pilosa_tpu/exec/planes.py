@@ -1024,6 +1024,15 @@ class PlaneCache:
         return ("plane", index, field.name, view_name,
                 shards) in self._entries
 
+    def has_rows(self, index: str, field: Field, view_name: str,
+                 row_ids, shards: tuple[int, ...]) -> bool:
+        """Every one of ``row_ids`` holds a single-row entry
+        (:meth:`row_words`), fresh or stale: a hint for admission
+        decisions (the words are on the device already), never a
+        promise that the next fetch will not rebuild."""
+        return all(("row", index, field.name, view_name, r, shards)
+                   in self._entries for r in row_ids)
+
     def rows_plane(self, index: str, field: Field, view_name: str,
                    row_ids: np.ndarray,
                    shards: tuple[int, ...]) -> PlaneSet:

@@ -617,7 +617,8 @@ class StageTimer:
     with an honest name instead of vanishing.
 
     Stages of a served request, contiguous on the serving thread (a
-    multi-call request repeats ``plan`` … ``assemble`` per call):
+    multi-call request repeats ``plan`` … ``assemble`` per call, or
+    once per group where calls of one family execute together):
     ``http_in`` (body read, route match, decode), ``admit`` (QoS,
     execution slot, recovery gate), ``plan_cache`` (plan-cache lookup,
     build and validation — and the whole attempt when it serves

@@ -1070,3 +1070,241 @@ class TestRowAttrsOnRowResults:
         assert r.row_attrs is None
         assert not os.path.exists(
             os.path.join(idx.field("g").path, "_attrs.db"))
+
+
+# -- a request's calls group by family (PR 31) ---------------------------------
+
+N_F_ROWS = 8
+
+
+@pytest.fixture(scope="module")
+def grouped_env(tmp_path_factory):
+    """Three shards: set field ``f`` of eight rows, int fields ``a``
+    (negatives too) and ``b``; one executor per serving mode over it
+    and an op-at-a-time reference (batcher off, one call a request)."""
+    from pilosa_tpu.api import API
+    from pilosa_tpu.obs import Stats
+    holder = Holder(str(tmp_path_factory.mktemp("grouped"))).open()
+    idx = holder.create_index("i")
+    idx.create_field("f")
+    idx.create_field("a", FieldOptions(type="int", min=-500, max=500))
+    idx.create_field("b", FieldOptions(type="int", min=0, max=1000))
+    ref = Executor(holder, count_batch_window=0)
+    api = API(holder, ref)
+    rng = np.random.default_rng(31)
+    width = 3 * SHARD_WIDTH
+    data = {"f": {}}
+    for row in range(N_F_ROWS):
+        cols = rng.choice(width, 300, replace=False)
+        data["f"][row] = set(cols.tolist())
+        api.import_bits("i", "f", row_ids=[row] * len(cols),
+                        col_ids=cols.tolist())
+    for name, lo, hi in (("a", -500, 500), ("b", 0, 1000)):
+        cols = rng.choice(width, 1500, replace=False)
+        # every ``f`` column carries a value, so filtered sums are not 0
+        cols = np.union1d(cols, np.fromiter(
+            set().union(*data["f"].values()), dtype=np.int64))
+        vals = rng.integers(lo, hi, len(cols))
+        data[name] = dict(zip(cols.tolist(), vals.tolist()))
+        api.import_values("i", name, col_ids=cols.tolist(),
+                          values=vals.tolist())
+    executors = {}
+
+    def executor(mode: str):
+        if mode not in executors:
+            executors[mode] = Executor(
+                holder, stats=Stats(),
+                count_batch_window=0 if mode == "off" else "adaptive")
+        return executors[mode]
+
+    yield executor, ref, data
+    holder.close()
+
+
+def _grouped_request(k: int) -> list[str]:
+    """Interleaved calls of five families and a TopN: K filtered Sums
+    over ``a`` (filters repeat past eight), K Counts, Min / Max over
+    ``a`` with and without a filter, unfiltered Sums over a second BSI
+    field, an absent row, a TopN in the middle."""
+    calls = []
+    for i in range(k):
+        row = i % N_F_ROWS
+        calls.append(f"Sum(Row(f={row}), field=a)")
+        calls.append(f"Count(Row(f={row}))")
+        if i % 3 == 0:
+            calls.append(f"Min(Row(f={row}), field=a)")
+        if i % 3 == 1:
+            calls.append("Max(field=a)")
+        if i % 4 == 2:
+            calls.append("Sum(field=b)")
+        if i == 0:
+            calls += ["Sum(Row(f=99), field=a)", "Count(Row(f=99))"]
+        if i == 1:
+            calls.append("TopN(f, n=3)")
+    return calls
+
+
+def _family_sizes(calls: list[str]) -> dict:
+    """{family: calls} of the families that hold more than one call."""
+    sizes: dict = {}
+    for c in calls:
+        fam = ("count" if c.startswith("Count") else
+               "sum" if c.startswith("Sum") else
+               "minmax" if c.startswith(("Min", "Max")) else None)
+        key = (fam, "b" if "field=b" in c else "a")
+        if fam is not None:
+            sizes[key] = sizes.get(key, 0) + 1
+    return {k: n for k, n in sizes.items() if n > 1}
+
+
+def _counter(ex, name: str) -> dict:
+    return {dict(k)["family"]: v for k, v in
+            ex.stats.snapshot()["counters"].get(name, {}).items()}
+
+
+def _hold_fast_lane(batcher):
+    """A second thread takes the solo fast lane and keeps it, so the
+    request under test meets a busy lane and enqueues into the
+    window.  (Once a window has carried several items the adaptive
+    window is open and the lane is shut to every caller: the same
+    path, nothing to hold.)  Returns the function that lets go."""
+    import threading
+    entered, release = threading.Event(), threading.Event()
+
+    def hold():
+        held = batcher._fl_try_enter()
+        assert held or batcher._win > 0
+        entered.set()
+        release.wait(120)
+        if held:
+            batcher._fl_leave()
+    t = threading.Thread(target=hold, daemon=True)
+    t.start()
+    assert entered.wait(30)
+
+    def let_go():
+        release.set()
+        t.join(30)
+    return let_go
+
+
+@pytest.mark.parametrize("shards", [None, [0, 2]],
+                         ids=["all_shards", "shards_0_2"])
+@pytest.mark.parametrize("mode", ["off", "lane", "window"])
+@pytest.mark.parametrize("k", [2, 3, 10, 17])
+def test_a_read_only_request_groups_its_calls_by_family(
+        k, mode, shards, grouped_env):
+    """Call for call, and in call order, the grouped request answers
+    what the op-at-a-time reference answers — with the batcher off, on
+    the solo fast lane, and in the window when the lane is busy."""
+    from pilosa_tpu.exec import result_to_json
+    executor, ref, data = grouped_env
+    ex = executor(mode)
+    calls = _grouped_request(k)
+    want = [result_to_json(ref.execute("i", c, shards=shards)[0])
+            for c in calls]
+    if shards is None:
+        # the reference itself, against the data it was built from
+        cols = data["f"][1]
+        i = calls.index("Sum(Row(f=1), field=a)")
+        assert want[i] == {"value": sum(data["a"][c] for c in cols),
+                           "count": len(cols)}
+        assert want[calls.index("Count(Row(f=1))")] == len(cols)
+    groups0 = _counter(ex, "request_call_groups_total")
+    carried0 = _counter(ex, "request_grouped_calls_total")
+    lanes0 = ex.ledger.solo_dispatches
+    let_go = _hold_fast_lane(ex.batcher) if mode == "window" else None
+    try:
+        got = ex.execute("i", " ".join(calls), shards=shards)
+    finally:
+        if let_go is not None:
+            let_go()
+    assert [result_to_json(r) for r in got] == want
+    sizes = _family_sizes(calls)
+    groups = _counter(ex, "request_call_groups_total")
+    carried = _counter(ex, "request_grouped_calls_total")
+    for fam in ("count", "sum", "minmax"):
+        mine = [n for (f, _), n in sizes.items() if f == fam]
+        assert groups[fam] - groups0[fam] == len(mine), (fam, sizes)
+        assert carried[fam] - carried0[fam] == sum(mine), (fam, sizes)
+    if mode == "window":
+        # nothing of this request took the lane another thread held
+        assert ex.ledger.solo_dispatches == lanes0
+    if mode == "lane":
+        # one solo launch per group and per call that ran alone
+        alone = len(calls) - sum(sizes.values())
+        assert ex.ledger.solo_dispatches - lanes0 == len(sizes) + alone
+
+
+@pytest.mark.parametrize("read,write,before,after", [
+    ("Sum(field=amount)", "Set(3, amount=7)",
+     {"value": 5, "count": 1}, {"value": 12, "count": 2}),
+    ("Count(Row(f=1))", "Set(3, f=1)", 1, 2),
+    ("Min(field=amount)", "Set(3, amount=-9)",
+     {"value": 5, "count": 1}, {"value": -9, "count": 1}),
+])
+def test_a_write_between_two_reads_keeps_the_request_ordered(
+        read, write, before, after, env):
+    from pilosa_tpu.exec import result_to_json
+    _, _, ex = env
+    q(ex, "Set(1, f=1) Set(1, amount=5)")
+    moved0 = ex.stats.snapshot()["counters"].get(
+        "request_call_groups_total")
+    out = q(ex, f"{read} {write} {read}")
+    assert [result_to_json(r) for r in out] == [before, True, after]
+    # the two reads are one family, but a write stands between them
+    assert ex.stats.snapshot()["counters"].get(
+        "request_call_groups_total") == moved0
+
+
+@pytest.mark.parametrize("pql,builds_plane", [
+    # the Sums come first: their filters put rows 1 and 2 on the
+    # device one by one, and the Count group counts those
+    ("Sum(Row(f=1), field=amount) Count(Row(f=1)) "
+     "Sum(Row(f=2), field=amount) Count(Row(f=2))", False),
+    # nothing resident yet: the Count group admits the whole plane
+    ("Count(Row(f=1)) Count(Row(f=2))", True),
+])
+def test_a_count_group_builds_no_plane_beside_resident_rows(
+        pql, builds_plane, env):
+    from pilosa_tpu.store.view import VIEW_STANDARD
+    _, idx, ex = env
+    q(ex, "Set(1, f=1) Set(2, f=1) Set(2, f=2) "
+          "Set(1, amount=5) Set(2, amount=7)")
+    shards = ex._shards_for(idx, None, None)
+    for _ in range(2):
+        out = q(ex, pql)
+        ex.planes.wait_builds()
+    assert [r for r in out if isinstance(r, int)] == [2, 1]
+    assert ex.planes.has_entry("i", idx.field("f"), VIEW_STANDARD,
+                               shards) is builds_plane
+
+
+def test_calls_under_one_row_filter_share_one_scan(env):
+    """A bare ``Row`` filter is the resident row itself (no identity
+    program, no copy), so the calls that name it are ONE item of the
+    group's program: two Sums and a Max under ``Row(f=1)`` and a Sum
+    under ``Row(f=2)`` compile a two-item Sum program and a one-item
+    Min/Max program."""
+    _, _, ex = env
+    q(ex, "Set(1, f=1) Set(2, f=1) Set(2, f=2) "
+          "Set(1, amount=5) Set(2, amount=7)")
+    out = q(ex, "Sum(Row(f=1), field=amount) Sum(Row(f=2), field=amount) "
+                "Sum(Row(f=1), field=amount) Max(Row(f=1), field=amount) "
+                "Min(Row(f=1), field=amount)")
+    assert [(r.value, r.count) for r in out] == [
+        (12, 2), (7, 1), (12, 2), (7, 1), (5, 1)]
+    widths = {key[0][0]: len(key[0][3]) for key in ex.fused._programs
+              if key[0][0] in ("sum-plane", "minmax-plane")}
+    assert widths == {"sum-plane": 2, "minmax-plane": 1}
+
+
+def test_a_grouped_request_reports_the_earliest_failing_call(env):
+    """The Count group runs first and its last member names a field
+    that does not exist; in call order the Sum's missing field fails
+    before it, and that is the error the request reports."""
+    _, _, ex = env
+    q(ex, "Set(1, f=1)")
+    with pytest.raises(ExecutionError, match="nosuch_a"):
+        q(ex, "Count(Row(f=1)) Sum(field=nosuch_a) "
+              "Count(Row(nosuch_b=1))")

@@ -144,6 +144,51 @@ def test_a_multi_call_request_repeats_plan_to_assemble_per_call(served):
     assert got["http_in"][0] == got["encode"][0] == got["http_out"][0] == 1
 
 
+def _grouped_counters(stats) -> dict:
+    snap = stats.snapshot()["counters"]
+    return {name: sum(snap.get(name, {}).values()) for name in
+            ("request_call_groups_total", "request_grouped_calls_total")}
+
+
+def test_a_grouped_request_passes_the_stages_once_per_group(served):
+    """Twenty interleaved Sum / Count calls are two groups: the clock
+    enters ``dispatch`` and ``read`` twice, the stages still cover the
+    handler's wall time, and the two solo launches make
+    ``batcher.requests_per_dispatch`` read 0.5 for this request."""
+    query, stats, ex, _ = served
+    rows = [("f", r) for r in range(4)] + [("g", r) for r in range(4)] \
+        + [("f", 0), ("g", 3)]
+    calls = [c for fld, r in rows for c in
+             (f"Sum(Row({fld}={r}), field=v)", f"Count(Row({fld}={r}))")]
+    pql = " ".join(calls)
+    for _ in range(3):      # planes resident, programs compiled
+        want = query(pql)["results"]
+    # in call order, what each call answers alone
+    assert want == [query(c)["results"][0] for c in calls]
+    time.sleep(0.05)
+    before, wall0 = _stages(stats), _handler_seconds(stats)
+    grouped0 = _grouped_counters(stats)
+    costs0 = ex.ledger.payload()
+    assert query(pql)["results"] == want
+    time.sleep(0.05)
+    got = _delta(before, _stages(stats))
+    wall = _handler_seconds(stats) - wall0
+    for stage in ("plan", "dispatch", "read", "deliver"):
+        assert got[stage][0] == 2, (stage, got)
+    assert "queue" not in got
+    for stage in EDGE_STAGES - {"plan", "assemble"}:
+        assert got[stage][0] == 1, (stage, got)
+    assert sum(s for _, s in got.values()) >= 0.95 * wall, (got, wall)
+    grouped = _grouped_counters(stats)
+    assert grouped["request_call_groups_total"] \
+        - grouped0["request_call_groups_total"] == 2
+    assert grouped["request_grouped_calls_total"] \
+        - grouped0["request_grouped_calls_total"] == 20
+    costs = ex.ledger.payload()
+    assert costs["soloDispatches"] - costs0["soloDispatches"] == 2
+    assert costs["windows"] == costs0["windows"]
+
+
 def test_an_in_process_execute_keeps_its_own_clock(served):
     _, stats, ex, _ = served
     ex.execute("i", "Count(Row(f=3))")
