@@ -49,8 +49,18 @@ def metric(name: str) -> dict:
 
 
 def metrics_of(cell_name: str, bench: dict) -> tuple:
-    """(end-to-end entries, per-layer entries) that this cell reports."""
+    """(end-to-end entries, per-layer entries) that this cell reports.
+    The per-layer list is the metric files' own: every file under
+    ``metrics/`` that names the cell in its ``workloads``, or has none,
+    as the entry it is or will be (the file less its ``reader``).  For a
+    cell that is an entry of ``BENCHMARK.json`` these are its
+    ``per_layer`` entries and no others: the tests hold the two
+    together."""
     def here(m):
         return "workloads" not in m or cell_name in m["workloads"]
+    per_layer = [{k: v for k, v in metric(f[:-len(".json")]).items()
+                  if k != "reader"}
+                 for f in sorted(os.listdir(os.path.join(HERE, "metrics")))
+                 if f.endswith(".json")]
     return ([m for m in bench["end_to_end"] if here(m)],
-            [m for m in bench["per_layer"] if here(m)])
+            [m for m in per_layer if here(m)])

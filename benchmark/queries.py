@@ -7,6 +7,8 @@ A *call* is one of
     {"call": "TopN",    "field": F, "n": N?, "filter": BITMAP?}
     {"call": "Sum",     "field": INT_FIELD, "filter": BITMAP?}
     {"call": "GroupBy", "fields": [F1, F2, ...]}
+    {"call": "Set",      "field": F, "column": C, "row": R}
+    {"call": "SetValue", "field": INT_FIELD, "column": C, "value": V}
 
 and a BITMAP is ``{"row": [field, id]}`` or ``{"op": OP, "args":
 [BITMAP, ...]}`` with OP one of Intersect / Union / Difference / Xor /
@@ -15,6 +17,13 @@ Not.  Every column of both datasets exists, so ``Not(x)`` is ``~x``.
 ``partial(call, shard)`` is what one shard contributes, ``combine`` adds
 the partials of every call, ``finish`` turns the total into the JSON the server must
 return for that call.
+
+The two write calls render to ``Set(C, F=R)`` and ``Set(C, INT_FIELD=V)``;
+the result of each is its acknowledgement, ``true`` for a bit or a value
+that changed.  Every read call is a sum over columns, so what a write
+request adds to a read's answer is ``partial(call, written(calls, ...))``:
+``written`` packs the request's columns, which must all be new, into a
+narrow shard of their own.
 """
 
 from __future__ import annotations
@@ -51,6 +60,10 @@ def render_call(c: dict) -> str:
         return f"Sum({', '.join(parts + ['field=' + c['field']])})"
     if kind == "GroupBy":
         return "GroupBy(" + ", ".join(f"Rows({f})" for f in c["fields"]) + ")"
+    if kind == "Set":
+        return f"Set({c['column']}, {c['field']}={c['row']})"
+    if kind == "SetValue":
+        return f"Set({c['column']}, {c['field']}={c['value']})"
     raise ValueError(f"unknown call {kind!r}")
 
 
@@ -109,8 +122,9 @@ def partial(c: dict, shard: dict):
     if kind == "Sum":
         vals = shard["ints"][c["field"]]
         if c.get("filter"):
+            # a narrow shard (``written``) is padded to whole words
             vals = vals[unpack_bits(eval_bitmap(c["filter"], shard)
-                                    .view(np.uint32))]
+                                    .view(np.uint32))[:vals.size]]
         return np.array([vals.sum(dtype=np.int64), vals.size], np.int64)
     if kind == "GroupBy":
         planes = [_rows(shard, f) for f in c["fields"]]
@@ -120,6 +134,37 @@ def partial(c: dict, shard: dict):
             acc = acc[..., None, :] & p
         return _popcount(acc)
     raise ValueError(f"unknown call {kind!r}")
+
+
+def written(calls: list, field_rows: dict, int_fields: list) -> dict:
+    """The columns that one write request creates, as a shard of their
+    own, as wide as they are many (padded to whole 64-bit words with
+    columns that hold nothing).  Each column must be new — written once,
+    with one value in every int field — so that the request ADDS this
+    shard's partials to every read: anything else is refused."""
+    columns = sorted({c["column"] for c in calls})
+    at = {col: i for i, col in enumerate(columns)}
+    words = 2 * -(-len(columns) // 64)
+    sets = {f: np.zeros((r, words), np.uint32) for f, r in field_rows.items()}
+    ints = {f: np.full(len(columns), -1, np.int64) for f in int_fields}
+    for c in calls:
+        i = at[c["column"]]
+        if c["call"] == "Set":
+            row = sets[c["field"]][c["row"]]
+            if row[i // 32] >> (i % 32) & 1:
+                raise ValueError(f"bit written twice: {render_call(c)}")
+            row[i // 32] |= np.uint32(1 << (i % 32))
+        elif c["call"] == "SetValue":
+            if ints[c["field"]][i] >= 0 or c["value"] < 0:
+                raise ValueError(f"value written twice, or negative: "
+                                 f"{render_call(c)}")
+            ints[c["field"]][i] = c["value"]
+        else:
+            raise ValueError(f"not a write call: {c['call']!r}")
+    if any((v < 0).any() for v in ints.values()):
+        raise ValueError("a written column lacks a value in an int field")
+    return {"sets": sets, "ints": {f: v.astype(np.int32)
+                                   for f, v in ints.items()}}
 
 
 def combine(totals: list | None, parts: list) -> list:

@@ -8,13 +8,16 @@
 Writes the cell's index from ``--seed``, boots the server child, warms
 the cell's own shapes, measures a closed-loop window from the client's
 side, compares EVERY response with the numpy oracle, and prints the
-contract's one JSON line last.  See ``benchmark/README.md``.
+contract's one JSON line last.  A cell whose mix writes is also stopped
+and booted a second time on the same data directory, and every read of
+its pool is compared once more.  See ``benchmark/README.md``.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -28,6 +31,7 @@ if ROOT not in sys.path:
 
 from benchmark import (controls, load, loader, manifest, queries,  # noqa: E402
                        readers, roofline, traffic)
+from benchmark.bitmaps import SHARD_WIDTH  # noqa: E402
 from benchmark.server import (HarnessError, Server, check_device,  # noqa: E402
                               child_env, device_of, health_facts)
 
@@ -39,6 +43,14 @@ CONCURRENT_WARMUP_MAX_S = 12.0
 CONCURRENT_ROUND_S = 3.0
 LADDER_ROUND_S = 1.0          # a round of 1, 2, 4, ... clients
 TRACE_START_S = 2.0           # into the window
+# a mix that writes: this many whole rounds of the client's own walk,
+# the write slot in each.  A delta overlay only grows (a compaction is
+# many minutes of such traffic away) and each doubling of it is a new
+# bucket and a new program, so no warm-up can meet them all: a fixed
+# count opens every run's window on the same overlay, 1 + 64 write
+# requests old, and a window of fewer than 191 more stays inside two
+# doublings of it (``fused.compiles_in_window`` says what it met)
+WRITE_WARMUP_ROUNDS = 64
 # /status planeCache counters that stand still when every plane asked
 # for was resident and nothing was built
 PLANE_KEYS = ("misses", "builds", "bytes", "entries")
@@ -83,6 +95,79 @@ def write_index(cell: dict, pool: traffic.Pool, data_dir: str, seed: int,
     return expected, written, totals, calls, index
 
 
+def write_state(pool: traffic.Pool, config: dict, seed: int, n_shards: int,
+                calls: list, call_index: list, totals: list) -> tuple:
+    """A mix that writes -> (its write requests in the order they will
+    be sent, the calls in each, the oracle with a state).  New columns
+    start where the loaded shards end."""
+    write, dataset = pool.write, config["dataset"]
+    field_rows = loader.dataset_field_rows(config)
+    first_column = n_shards * SHARD_WIDTH
+    per_ride = len(write["set_fields"]) + len(write["int_fields"])
+
+    def calls_of(k: int) -> list:
+        return traffic.write_calls(write, dataset, seed, k, first_column)
+
+    def added(k: int, lose: tuple) -> list:
+        kept = [c for i, c in enumerate(calls_of(k))
+                if i // per_ride not in lose]
+        shard = queries.written(kept, field_rows, write["int_fields"])
+        return [queries.partial(c, shard) for c in calls]
+
+    return (load.Writes(calls_of), per_ride * int(write["rides"]),
+            load.Oracle(calls, call_index, totals, added))
+
+
+def after_restart(tmp: str, out_dir: str, config: dict, pool: traffic.Pool,
+                  expected, control_state, run_times: dict) -> tuple:
+    """The second boot of a cell that writes, on the data directory the
+    first child was stopped on: every distinct read of the pool once,
+    against the oracle's final state.  -> (what it adds to ``compared``,
+    that child's peak device memory).  Under a control the reference's
+    own final state answers in the program's place."""
+    server = Server(os.path.join(tmp, "data"), out_dir, config["server_env"],
+                    name="restart")
+    try:
+        server.wait_up()
+        run_times["restart_to_serving_s"] = \
+            time.perf_counter() - server.t_spawn
+        n = wrong = failed = 0
+        for rid, request in enumerate(pool.requests):
+            try:
+                got = control_state[rid] if control_state is not None else \
+                    one_request(server, config["index"], request["pql"])
+            except (HarnessError, OSError, ValueError) as e:
+                failed += 1
+                say(f"after the restart, request {rid}: {e}")
+                continue
+            n += 1
+            if got != expected[rid]:
+                if not wrong:
+                    say(f"first wrong answer after the restart: request "
+                        f"{rid} {request['pql'][:120]}: got "
+                        f"{json.dumps(got)[:300]} want "
+                        f"{json.dumps(expected[rid])[:300]}")
+                wrong += 1
+        run_times["restart_to_read_back_s"] = \
+            time.perf_counter() - server.t_spawn
+        health = health_facts(server.status(), server.metrics())
+        rc = server.stop()
+        if rc != 0:
+            raise HarnessError(f"the restarted server exited rc={rc} on "
+                               f"SIGTERM; log tail:\n{server.log_tail()}")
+        memory_peak = server.memory_peak_bytes()
+        server = None
+    finally:
+        if server is not None:
+            server.stop()
+    out = {"after_restart_compared": {"value": n, "at_least": 1},
+           "after_restart_wrong": {"value": wrong, "limit": 0},
+           "after_restart_failed": {"value": failed, "limit": 0}}
+    out.update({f"after_restart_{k}": {"value": v, "limit": lim}
+                for k, (v, lim) in health.items()})
+    return out, memory_peak
+
+
 def one_request(server: Server, index: str, pql: str):
     body = server.request(f"/index/{index}/query", pql.encode())
     return json.loads(body).get("results")
@@ -113,15 +198,17 @@ def wait_fused(server: Server, index: str, request: dict, want) -> tuple:
                 f"after {WARMUP_TIMEOUT_S:.0f}s: {after}")
 
 
-def warm_up(server: Server, cell: dict, pool: traffic.Pool, expected: list,
-            orders: list, bodies: list) -> dict:
+def warm_up(server: Server, cell: dict, pool: traffic.Pool, expected,
+            orders: list, bodies: list, writes=None,
+            write_calls: int = 0) -> dict:
     """Only the cell's own shapes: the pool's cover solo (every row a
     template can name, in every position) until a round compiles
     nothing, builds nothing and misses no plane; every other request of
     the pool once, so that the window meets no query for the first time
     (the program caches what it has parsed); then concurrent rounds of
     1, 2, 4, ... clients and full ones, until two full rounds in a row
-    are as still."""
+    are as still.  A mix that writes: ``WRITE_WARMUP_ROUNDS`` rounds of
+    the one client's walk, the write slot in each as in the window."""
     index = cell["config"]["index"]
     wrong = rounds = 0
     deadline = time.monotonic() + WARMUP_TIMEOUT_S
@@ -156,12 +243,13 @@ def warm_up(server: Server, cell: dict, pool: traffic.Pool, expected: list,
             wrong += got != expected[rid]
 
     def together(clients: int | None = None,
-                 seconds: float = CONCURRENT_ROUND_S) -> None:
+                 seconds: float = CONCURRENT_ROUND_S,
+                 requests: float = math.inf) -> None:
         nonlocal wrong
-        ld = load.Load(server, index, orders[:clients], bodies)
-        ld.run(seconds=seconds)
-        verdict = load.judge(ld.records, expected)
-        wrong += verdict["wrong"] + verdict["failed"]
+        ld = load.Load(server, index, orders[:clients], bodies, writes)
+        ld.run(seconds, requests=requests)
+        verdict = load.judge(ld.records, expected, write_calls)
+        wrong += verdict["wrong"] + verdict["failed"] + verdict["acks_wrong"]
 
     until_still(solo)
     solo(sorted(set(range(len(pool.requests))) - set(pool.cover)))
@@ -179,6 +267,9 @@ def warm_up(server: Server, cell: dict, pool: traffic.Pool, expected: list,
         if not settled:
             say(f"warm-up: concurrent rounds still compiled after "
                 f"{CONCURRENT_WARMUP_MAX_S:.0f} s; going on")
+    if writes is not None:
+        together(1, math.inf, WRITE_WARMUP_ROUNDS * pool.cycle_len)
+        rounds += WRITE_WARMUP_ROUNDS
     return {"wrong": wrong, "rounds": rounds, "settled": settled}
 
 
@@ -228,6 +319,8 @@ def read_trace(trace_dir: str, seconds: float, out_dir: str, marks: dict,
                 if lo <= t_done < hi]
     trace["requests_captured"] = len(captured) or None
     try:
+        if min(captured, default=0) < 0:
+            raise ValueError("a write request reads no rows")
         trace["required_bytes"] = float(sum(
             roofline.required_row_bytes(pool.requests[rid]["calls"], n_shards)
             for rid in captured)) or None
@@ -254,7 +347,7 @@ def main(argv: list | None = None) -> int:
     args = ap.parse_args(argv)
     try:
         return run(args)
-    except (HarnessError, manifest.ManifestError) as e:
+    except (HarnessError, manifest.ManifestError, traffic.MixError) as e:
         say(f"benchmark: {e}")
         return 1
 
@@ -281,6 +374,9 @@ def run(args) -> int:
     try:
         field_rows = loader.dataset_field_rows(config)
         pool = traffic.Pool(mix, field_rows, args.seed)
+        if args.control in controls.WRITE and pool.write is None:
+            raise HarnessError(f"--control {args.control} is for a mix "
+                               f"that writes; {mix['name']} does not")
         t0 = time.perf_counter()
         expected, written, totals, calls, call_index = write_index(
             cell, pool, tmp, args.seed, n_shards)
@@ -288,6 +384,11 @@ def run(args) -> int:
         say(f"index: {n_shards} shards, {written / 1e9:.2f} GB of fragments, "
             f"{len(pool.requests)} distinct requests, "
             f"{run_times['index_write_s']:.1f} s")
+
+        writes, write_calls = None, 0
+        if pool.write is not None:
+            writes, write_calls, expected = write_state(
+                pool, config, args.seed, n_shards, calls, call_index, totals)
 
         server = Server(os.path.join(tmp, "data"), out_dir,
                         config["server_env"])
@@ -297,13 +398,27 @@ def run(args) -> int:
         check_device(device, cell["workload"]["chips"], peaks, args.rehearse)
 
         index = config["index"]
+        wrong_early = 0
+        if writes is not None:
+            # the first write opens the shard that every later one lands
+            # in, before any plane is built: no plane is then built for
+            # a shard set the window does not use
+            k, body = writes.take()
+            reply = server.request(f"/index/{index}/query", body)
+            verdict = load.judge([[(-1 - k, 0.0, 0.0, 200, reply)]], expected,
+                                 write_calls)
+            wrong_early += verdict["acks_wrong"]
+            if verdict["first_wrong"]:
+                say(f"the first write: {verdict['first_wrong']}")
         first = pool.cover[0]
-        t_fused, wrong_early = wait_fused(server, index, pool.requests[first],
-                                          expected[first])
+        t_fused, wrong = wait_fused(server, index, pool.requests[first],
+                                    expected[first])
+        wrong_early += wrong
         run_times["boot_to_fused_s"] = t_fused - server.t_spawn
         orders = [pool.client_order(c) for c in range(int(mix["clients"]))]
         bodies = [r["pql"].encode() for r in pool.requests]
-        warm = warm_up(server, cell, pool, expected, orders, bodies)
+        warm = warm_up(server, cell, pool, expected, orders, bodies, writes,
+                       write_calls)
         run_times["setup_s"] = time.perf_counter() - t_start
         say(f"set-up {run_times['setup_s']:.1f} s: boot->serving "
             f"{run_times['boot_to_serving_s']:.1f} s, ->fused "
@@ -312,7 +427,8 @@ def run(args) -> int:
 
         # -- the window ---------------------------------------------------
         status_before, prom_before = server.status(), server.metrics()
-        ld = load.Load(server, index, orders, bodies)
+        at_start = expected.copy() if writes is not None else None
+        ld = load.Load(server, index, orders, bodies, writes)
         marks: dict = {}
         trace_dir = os.path.join(tmp, "trace")
         trace_seconds = min(float(mix["trace_seconds"]), args.seconds / 2)
@@ -332,12 +448,15 @@ def run(args) -> int:
         server = None
 
         # -- after the window: compare every answer ------------------------
-        records = ld.records
-        if args.control:
-            records = controls.ALL[args.control](
+        records, control_state = ld.records, None
+        if args.control in controls.READ:
+            records = controls.READ[args.control](
                 records, cell, pool, calls, call_index, totals, args.seed,
                 n_shards)
-        verdict = load.judge(records, expected)
+        elif args.control:
+            records, control_state = controls.WRITE[args.control](
+                records, at_start, write_calls)
+        verdict = load.judge(records, expected, write_calls)
         stats = load.window_stats(ld.records, verdict["ok"], ld.t0,
                                   args.seconds)
         gaps = [g for per in ld.gaps for g in per]
@@ -353,9 +472,20 @@ def run(args) -> int:
                                      "limit": 0}}
         compared.update({k: {"value": v, "limit": lim}
                          for k, (v, lim) in health.items()})
-        correct = verdict["attempted"] >= 1 and all(
-            c["value"] <= c["limit"] for c in compared.values()
-            if "limit" in c)
+        if writes is not None:
+            # the oracle now stands at the record's end: stop, boot
+            # again on the same data directory, read everything once
+            compared.update(
+                acked_writes={"value": verdict["acked"], "at_least": 1},
+                write_acks_wrong={"value": verdict["acks_wrong"],
+                                  "limit": 0})
+            restart, restart_peak = after_restart(
+                tmp, out_dir, config, pool, expected, control_state, run_times)
+            compared.update(restart)
+            memory_peak = max(memory_peak or 0, restart_peak or 0) or None
+        correct = all(
+            c["value"] <= c["limit"] if "limit" in c
+            else c["value"] >= c["at_least"] for c in compared.values())
         if verdict["first_wrong"]:
             say(f"first wrong answer: {verdict['first_wrong']}")
 
@@ -403,6 +533,12 @@ def run(args) -> int:
                            "completed_per_second":
                                stats["completed_per_second"],
                            "bytes_written": written}
+        if writes is not None:
+            line["samples"].update(
+                writes=stats["writes"], write_ms=stats["write_ms"],
+                read_after_write_ms=stats["read_after_write_ms"],
+                restart_to_serving_s=run_times["restart_to_serving_s"],
+                restart_to_read_back_s=run_times["restart_to_read_back_s"])
         line["compared"] = compared
         record = {"args": vars(args), "line": line, "run": run_times,
                   "client": client,

@@ -43,12 +43,16 @@ def child_env(extra: dict) -> dict:
 class Server:
     """One ``python -m pilosa_tpu.cli server`` (through
     ``program.py serve``, which adds only the peak-memory reading at
-    exit)."""
+    exit).  A run's second child on the same data directory (the
+    restart of a write cell) has a ``name`` of its own."""
 
-    def __init__(self, data_dir: str, out_dir: str, extra_env: dict):
+    def __init__(self, data_dir: str, out_dir: str, extra_env: dict,
+                 name: str = "server"):
         self.port = free_port()
-        self.log_path = os.path.join(out_dir, "server.log")
-        self.memory_path = os.path.join(out_dir, "memory.json")
+        self.log_path = os.path.join(out_dir, f"{name}.log")
+        self.memory_path = os.path.join(
+            out_dir, "memory.json" if name == "server"
+            else f"{name}_memory.json")
         self._log = open(self.log_path, "wb")
         self.t_spawn = time.perf_counter()
         self.proc = subprocess.Popen(

@@ -35,7 +35,7 @@ def _oracle(cell_name):
     return cell, pool, calls, index, totals, expected
 
 
-@pytest.mark.parametrize("control", sorted(controls.ALL))
+@pytest.mark.parametrize("control", sorted(controls.READ))
 @pytest.mark.parametrize("cell_name", CELLS)
 def test_the_control_comes_out_as_not_correct(cell_name, control):
     cell, pool, calls, index, totals, expected = _oracle(cell_name)
@@ -43,7 +43,7 @@ def test_the_control_comes_out_as_not_correct(cell_name, control):
                json.dumps({"results": expected[rid]}).encode())
               for rid in pool.client_order(0)[:300]]]
     assert load.judge(sound, expected)["wrong"] == 0
-    broken = controls.ALL[control](sound, cell, pool, calls, index, totals,
+    broken = controls.READ[control](sound, cell, pool, calls, index, totals,
                                    SEED, N_SHARDS)
     verdict = load.judge(broken, expected)
     assert verdict["attempted"] == 300

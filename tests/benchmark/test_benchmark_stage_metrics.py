@@ -75,15 +75,34 @@ def test_every_stage_the_window_path_enters_is_in_status(rehearsal):
     assert seen <= set(STAGES)
 
 
-@pytest.mark.parametrize("name", STAGE_METRICS)
+def _metric_files():
+    return sorted(f[:-len(".json")] for f in os.listdir(
+        os.path.join(REPO, "benchmark", "metrics")) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("name", _metric_files())
 def test_metric_file_and_benchmark_json_agree(name):
+    """The harness takes a cell's per-layer list from the metric files
+    (``manifest.metrics_of``) and the driver takes it from
+    ``BENCHMARK.json``: in every cell that is an entry, a file reports
+    exactly where an entry of its name does, wherever that entry stands
+    in the list, and the two are equal."""
     bench = manifest.benchmark_json()
-    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
-    decl = manifest.metric(name)
-    assert {k: v for k, v in decl.items() if k != "reader"} == entry
-    # new entries stand at the end of the list, in the issue's order
-    assert [m["name"] for m in bench["per_layer"]][-len(STAGE_METRICS):] \
-        == STAGE_METRICS
+    decl = {k: v for k, v in manifest.metric(name).items() if k != "reader"}
+    assert decl["name"] == name
+    for cell in (w["name"] for w in bench["workloads"]):
+        def here(m):
+            return "workloads" not in m or cell in m["workloads"]
+        entries = [m for m in bench["per_layer"]
+                   if m["name"] == name and here(m)]
+        assert entries == ([decl] if here(decl) else []), cell
+        assert (decl in manifest.metrics_of(cell, bench)[1]) == here(decl)
+
+
+def test_every_per_layer_entry_has_its_file():
+    bench = manifest.benchmark_json()
+    assert {m["name"] for m in bench["per_layer"]} <= set(_metric_files())
+    assert "api.parse_ms" not in _metric_files()
 
 
 def _leaves(expr):
