@@ -32,11 +32,12 @@ def lost_shard(records, cell, pool, calls, call_index, totals, seed,
 
 def approximate(records, cell, pool, calls, call_index, totals, seed,
                 n_shards) -> list:
-    """Exactness broken: every count kept to four significant digits (an
-    approximate answer where the configuration states an exact one)."""
+    """Exactness broken: every count, and every sum, kept to four
+    significant digits (an approximate answer where the configuration
+    states an exact one)."""
     def coarse(x):
         if isinstance(x, dict):
-            return {k: coarse(v) if k in ("count", "value") else v
+            return {k: coarse(v) if k in ("count", "value", "agg") else v
                     for k, v in x.items()}
         if isinstance(x, list):
             return [coarse(v) for v in x]

@@ -15,7 +15,7 @@ import os
 import numpy as np
 
 from benchmark import datasets, queries
-from benchmark.bitmaps import (WORDS, bsi_depth, bsi_rows, serialize_dense)
+from benchmark.bitmaps import WORDS, bsi_depth, bsi_rows, serialize_rows
 
 
 def fragment_dir(data_dir: str, index: str, field: str, view: str) -> str:
@@ -43,14 +43,14 @@ def _load_chunk(task: tuple):
     gen = datasets.generator(config["dataset"]["kind"])
     index = config["index"]
     int_fields = config["dataset"].get("int_fields", {})
-    all_ones = serialize_dense(np.full((1, WORDS), 0xFFFFFFFF, np.uint32))
+    all_ones = serialize_rows(np.full((1, WORDS), 0xFFFFFFFF, np.uint32))
     totals, written = None, 0
     for shard in shards:
         data = gen(config["dataset"], seed, shard)
-        blobs = {(f, "standard"): serialize_dense(rows)
+        blobs = {(f, "standard"): serialize_rows(rows)
                  for f, rows in data["sets"].items()}
         for f, vals in data["ints"].items():
-            blobs[(f, f"bsi_{f}")] = serialize_dense(
+            blobs[(f, f"bsi_{f}")] = serialize_rows(
                 bsi_rows(vals, bsi_depth(int_fields[f]["max"])))
         blobs[("_exists", "standard")] = all_ones
         for (f, view), blob in blobs.items():

@@ -30,6 +30,8 @@ def _leaves(bitmap: dict, rows: set) -> None:
     if "row" in bitmap:
         rows.add(tuple(bitmap["row"]))
         return
+    if "cond" in bitmap:
+        raise ValueError("no byte count is defined for a range row")
     if bitmap["op"] == "Not":
         rows.add(("_exists", 0))  # Not(x) = exists & ~x
     for a in bitmap["args"]:

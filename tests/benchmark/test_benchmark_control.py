@@ -16,7 +16,7 @@ from benchmark import run as bench_run
 from benchmark import server as bench_server
 
 CELLS = ("pibench1b.point_c1", "pibench1b.intersect_c32", "taxi333m.dash_c1",
-         "pibench1b.trees_c32", "taxi333m.dash_c8")
+         "pibench1b.trees_c32", "taxi333m.dash_c8", "taxi333m.analytic_c1")
 SEED, N_SHARDS = 2_400_000_029, 2
 
 

@@ -3,6 +3,7 @@ at two shards: every template's oracle answer equals a brute force over
 unpacked bits; the seed decides the request sequence."""
 
 import json
+import operator
 import os
 
 import numpy as np
@@ -11,9 +12,11 @@ import pytest
 from benchmark import bitmaps, load, loader, manifest, queries, traffic
 
 CELLS = ("pibench1b.point_c1", "pibench1b.intersect_c32", "taxi333m.dash_c1",
-         "pibench1b.trees_c32", "taxi333m.dash_c8")
+         "pibench1b.trees_c32", "taxi333m.dash_c8", "taxi333m.analytic_c1")
 N_SHARDS = 2
 SEED = 2_400_000_011          # over 2**31, as the driver's are
+CMP = {"<": operator.lt, "<=": operator.le, ">": operator.gt,
+       ">=": operator.ge, "==": operator.eq, "!=": operator.ne}
 
 
 def _cell_templates():
@@ -40,12 +43,19 @@ def _shards(cell):
     return _SHARDS[key]
 
 
-def _brute_bitmap(b, bits):
-    """bool[2^20], column by column, from the unpacked rows."""
+def _brute_bitmap(b, data, bits):
+    """bool[2^20], column by column, from the unpacked rows and the
+    int field's values."""
     if "row" in b:
         field, row = b["row"]
         return bits[field][row]
-    args = [_brute_bitmap(a, bits) for a in b["args"]]
+    if "cond" in b:
+        field, op, *v = b["cond"]
+        vals = data["ints"][field]
+        if op == "between":
+            return np.array([v[0] <= x <= v[1] for x in vals.tolist()])
+        return CMP[op](vals, v[0])
+    args = [_brute_bitmap(a, data, bits) for a in b["args"]]
     op = b["op"]
     if op == "Not":
         return np.logical_not(args[0])
@@ -62,13 +72,13 @@ def _brute(call, shards):
     """The server's JSON for one call, by counting columns."""
     kind = call["call"]
     if kind == "Count":
-        return sum(int(_brute_bitmap(call["of"], bits).sum())
-                   for _, bits in shards)
+        return sum(int(_brute_bitmap(call["of"], data, bits).sum())
+                   for data, bits in shards)
     if kind == "TopN":
         n_rows = shards[0][1][call["field"]].shape[0]
         counts = [0] * n_rows
-        for _, bits in shards:
-            keep = _brute_bitmap(call["filter"], bits) \
+        for data, bits in shards:
+            keep = _brute_bitmap(call["filter"], data, bits) \
                 if call.get("filter") else True
             for r in range(n_rows):
                 counts[r] += int((bits[call["field"]][r] & keep).sum())
@@ -78,22 +88,29 @@ def _brute(call, shards):
     if kind == "Sum":
         total = count = 0
         for data, bits in shards:
-            keep = _brute_bitmap(call["filter"], bits)
+            keep = _brute_bitmap(call["filter"], data, bits)
             total += int(data["ints"][call["field"]][keep].sum(dtype=np.int64))
             count += int(keep.sum())
         return {"value": total, "count": count}
     if kind == "GroupBy":
-        fa, fb = call["fields"]
-        out = []
-        na, nb = (shards[0][1][f].shape[0] for f in (fa, fb))
-        for a in range(na):
-            for b in range(nb):
-                c = sum(int((bits[fa][a] & bits[fb][b]).sum())
-                        for _, bits in shards)
-                if c:
-                    out.append({"group": [{"field": fa, "rowID": a},
-                                          {"field": fb, "rowID": b}],
-                                "count": c})
+        fields, out = call["fields"], []
+        for rows in np.ndindex(*(shards[0][1][f].shape[0] for f in fields)):
+            count = total = 0
+            for data, bits in shards:
+                keep = _brute_bitmap(call["filter"], data, bits) \
+                    if call.get("filter") else True
+                for f, r in zip(fields, rows):
+                    keep = keep & bits[f][r]
+                count += int(keep.sum())
+                if call.get("aggregate"):
+                    vals = data["ints"][call["aggregate"]["sum"]]
+                    total += sum(vals[keep].tolist())
+            if count:
+                out.append({"group": [{"field": f, "rowID": r}
+                                      for f, r in zip(fields, rows)],
+                            "count": count})
+                if call.get("aggregate"):
+                    out[-1]["agg"] = total
         return out
     raise AssertionError(kind)
 
@@ -135,7 +152,7 @@ def test_fragment_round_trip_and_bsi_rows():
     rng = np.random.default_rng(3)
     rows = rng.integers(0, 1 << 32, size=(3, bitmaps.WORDS), dtype=np.uint32)
     rows[1] = 0                      # an empty row writes no container
-    blob = bitmaps.serialize_dense(rows)
+    blob = bitmaps.serialize_rows(rows)   # half full: bitmap containers
     magic, version, n = np.frombuffer(blob[:8], "<u2,<u2,<u4")[0]
     assert (magic, version, n) == (12348, 0, 32)
     keys = np.frombuffer(blob[8:8 + 12 * n], "<u8,<u2,<u2")["f0"]

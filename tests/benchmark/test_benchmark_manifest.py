@@ -29,9 +29,12 @@ def _bench():
         return json.load(fh)
 
 
+# and the probes, which the tests hold as their inputs (conftest.py)
 @pytest.mark.parametrize("path", [
     p for kind in ("configs", "traffic", "workloads", "metrics")
-    for p in _files(kind)], ids=lambda p: os.path.relpath(p, BENCH))
+    for p in _files(kind)] + sorted(glob.glob(os.path.join(
+        os.path.dirname(__file__), "probes", "*", "*.json"))),
+    ids=lambda p: os.path.relpath(p, BENCH))
 def test_file_loads_and_is_named_for_what_it_holds(path):
     with open(path) as fh:
         obj = json.load(fh)
