@@ -130,6 +130,29 @@ def row_counts(plane: jax.Array, filter_words: jax.Array | None = None) -> jax.A
     return count(plane)
 
 
+def pair_counts(a_plane: jax.Array, b_plane: jax.Array,
+                filter_words: jax.Array | None = None) -> jax.Array:
+    """Intersection counts of every row of one plane with every row of
+    another, summed over shards: ``out[c, r] = Σ_s Σ_w popcount(filter[s, w]
+    & a[s, c, w] & b[s, r, w])``.
+
+    a_plane: uint32[S, Ra, W]; b_plane: uint32[S, Rb, W]; filter:
+    uint32[S, W] -> int32[Ra, Rb] (exact while S <= SAFE_SHARD_SUM).
+
+    The GroupBy kernel (reference: ``executor.go#executeGroupByShard``
+    intersects per combination): both planes are read where they lie,
+    through broadcasts only, so XLA fuses AND + popcount + reduce into
+    one pass with no intermediate — a ``uint32[S, Ra, Rb, W]`` is never
+    written.  The reduce is flat on purpose: :func:`count`'s
+    ``COUNT_TILE`` reshape re-tiles a whole plane on the TPU when the
+    row axis is not the reduced one.
+    """
+    words = jnp.bitwise_and(a_plane[:, :, None, :], b_plane[:, None, :, :])
+    if filter_words is not None:
+        words = jnp.bitwise_and(words, filter_words[:, None, None, :])
+    return jnp.sum(popcount(words), axis=(0, 3), dtype=jnp.int32)
+
+
 def selected_row_counts(plane: jax.Array, row_idx: jax.Array,
                         sorted_idx: bool = False) -> jax.Array:
     """Popcounts of N SELECTED rows in one pass over only their memory.

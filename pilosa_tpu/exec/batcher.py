@@ -2071,10 +2071,7 @@ class CountBatcher:
             try:
                 args, (agg_kind, _meta), _sig = p.nodes
                 planes, ci, lp, fw, ap, dl = args
-                ad = ((dl.col_shard, dl.col_word, dl.col_vals,
-                       dl.col_mask) if dl is not None else None)
-                out = gb._groupby_program(planes, ci, lp, fw, ap,
-                                          agg_kind, agg_delta=ad)
+                out = gb.run_block(planes, ci, lp, fw, ap, agg_kind, dl)
                 self._deliver(p, {k: np.asarray(v)
                                   for k, v in out.items()})
             except Exception as e2:  # noqa: BLE001

@@ -15,6 +15,7 @@ the reference (``executor.Execute`` translate steps).
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import OrderedDict
@@ -449,6 +450,10 @@ class Executor:
             self.stats.count("request_call_groups_total", 0, family=kind)
             self.stats.count("request_grouped_calls_total", 0,
                              family=kind)
+        # GroupBy blocks handed on (_execute_groupby), by how their
+        # counts are computed (exec.groupby.block_form)
+        for form in ("pair", "mapped"):
+            self.stats.count("groupby_blocks_total", 0, form=form)
         # device-cost ledger + flight recorder (r19): one ledger and
         # one event ring per executor, threaded into every layer that
         # spends device time (planes, pager, fused cache, batcher,
@@ -3694,7 +3699,7 @@ class Executor:
         total = 0
         agg_kind = (None if minmax_host
                     else self._GROUPBY_AGGS.get(agg_name))
-        run = None
+        submit = gb.run_block
         if (self.batcher is not None
                 and len(ctx.shards) <= self._REDUCE_SHARD_MAX):
             # GroupBy blocks ride the window machinery (r20): the
@@ -3706,18 +3711,22 @@ class Executor:
             import hashlib
             deadline = self._query_deadline()
 
-            def run(pl, ci, lp, fw, ap, agg, ad):
+            def submit(pl, ci, lp, fw, ap, agg, ad):
                 _stage("plan")
                 # ci arrives as the HOST combo array (see iter_blocks)
                 # — the digest costs no device round trip
-                meta = (int(ci.shape[0]) if pl else 1,
-                        int(lp.shape[1]),
+                meta = (math.prod(ci.shape[:-1]), int(lp.shape[1]),
                         int(ap.shape[1]) - 2 if ap is not None else 0)
                 digest = hashlib.blake2b(
                     ci.tobytes(), digest_size=8).digest()
                 return self.batcher.submit_groupby(
                     pl, ci, lp, fw, ap, agg, meta, digest, delta=ad,
                     deadline=deadline)
+
+        def run(pl, ci, lp, fw, ap, agg, ad):
+            self.stats.count("groupby_blocks_total", 1,
+                             form=gb.block_form(pl, agg))
+            return submit(pl, ci, lp, fw, ap, agg, ad)
         for combo_rows, out in gb.iter_blocks(
                 specs, filter_words,
                 None if minmax_host else agg_plane, agg_kind,

@@ -3,15 +3,22 @@
 Reference: ``executor.go#executeGroupByShard`` walks the cross-product of
 ``Rows()`` selections recursively, intersecting per combination.  Host
 recursion costs one dispatch (plus a device->host read) per prefix
-combination; this module instead runs ONE compiled program that loops
-over prefix combinations with ``lax.map`` (device-side, no host reads)
-and vectorizes the innermost level as a popcount matrix — O(1) dispatch
-+ O(1) reads for the entire GroupBy, any number of levels.
+combination; this module instead runs ONE compiled program per block of
+combinations — O(1) dispatch + O(1) reads for the entire GroupBy, any
+number of levels.
 
-Aggregates (``aggregate=Sum/Count/Min/Max(field=f)``) ride the same
-program: per-combination BSI bit counts (Sum) or min/max bit descent
-(Min/Max) reduce over shards on device in int32; the host finishes the
-``<< b`` weighting in exact int64 (``bsi.combine_sum`` policy).
+Counts come from PAIRS OF ROWS, not copies of them: the innermost
+``Rows()`` level and the prefix level above it are two vectorised axes
+of one ``kernels.pair_counts`` matrix over the two resident planes, and
+a combination selects from that small int32 matrix, never from a plane.
+Only the levels ABOVE those two loop (a sequential ``lax.map`` on the
+device, one pair matrix a step under the step's prefix rows).
+
+Aggregates (``aggregate=Sum/Count/Min/Max(field=f)``) keep a
+per-combination body mapped over every prefix level: per-combination
+BSI bit counts (Sum) or min/max bit descent (Min/Max) reduce over shards
+on device in int32; the host finishes the ``<< b`` weighting in exact
+int64 (``bsi.combine_sum`` policy).
 """
 
 from __future__ import annotations
@@ -35,13 +42,59 @@ MAX_SHARDS = kernels.SAFE_SHARD_SUM
 MINMAX_MAX_DEPTH = 30
 
 
+def block_form(prefix_planes, agg) -> str:
+    """How one block's counts are computed: ``"pair"`` (a pair-count
+    matrix over the two innermost levels) or ``"mapped"`` (one body per
+    combination: aggregates, and a GroupBy of a single ``Rows()``)."""
+    return "pair" if prefix_planes and agg is None else "mapped"
+
+
+def _prefix_words(planes, ix, filter_words):
+    """``filter_words`` ANDed with row slot ``ix[l]`` of every plane
+    (uint32[S, W]; None when there is neither)."""
+    prefix = filter_words
+    for lvl, plane in enumerate(planes):
+        row = plane[:, ix[lvl], :]
+        prefix = row if prefix is None else jnp.bitwise_and(prefix, row)
+    return prefix
+
+
+def _pair_counts_block(prefix_planes, combo_idx, last_plane, filter_words):
+    """Counts of one pair-form block, int32[Bo * n, n_last].
+
+    combo_idx: int32[Bo, n, L-1] — Bo combinations of the OUTER prefix
+    levels, each with the n selected rows of the innermost prefix level
+    (slots 0..n-1 of its plane: ``PlaneCache.rows_plane`` puts the
+    selected rows first, the pow2 pad behind them stays out of the scan).
+    """
+    *outer, inner = prefix_planes
+    n = combo_idx.shape[1]
+
+    def pairs_under(ix):
+        return kernels.pair_counts(inner[:, :n, :], last_plane,
+                                   _prefix_words(outer, ix, filter_words))
+
+    if outer:
+        # a plain (sequential) map: one step is a whole pair matrix, and
+        # its prefix rows are dynamic slices fused into it.  A vmapped
+        # step turns the row read into a gather that the TPU expands to
+        # a loop copying every row out before anything is counted.
+        mats = jax.lax.map(pairs_under, combo_idx[:, 0, :-1])
+    else:
+        mats = pairs_under(None)[None]
+    counts = jnp.take_along_axis(mats, combo_idx[:, :, -1:], axis=1)
+    return counts.reshape(-1, last_plane.shape[1])
+
+
 def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
                 agg_plane, agg, agg_delta=None):
     """All GroupBy combination counts (+ optional aggregate) in one program.
 
     prefix_planes: tuple of uint32[S, n_l, W], one per non-innermost
-        ``Rows()`` level (possibly empty); ``combo_idx`` int32[C, L-1]
-        indexes one row slot per level per combination.
+        ``Rows()`` level (possibly empty).  ``combo_idx`` holds one row
+        slot per level per combination: int32[Bo, n, L-1] in the pair
+        form (:func:`block_form`; see :func:`_pair_counts_block`),
+        int32[C, L-1] in the mapped form.
     last_plane: uint32[S, n_last, W] — innermost level, vectorized.
     filter_words: uint32[S, W] | None.
     agg_plane: BSI uint32[S, D+2, W] | None; agg: None | "sum" | "minmax".
@@ -52,9 +105,12 @@ def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
         by a merged mini plane), so GroupBy stays fold-free under
         sustained BSI ingest.
 
-    Returns per-combination stacked outputs: counts int32[C, n_last] and
-    aggregate arrays (see body).
+    Returns per-combination stacked outputs: counts int32[C, n_last]
+    (C = Bo * n in the pair form) and aggregate arrays (see body).
     """
+    if block_form(prefix_planes, agg) == "pair":
+        return {"counts": _pair_counts_block(prefix_planes, combo_idx,
+                                             last_plane, filter_words)}
     mini = excl = None
     if agg is not None and agg_delta is not None:
         from pilosa_tpu.ingest.delta import (bsi_excl_filter,
@@ -67,10 +123,7 @@ def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
         cs_c = jnp.clip(cs, 0, s - 1)
 
     def body(ix):
-        prefix = filter_words
-        for lvl, plane in enumerate(prefix_planes):
-            row = plane[:, ix[lvl], :]
-            prefix = row if prefix is None else jnp.bitwise_and(prefix, row)
+        prefix = _prefix_words(prefix_planes, ix, filter_words)
         counts = jnp.sum(kernels.row_counts(last_plane, prefix), axis=0,
                          dtype=jnp.int32)
         out = {"counts": counts}
@@ -136,14 +189,25 @@ def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
     if not prefix_planes:
         return jax.tree.map(lambda x: x[None],
                             body(jnp.zeros((0,), jnp.int32)))
-    # batch_size vmaps combos in chunks: a plain lax.map serializes one
-    # tiny AND+popcount kernel per combination (measured ~1.7 ms each on
-    # a v5e — 4.3 s for a 50x50 prefix grid); 32-wide batches amortize
-    # the per-iteration overhead while bounding the fused intermediate
+    # aggregates only: batch_size vmaps combos in chunks of 32, which
+    # amortizes the per-iteration overhead of a serial map (~1.7 ms a
+    # combination on a v5e — 4.3 s for a 50x50 prefix grid) while
+    # bounding the fused intermediate.  Its price is the vmapped row
+    # read above: a gather that copies each chunk's prefix rows out.
     return jax.lax.map(body, combo_idx, batch_size=32)
 
 
 _groupby_program = partial(jax.jit, static_argnames=("agg",))(groupby_out)
+
+
+def run_block(planes, combo_idx, last_plane, filter_words, agg_plane, agg,
+              delta=None):
+    """One block as its own program (no batcher, and the batcher's
+    fallback); ``delta`` is the agg plane's ``BsiOverlay`` or None."""
+    at = ((delta.col_shard, delta.col_word, delta.col_vals,
+           delta.col_mask) if delta is not None else None)
+    return _groupby_program(planes, combo_idx, last_plane, filter_words,
+                            agg_plane, agg, agg_delta=at)
 
 
 def block_part_names(agg: str | None) -> tuple[str, ...]:
@@ -208,17 +272,21 @@ LIMIT_BLOCK = 1024
 
 
 def iter_blocks(specs, filter_words, agg_plane, agg_kind,
-                limited: bool = False, run=None, agg_delta=None):
+                limited: bool = False, run=run_block, agg_delta=None):
     """Execute the program over lexicographic combination blocks.
 
     specs: list of (field, rows np.ndarray, PlaneSet); the last spec is
     the vectorized innermost level.  Yields (combo_rows uint64[B, L-1],
     outputs dict of np arrays) in combination order; callers stop
     consuming once a ``limit=`` is satisfied.  Blocks are padded to one
-    static shape (single compile), the pad tail is sliced off here.
+    static shape (single compile), the pad tail is sliced off here.  A
+    pair-form block (:func:`block_form`) holds whole runs of the
+    innermost prefix level — its combinations go out shaped
+    int32[Bo, n, L-1], so the level's row count is a shape the program
+    sees — and a block boundary falls between outer combinations.
 
-    ``run`` (r20): an alternative block dispatcher with the
-    ``_groupby_program`` signature returning a dict of HOST arrays —
+    ``run`` (r20): an alternative block dispatcher with
+    :func:`run_block`'s signature returning a dict of HOST arrays —
     the executor routes blocks through the batcher's collection
     window here, so a GroupBy block shares its dispatch window and
     packed readback with concurrent Counts/aggregates instead of
@@ -239,22 +307,24 @@ def iter_blocks(specs, filter_words, agg_plane, agg_kind,
         per_combo += n_last * (2 * depth + 1) * 4
     elif agg_kind == "minmax":
         per_combo += n_last * 16
-    block = max(1, min(n_combos, BLOCK_OUT_BYTES // per_combo,
-                       *([LIMIT_BLOCK] if limited else [])))
-
     planes = tuple(ps.plane for _, _, ps in prefix_specs)
+    pair = block_form(planes, agg_kind) == "pair"
+    # a block is a whole number of units: one combination, or in the
+    # pair form one run of the innermost prefix level
+    unit = len(slot_levels[-1]) if pair else 1
+    block = max(unit, min(n_combos, BLOCK_OUT_BYTES // per_combo,
+                          *([LIMIT_BLOCK] if limited else []))
+                // unit * unit)
+
     aplane = agg_plane.plane if agg_plane is not None else None
-    if run is None:
-        def run(pl, ci, lp, fw, ap, agg, ad):
-            at = ((ad.col_shard, ad.col_word, ad.col_vals,
-                   ad.col_mask) if ad is not None else None)
-            return _groupby_program(pl, ci, lp, fw, ap, agg,
-                                    agg_delta=at)
     for start in range(0, n_combos, block):
         sl = combo_slots[start:start + block]
         n = sl.shape[0]
         if n < block:  # pad to the compiled shape; tail dropped below
-            sl = np.concatenate([sl, np.repeat(sl[-1:], block - n, axis=0)])
+            sl = np.concatenate(
+                [sl, np.tile(sl[-unit:], ((block - n) // unit, 1))])
+        if pair:
+            sl = sl.reshape(block // unit, unit, -1)
         # the combo block stays a HOST array here: the batcher route
         # hashes it for dedupe (a device array would force a blocking
         # D2H read per block just to digest bytes that originated

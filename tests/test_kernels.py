@@ -137,6 +137,70 @@ def test_row_counts_shapes(rng, s, r, w, filt):
     assert np.array_equal(got, _np_popcount(masked).sum(-1))
 
 
+@pytest.mark.parametrize("s,ra,rb,w,filt,pad", [
+    (1, 4, 4, 96, None, 0),          # one shard
+    (1, 4, 4, 96, "random", 0),
+    (3, 10, 8, 2048, None, 0),       # several shards, Ra != Rb
+    (3, 10, 8, 2048, "random", 0),
+    (3, 16, 8, 2048, "random", 6),   # a pow2 plane's zero pad slots
+    (2, 8, 32, 2 * T + 96, None, 3),
+    (5, 1, 7, 299, "random", 0),     # a single prefix row
+    (5, 7, 1, 299, None, 0),
+    (2, 5, 3, 96, "empty", 0),       # an empty filter counts nothing
+    (2, 5, 3, 4 * T, "ones", 0),     # the largest partial sums there are
+])
+def test_pair_counts_matches_numpy(rng, s, ra, rb, w, filt, pad):
+    a = _fill(rng, (s, ra, w), "random")
+    a[:, ra - pad:, :] = 0
+    b = _fill(rng, (s, rb, w), "random")
+    fw = None if filt is None else _fill(rng, (s, w), filt)
+    got = np.asarray(kernels.pair_counts(a, b, fw))
+    words_ = a[:, :, None, :] & b[:, None, :, :]
+    if fw is not None:
+        words_ = words_ & fw[:, None, None, :]
+    assert got.shape == (ra, rb) and got.dtype == np.int32
+    assert np.array_equal(got, _np_popcount(words_).sum(axis=(0, 3)))
+    assert not got[ra - pad:].any()
+
+
+def _plane_reads(lowered_text, plane_shape):
+    """The indexed reads of a lowered program whose operand is a whole
+    plane: ``stablehlo.gather`` / ``dynamic_slice`` lines naming the
+    plane's tensor type among their operand types."""
+    plane_type = "tensor<" + "x".join(map(str, plane_shape)) + "xui32>"
+    return [line.strip() for line in lowered_text.splitlines()
+            if ("stablehlo.gather" in line
+                or "stablehlo.dynamic_slice" in line)
+            and plane_type in line.split("->")[0]]
+
+
+@pytest.mark.parametrize("filt", [False, True])
+def test_a_two_level_groupby_gathers_nothing_from_a_plane(rng, filt):
+    """The guard a CPU run can give against the TPU's gather-by-``while``
+    coming back: with one prefix level no row of either plane is read
+    by index — the one indexed read is the take on the int32 matrix."""
+    import jax
+    from pilosa_tpu.exec import groupby as gb
+    s, n, w = 3, 10, 2048
+    prefix = _fill(rng, (s, 16, w), "random")
+    prefix[:, n:, :] = 0
+    last = _fill(rng, (s, 8, w), "random")
+    fw = _fill(rng, (s, w), "random") if filt else None
+    ci = np.arange(n, dtype=np.int32).reshape(1, n, 1)
+    program = jax.jit(gb.groupby_out, static_argnames=("agg",))
+    text = program.lower((prefix,), ci, last, fw, None, None).as_text()
+    assert _plane_reads(text, prefix.shape) == []
+    assert _plane_reads(text, last.shape) == []
+    assert "stablehlo.while" not in text
+    gathers = [ln for ln in text.splitlines() if "stablehlo.gather" in ln]
+    assert len(gathers) == 1 and "xi32>" in gathers[0].split("->")[0]
+    masked = prefix[:, :n, None, :] & last[:, None, :, :]
+    if filt:
+        masked = masked & fw[:, None, None, :]
+    got = np.asarray(program((prefix,), ci, last, fw, None, None)["counts"])
+    assert np.array_equal(got, _np_popcount(masked).sum(axis=(0, 3)))
+
+
 def test_row_counts_and_topn(rng):
     n_rows = 16
     plane = rng.integers(0, 2**32, size=(n_rows, W), dtype=np.uint32)
