@@ -650,9 +650,9 @@ class CountBatcher:
     def _fastlane_agg(self, kind: str, plane, filters: list, delta):
         """One request's K BSI Sum (or Min/Max) items over one plane
         dispatched inline on the caller thread: ONE program at the
-        request's own width (no pow2 padding: a padded item is a scan
-        XLA cannot fold away), one read, K decodes.  None = fall back
-        to the window."""
+        request's own width (no pow2 padding: a padded item is one more
+        filter pass of a Sum, one more plane scan of a Min/Max), one
+        read, K decodes.  None = fall back to the window."""
         _stage("dispatch")
         t0 = time.perf_counter()
         try:
@@ -665,10 +665,12 @@ class CountBatcher:
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
-        # each distinct item scans the plane under its own filter
+        # the K Sums read the plane once (bsi.sum_pair_counts); each
+        # distinct Min/Max item descends it under its own filter
         scanned = {id(f): getattr(f, "nbytes", 0) for f in filters}
+        reads = 1 if kind == "sum" else len(scanned)
         self._fastlane_done(
-            kind, len(scanned) * plane.nbytes + sum(scanned.values())
+            kind, reads * plane.nbytes + sum(scanned.values())
             + (delta.nbytes if delta is not None else 0),
             wall=time.perf_counter() - t0)
         return vals

@@ -139,13 +139,14 @@ def dedupe_filters(filters) -> tuple[list, list[int]]:
 
 
 def _after(plane, row):
-    """Inside a K-item plane-batch program: make the next item's scan
-    of ``plane`` wait for the previous item's ``row``.  Left free, XLA
-    fuses the K items side by side and keeps every item's column masks
-    in HBM at once — 2 x K x [S, W] words of temporaries (840 MB for
-    ten Sums over 318 shards, by the v5e compiler's memory analysis)
-    beside planes that fill the chip; in turn, each item is the
-    one-item program's fusions and the temporaries are one item's."""
+    """Inside the K-item Min/Max program: make the next item's bit
+    descent over ``plane`` wait for the previous item's ``row``.  Left
+    free, XLA fuses the K items side by side and keeps every item's
+    candidate masks in HBM at once (840 MB for ten items over 318
+    shards, by the v5e compiler's memory analysis) beside planes that
+    fill the chip; in turn, the temporaries are one item's.  (The Sum
+    program needs none: ``bsi.sum_pair_counts`` reads the plane once
+    for all K items.)"""
     return jax.lax.optimization_barrier((plane, row))
 
 
@@ -327,6 +328,11 @@ class FusedCache:
         # (the class that once collapsed 32 clients to ~23 qps, see
         # pow2_bucket) visible on /metrics instead of only as latency
         self._stats = stats or NopStats()
+        # K-item Sum launches and the items that shared each one's read
+        # (run_agg_plane_batch), registered at 0 so the series print
+        # before the first one
+        self._stats.count("sum_plane_launches_total", 0)
+        self._stats.count("sum_plane_items_total", 0)
         # compile observability (r19): per-family compile seconds with
         # first-compile trace exemplars land in the cost ledger, and
         # every compile is a flight-recorder event — a recompile storm
@@ -1005,28 +1011,6 @@ class FusedCache:
                                 donate=(len(arrays),))(*arrays, scratch)
         return self._cached(key, build)(*arrays)
 
-    def run_sum_batch(self, flags: tuple, leaves):
-        """K BSI Sum items (same bit depth) in ONE program.  ``flags[k]``
-        = item k has a filter leaf; leaves alternate plane[, filter] per
-        item.  Returns int32[K, n_shards, 2*depth+1]: per-bit positive
-        counts, per-bit negative counts, non-null count — one stacked
-        array = one host read; ``bsi.combine_sum`` finishes exactly."""
-        def build():
-            def program(*ls):
-                rows = []
-                i = 0
-                for has_filter in flags:
-                    plane = ls[i]
-                    flt = ls[i + 1] if has_filter else None
-                    i += 2 if has_filter else 1
-                    pos, neg, cnt = bsik.bit_counts(plane, flt)
-                    rows.append(jnp.concatenate(
-                        [pos, neg, cnt[..., None]], axis=-1))
-                return jnp.stack(rows)
-            return program
-        return self._cached((flags, sharding_key(leaves[0]),
-                             "sum-batch"), build)(*leaves)
-
     def run_percentile(self, plane, filter_words, nth: float):
         """Percentile in two bounded programs (cached/evicted like every
         other fused program): total count, then the on-device rank
@@ -1064,16 +1048,14 @@ class FusedCache:
     # ------------------------------------------------- BSI plane batches
     #
     # r20 (the PQL-surface work): the per-PLANE aggregate families.
-    # Unlike the legacy run_sum_batch layout (whose K items each carry
-    # their own plane leaf — K copies of a multi-GB operand in the
-    # program signature), these take ONE resident plane plus the
-    # items' filter leaves, so concurrent aggregates over the same
-    # plane co-batch into one program that references the plane once,
-    # and a pending BSI write overlay (``ingest.delta.BsiOverlay``)
-    # merges in-program: the base side scans the untouched columns
-    # (touched word columns masked out of the filter), the mini side
-    # runs the SAME kernel over the merged touched columns as a tiny
-    # standalone plane — base⊕delta exact with zero plane rewrites.
+    # These take ONE resident plane plus the items' filter leaves, so
+    # concurrent aggregates over the same plane co-batch into one
+    # program that references the plane once, and a pending BSI write
+    # overlay (``ingest.delta.BsiOverlay``) merges in-program: the base
+    # side scans the untouched columns (touched word columns masked out
+    # of the filter), the mini side runs the SAME kernel over the merged
+    # touched columns as a tiny standalone plane — base⊕delta exact with
+    # zero plane rewrites.
 
     def run_agg_plane_batch(self, kind: str, plane, filters,
                             delta=None, bucket: bool = False):
@@ -1087,6 +1069,11 @@ class FusedCache:
         together.  Returns ``(device out, each item's row in it, the
         row decoder)``."""
         uniq, assign = dedupe_filters(filters)
+        if kind == "sum":
+            # one launch, and the distinct items that share its one
+            # read of the plane (pads excluded)
+            self._stats.count("sum_plane_launches_total", 1)
+            self._stats.count("sum_plane_items_total", len(uniq))
         if bucket:
             uniq += [uniq[0]] * (pow2_bucket(len(uniq)) - len(uniq))
         flags = tuple(f is not None for f in uniq)
@@ -1122,14 +1109,19 @@ class FusedCache:
 
     def run_sum_plane_batch(self, plane, flags: tuple, filters: tuple,
                             delta=None):
-        """K BSI Sum items over ONE resident plane in one program —
+        """K BSI Sum items over ONE resident plane in one program that
+        reads the plane once (``bsi.sum_pair_counts``) —
         int32[K, n_shards, 2*depth+1], decoded by
-        ``bsi.decode_sum_packed`` exactly like :meth:`run_sum_batch`.
-        ``flags[k]`` = item k has a filter; ``filters`` holds the
-        flagged items' uint32[S, W] bitmaps in order.  With ``delta``
-        (a ``BsiOverlay``) the mini side's per-bit counts fold into
-        shard 0's row (Sum is linear over columns), so the output
-        shape and decode stay identical."""
+        ``bsi.decode_sum_packed``.  ``flags[k]`` = item k has a filter;
+        ``filters`` holds the flagged items' uint32[S, W] bitmaps in
+        order.  With ``delta`` (a ``BsiOverlay``) the base side is the
+        same pair form over the K exclusion filters, and each item's
+        mini-side counts (a tiny plane, item by item) fold into shard
+        0's row (Sum is linear over columns), so the output shape and
+        decode stay identical."""
+        from pilosa_tpu.ingest.delta import (bsi_excl_filter,
+                                             bsi_mini_filter,
+                                             bsi_mini_plane)
         n_filters = len(filters)
         bucket, delta_ops = self._delta_args(delta)
         key = (("sum-plane", plane.shape, sharding_key(plane), flags,
@@ -1137,31 +1129,25 @@ class FusedCache:
 
         def build():
             def program(p, *rest):
-                filts = rest[:n_filters]
-                dops = rest[n_filters:] or None
-                rows = []
-                fi = 0
-                for has_filter in flags:
-                    if rows:
-                        p, rows[-1] = _after(p, rows[-1])
-                    flt = filts[fi] if has_filter else None
-                    fi += 1 if has_filter else 0
-                    excl, mini, mflt = self._bsi_split(p, flt, dops)
-                    pos, neg, cnt = bsik.bit_counts(p, excl)
-                    row = jnp.concatenate(
-                        [pos, neg, cnt[..., None]], axis=-1)
-                    if mini is not None:
-                        mp, mn, mc = bsik.bit_counts(mini, mflt)
-                        adj = jnp.concatenate(
-                            [jnp.sum(mp, axis=0, dtype=jnp.int32),
-                             jnp.sum(mn, axis=0, dtype=jnp.int32),
-                             jnp.sum(mc, dtype=jnp.int32)[None]])
-                        row = row.at[0].add(adj)
-                    rows.append(row)
-                return jnp.stack(rows)
+                it = iter(rest[:n_filters])
+                items = [next(it) if has else None for has in flags]
+                if not rest[n_filters:]:
+                    return bsik.sum_pair_counts(p, items)
+                cs, cw, cv, cm = rest[n_filters:]
+                out = bsik.sum_pair_counts(
+                    p, [bsi_excl_filter(p, cs, cw, f) for f in items])
+                mini = bsi_mini_plane(p, cs, cw, cv, cm)
+                adj = []
+                for f in items:
+                    mp, mn, mc = bsik.bit_counts(
+                        mini, bsi_mini_filter(p, cs, cw, f))
+                    adj.append(jnp.concatenate(
+                        [jnp.sum(mp, axis=0, dtype=jnp.int32),
+                         jnp.sum(mn, axis=0, dtype=jnp.int32),
+                         jnp.sum(mc, dtype=jnp.int32)[None]]))
+                return out.at[:, 0].add(jnp.stack(adj))
             return program
-        return self._cached(key, build)(plane, *filters, *delta_ops) \
-            if delta_ops else self._cached(key, build)(plane, *filters)
+        return self._cached(key, build)(plane, *filters, *delta_ops)
 
     def run_minmax_plane_batch(self, plane, flags: tuple,
                                filters: tuple, delta=None):

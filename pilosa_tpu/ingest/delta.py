@@ -390,7 +390,10 @@ def bsi_mini_plane(plane: jax.Array, col_shard: jax.Array,
     gather shard 0 garbage; the mini FILTER zeroes them."""
     s = plane.shape[0]
     cs = jnp.clip(col_shard, 0, s - 1)
-    base_cols = plane[cs, :, col_word]            # [K, rows]
+    # row by row: the TPU lays a plane out as its rows, and one gather
+    # over the [S, R, W] view makes the compiler copy the whole plane
+    base_cols = jnp.stack([plane[:, r, :][cs, col_word]
+                           for r in range(plane.shape[1])], axis=1)
     merged = jnp.where(col_mask.astype(bool), col_vals, base_cols)
     return merged[..., None]                      # [K, rows, 1]
 

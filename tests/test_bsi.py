@@ -4,8 +4,10 @@ Mirrors the reference's BSI range/sum edge-case tests (sign, base,
 boundaries; ``fragment_test.go``, SURVEY.md §5)."""
 
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from pilosa_tpu.engine import bsi, kernels, words
@@ -113,3 +115,28 @@ def test_batched_shard_axis(rng):
     per_shard = bsi.min_max(planes)
     assert [t[0] for t in per_shard] == [-4, 7]   # per-shard min
     assert [t[2] for t in per_shard] == [3, 7]    # per-shard max
+
+
+@pytest.mark.parametrize("signed", [True, False], ids=["sign_set", "sign_empty"])
+@pytest.mark.parametrize("items", [
+    (None,), (0,), (0, None), (None, 0, 1, 2, 0), tuple(range(10))],
+    ids=["k1_unfiltered", "k1", "k2", "k5_dup", "k10"])
+def test_sum_pair_counts_equals_bit_counts_item_by_item(signed, items):
+    """The one-read K-item form against the per-item kernel on random
+    bits (every row, the exists and sign rows too): the sign test takes
+    the signed pass when the sign row has a bit, the unsigned one when
+    it is empty — both exact."""
+    rng = np.random.default_rng(len(items) * 2 + signed)
+    s, rows = 3, DEPTH + 2
+    plane = rng.integers(0, 2**32, (s, rows, W), dtype=np.uint32)
+    if not signed:
+        plane[:, bsi.SIGN_ROW] = 0
+    filters = rng.integers(0, 2**32, (10, s, W), dtype=np.uint32)
+    fs = [None if i is None else jnp.asarray(filters[i]) for i in items]
+    out = np.asarray(jax.jit(bsi.sum_pair_counts)(jnp.asarray(plane), fs))
+    assert out.shape == (len(items), s, 2 * DEPTH + 1)
+    for k, f in enumerate(fs):
+        pos, neg, cnt = bsi.bit_counts(jnp.asarray(plane), f)
+        want = np.concatenate([pos, neg, np.asarray(cnt)[:, None]], axis=-1)
+        np.testing.assert_array_equal(out[k], want)
+    assert (out[..., DEPTH:2 * DEPTH] != 0).any() == signed
