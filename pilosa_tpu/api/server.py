@@ -35,7 +35,7 @@ from pilosa_tpu import __version__, fault
 from pilosa_tpu.api.api import API, ApiError
 from pilosa_tpu.obs import metrics as _metrics
 from pilosa_tpu.obs.metrics import (StageTimer, enter_stage,
-                                    set_current_timer)
+                                    set_current_timer, swap_span)
 from pilosa_tpu.store.health import StorageFaultError as _StorageFaultError
 
 
@@ -97,6 +97,8 @@ class Handler(BaseHTTPRequestHandler):
     # connection): reclaims handler threads from clients that stall
     # mid-handshake or idle forever without closing
     timeout = 120
+    # the request's http_in event while a capture is open (parse_request)
+    _span = None
     # TCP_NODELAY on every accepted connection (StreamRequestHandler
     # applies it in setup()): with keep-alive clients the response's
     # small writes otherwise collide with Nagle + the peer's delayed
@@ -114,9 +116,15 @@ class Handler(BaseHTTPRequestHandler):
     def parse_request(self) -> bool:
         # the request line has just been read off the socket: where a
         # served query's stage clock starts (_dispatch), so that header
-        # parsing is http_in's and not nobody's
+        # parsing is http_in's and not nobody's; so is the event of a
+        # capture, which the stage clock adopts
         self._t_request = time.perf_counter()
-        return super().parse_request()
+        self._span = (swap_span(None, "http_in") if _metrics.capture_open
+                      else None)
+        if super().parse_request():
+            return True
+        self._span = swap_span(self._span, None)
+        return False
 
     def _body(self) -> bytes:
         # read-once, cached: _dispatch drains the body for EVERY
@@ -162,6 +170,7 @@ class Handler(BaseHTTPRequestHandler):
         self.wfile.write(data)
 
     def _dispatch(self, method: str) -> None:
+        span, self._span = self._span, None
         parsed = urllib.parse.urlparse(self.path)
         self.query = urllib.parse.parse_qs(parsed.query)
         # one handler instance serves every request on a keep-alive
@@ -173,6 +182,7 @@ class Handler(BaseHTTPRequestHandler):
             # undrained chunked payload would corrupt the keep-alive
             # stream, so refuse and drop the connection
             self.close_connection = True
+            swap_span(span, None)
             self._reply({"error": "chunked transfer encoding not "
                                   "supported; send Content-Length"}, 411)
             return
@@ -192,8 +202,10 @@ class Handler(BaseHTTPRequestHandler):
         timer = None
         if fn is Handler.h_query:
             timer = StageTimer(srv.api.executor.stats, "http_in",
-                               at=self._t_request)
+                               at=self._t_request, span=span)
             set_current_timer(timer)
+        else:
+            swap_span(span, None)
         t0 = time.perf_counter()
         code = 200
         try:
@@ -714,8 +726,12 @@ class Handler(BaseHTTPRequestHandler):
         try:
             _time.sleep(seconds)
         finally:
-            _metrics.capture_open = False
-            jax.profiler.stop_trace()
+            # the device's ops are recorded until stop_trace has
+            # stopped its tracer: the stages go on being events as long
+            try:
+                jax.profiler.stop_trace()
+            finally:
+                _metrics.capture_open = False
         self._reply({"traceDir": out_dir, "seconds": seconds})
 
 

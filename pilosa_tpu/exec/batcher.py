@@ -550,8 +550,9 @@ class CountBatcher:
             out = self.fused.run_count_batch(padded, leaves,
                                              scratch=scratch)
             _stage("read")
-            host = np.asarray(out).astype(np.int64)
+            host = np.asarray(out)
             _stage("deliver")
+            host = host.astype(np.int64)
             self._pp.retire(out)
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
@@ -577,8 +578,9 @@ class CountBatcher:
                 plane, tuple(order), delta=delta, scratch=scratch,
                 sorted_idx=True)
             _stage("read")
-            host = np.asarray(out).astype(np.int64)
+            host = np.asarray(out)
             _stage("deliver")
+            host = host.astype(np.int64)
             self._pp.retire(out)
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
@@ -597,8 +599,9 @@ class CountBatcher:
                 out = self.fused.run_rowcounts_delta(
                     plane, delta, filter_words=filter_words)
                 _stage("read")
-                host = np.asarray(out).astype(np.int64)
+                host = np.asarray(out)
                 _stage("deliver")
+                host = host.astype(np.int64)
             else:
                 flags = (filter_words is not None,)
                 leaves = ((plane,) if filter_words is None
@@ -608,8 +611,9 @@ class CountBatcher:
                 out = self.fused.run_rowcounts_batch(flags, leaves,
                                                      scratch=scratch)
                 _stage("read")
-                host = np.asarray(out).astype(np.int64)[0]
+                host = np.asarray(out)
                 _stage("deliver")
+                host = host[0].astype(np.int64)
                 self._pp.retire(out)
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
@@ -630,8 +634,9 @@ class CountBatcher:
                                              (tuple(prog),),
                                              tuple(extras), delta=delta)
             _stage("read")
-            val = int(np.asarray(out).astype(np.int64)[0])
+            host = np.asarray(out)
             _stage("deliver")
+            val = int(host[0])
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
@@ -660,8 +665,8 @@ class CountBatcher:
                 kind, plane, filters, delta=delta)
             _stage("read")
             host = np.asarray(out)
-            vals = [decode(host[slot]) for slot in assign]
             _stage("deliver")
+            vals = [decode(host[slot]) for slot in assign]
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
@@ -686,8 +691,9 @@ class CountBatcher:
                                              tuple(operands),
                                              delta=delta)
             _stage("read")
-            val = int(np.asarray(out).astype(np.int64)[0])
+            host = np.asarray(out)
             _stage("deliver")
+            val = int(host[0])
         except Exception:  # noqa: BLE001 — windowed path is the fallback
             self.governor.record_fault()
             return None
@@ -1705,11 +1711,12 @@ class CountBatcher:
                 tuple(out for _, _, out, _ in pending),
                 scratch=self._pp.scratch((total,), "int32"))
             packed = np.asarray(packed_dev)
-            self.stats.count("batcher_readback_packed", 1)
-            self.stats.count("batcher_readback_groups", len(pending))
         except Exception:  # noqa: BLE001 — per-group reads
             packed = packed_dev = None
         _phase("deliver", w.items)
+        if packed is not None:
+            self.stats.count("batcher_readback_packed", 1)
+            self.stats.count("batcher_readback_groups", len(pending))
         off = 0
         for key, group, out, finish in pending:
             try:

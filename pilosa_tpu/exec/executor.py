@@ -1006,9 +1006,9 @@ class Executor:
         _stage("dispatch")
         per_shard = self.fused.run_count_batch(nodes, leaves)
         _stage("read")
-        host = np.asarray(per_shard).astype(np.int64)  # one read
+        host = np.asarray(per_shard)  # one read
         _stage("assemble")
-        return [int(row.sum()) for row in host]
+        return [int(row.sum()) for row in host.astype(np.int64)]
 
     def _count_batch_plane(self, ctx: _Ctx, calls: list[Call]) \
             -> list[int] | None:
@@ -1378,7 +1378,7 @@ class Executor:
             dev = self.fused.run_tree_counts(ps.plane, slots, progs,
                                              extras, delta=ps.delta)
             _stage("read")
-            vals = np.asarray(dev).astype(np.int64)
+            vals = np.asarray(dev)
             _stage("assemble")
             for j, i in enumerate(idxs):
                 out[i] = int(vals[j])
@@ -1539,7 +1539,7 @@ class Executor:
             out = self.fused.run_selected_counts(ps.plane, slots,
                                                  delta=ps.delta)
             _stage("read")
-            vals = np.asarray(out).astype(np.int64)[:len(slots)]
+            vals = np.asarray(out)[:len(slots)]
         _stage("assemble")
         return dict(zip(slots, (int(v) for v in vals)))
 
@@ -1571,7 +1571,7 @@ class Executor:
                         kernels.row_counts(p), axis=0, dtype=jnp.int32)))
                 out = fn(ps.plane)
             _stage("read")
-            totals = np.asarray(out).astype(np.int64)  # one read
+            totals = np.asarray(out)  # one read
         else:
             if delta is not None:
                 out = self.fused.run_rowcounts_delta(ps.plane, delta,
@@ -1581,9 +1581,10 @@ class Executor:
                 fn = self.fused._cached(key, lambda: kernels.row_counts)
                 out = fn(ps.plane)
             _stage("read")
-            totals = np.asarray(out).astype(np.int64).sum(axis=0)
+            totals = np.asarray(out)
         _stage("assemble")
-        return totals
+        totals = totals.astype(np.int64)
+        return totals if small else totals.sum(axis=0)
 
     # ---------------------------------------------------------- plan cache
 

@@ -562,15 +562,19 @@ def swap_span(span, name: str | None, trace_id: str | None = None):
     capture is open, open ``pilosa.<name>`` and return it — None
     otherwise, or when ``name`` is None.  The request's trace id rides
     the event as metadata, so one request's spans on the serving
-    thread and on the batcher's threads can be joined."""
+    thread and on the batcher's threads can be joined.  The new event
+    opens before the old one closes: the two overlap by the call's
+    own microseconds instead of leaving a hole between them, in which
+    a device op that starts (a launch's first, right after
+    ``dispatch``) would lie outside every event."""
+    new = None
+    if name is not None and capture_open:
+        from jax.profiler import TraceAnnotation
+        new = TraceAnnotation("pilosa." + name, trace_id=trace_id or "")
+        new.__enter__()
     if span is not None:
         span.__exit__(None, None, None)
-    if name is None or not capture_open:
-        return None
-    from jax.profiler import TraceAnnotation
-    span = TraceAnnotation("pilosa." + name, trace_id=trace_id or "")
-    span.__enter__()
-    return span
+    return new
 
 
 _NO_SPAN = contextlib.nullcontext()
@@ -649,16 +653,20 @@ class StageTimer:
                  "tracer", "trace_id")
 
     def __init__(self, stats, stage: str, metric: str = STAGE_METRIC,
-                 tracer=None, at: float | None = None):
+                 tracer=None, at: float | None = None, span=None):
         """Opens ``stage`` now, or as of ``at`` (a ``perf_counter()``
-        reading taken before the timer could be built)."""
+        reading taken before the timer could be built); ``span``: the
+        ``pilosa.<stage>`` annotation the caller opened then."""
         self._stats = stats
         self._metric = metric
         self._stage = None
         self._span = None
         self._start = time.perf_counter() if at is None else at
         self.attach(tracer)
-        self._open(stage, self._start)
+        if span is None:
+            self._open(stage, self._start)
+        else:
+            self._stage, self._span = stage, span
 
     @property
     def stage(self) -> str | None:

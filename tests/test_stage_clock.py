@@ -294,6 +294,125 @@ def test_the_window_wait_is_split_from_dispatch_and_read(
         holder.close()
 
 
+SUMS = "Sum(Row(f=0), field=v) Sum(Row(f=1), field=v) Sum(Row(g=2), field=v)"
+
+
+@pytest.fixture
+def slow_decodes(served, monkeypatch):
+    """The K-item Sum's decodes each sleep ``SLOW`` seconds inside a
+    ``test.decode`` annotation: host work after the one read."""
+    _, _, ex, _ = served
+    fused = ex.batcher.fused
+    real = fused.run_agg_plane_batch
+    calls = []
+
+    def slow(*a, **k):
+        out, assign, decode = real(*a, **k)
+
+        def decode_slowly(row):
+            import jax.profiler
+            with jax.profiler.TraceAnnotation("test.decode"):
+                calls.append(row)
+                time.sleep(SLOW)
+                return decode(row)
+        return out, assign, decode_slowly
+    monkeypatch.setattr(fused, "run_agg_plane_batch", slow)
+    return calls
+
+
+SLOW = 0.03
+
+
+def test_a_k_item_sums_decodes_are_booked_after_the_read(served,
+                                                          slow_decodes):
+    """``_fastlane_agg`` ends ``read`` when the value is on the host:
+    the K decodes that follow are ``deliver``'s, not the device's."""
+    _, stats, ex, _ = served
+    want = ex.execute("i", SUMS)           # planes resident, compiled
+    slow_decodes.clear()
+    before = _stages(stats)
+    assert ex.execute("i", SUMS) == want
+    got = _delta(before, _stages(stats))
+    assert len(slow_decodes) == 3          # one launch, three decodes
+    assert got["read"][0] == 1 and got["deliver"][0] == 1, got
+    assert got["deliver"][1] >= 3 * SLOW
+    assert got["read"][1] < SLOW, got
+
+
+def test_a_captured_read_event_ends_before_the_decodes(served, slow_decodes,
+                                                       tmp_path,
+                                                       monkeypatch):
+    import jax.profiler
+    from jax.profiler import ProfileData
+    _, _, ex, _ = served
+    want = ex.execute("i", SUMS)
+    jax.profiler.start_trace(str(tmp_path))
+    monkeypatch.setattr(obs_metrics, "capture_open", True)
+    try:
+        assert ex.execute("i", SUMS) == want
+    finally:
+        monkeypatch.setattr(obs_metrics, "capture_open", False)
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    reads, decodes, delivers, stages = [], [], [], []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                lo, hi = e.start_ns, e.start_ns + e.duration_ns
+                {"pilosa.read": reads, "test.decode": decodes,
+                 "pilosa.deliver": delivers}.get(e.name, []).append((lo, hi))
+                if e.name.startswith("pilosa."):
+                    stages.append((lo, hi))
+    # one request's stage events leave no hole between them: each
+    # opens before the one it follows has closed
+    stages.sort()
+    assert len(stages) >= 5
+    assert all(b_lo <= a_hi for (_, a_hi), (b_lo, _) in zip(stages,
+                                                             stages[1:]))
+    assert len(reads) == 1 and len(decodes) == 3 and delivers, \
+        (reads, decodes, delivers)
+    # the read event closed before the first decode began, and the
+    # decodes lie inside the deliver event that followed it
+    assert reads[0][1] <= min(lo for lo, _ in decodes)
+    assert any(d_lo <= min(lo for lo, _ in decodes)
+               and max(hi for _, hi in decodes) <= d_hi
+               for d_lo, d_hi in delivers)
+
+
+def test_the_http_in_event_starts_where_its_stage_does(served, tmp_path,
+                                                       monkeypatch):
+    """The stage clock dates ``http_in`` from the request line; under a
+    capture its event opens there too, so the header parsing is in it
+    and not in the no-request time between two requests."""
+    import http.server
+
+    import jax.profiler
+    from jax.profiler import ProfileData
+    query, _, _, _ = served
+    query("Count(Row(f=1))")
+    real = http.server.BaseHTTPRequestHandler.parse_request
+
+    def slow_headers(self):
+        time.sleep(0.03)
+        return real(self)
+    monkeypatch.setattr(http.server.BaseHTTPRequestHandler,
+                        "parse_request", slow_headers)
+    jax.profiler.start_trace(str(tmp_path))
+    monkeypatch.setattr(obs_metrics, "capture_open", True)
+    try:
+        query("Count(Row(f=1))")
+    finally:
+        monkeypatch.setattr(obs_metrics, "capture_open", False)
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    http_in = [e.duration_ns for plane in ProfileData.from_file(path).planes
+               for line in plane.lines for e in line.events
+               if e.name == "pilosa.http_in"]
+    assert len(http_in) == 1 and http_in[0] >= 0.03e9, http_in
+
+
 # -- (c) a plan that serves nothing -------------------------------------------
 
 def test_a_plan_whose_plane_is_not_resident_falls_through_once(tmp_path):
@@ -477,7 +596,7 @@ def test_gaps_are_put_down_to_what_covered_them():
             # the next request arrives 3 ms after the last one left
             ("pilosa.http_in", 12 * MS, 13 * MS)]
     device = [(3.5 * MS, 7 * MS), (6 * MS, 7.5 * MS)]  # overlapping ops
-    r = gaps.reduce_events(device, host)
+    r = gaps.reduce_events({"/device:TPU:0": device}, host)
     assert r["window_s"] == pytest.approx(0.013)
     assert r["busy_s"] == pytest.approx(0.004)
     assert r["idle_s"] == pytest.approx(0.009)
