@@ -482,6 +482,9 @@ class Handler(BaseHTTPRequestHandler):
         stats.gauge("plane_cache_pinned_entries", pc["pinnedEntries"])
         stats.gauge("plane_lease_count", pc["leases"])
         stats.gauge("plane_cache_hit_ratio", pc["hitRatio"])
+        # live row sets from the generation-checked memo vs walked
+        stats.gauge("plane_cache_row_set_hits", pc["rowSetHits"])
+        stats.gauge("plane_cache_row_set_misses", pc["rowSetMisses"])
         # ingest overlays (r15): set bits pending in device delta
         # overlays — base⊕delta serving depth before compaction folds
         stats.gauge("delta_overlay_bits",

@@ -3529,17 +3529,14 @@ class Executor:
                                    for r in frag.rows_containing(off))
             rows = np.array(sorted(row_set), dtype=np.uint64)
         else:
-            # live rows come straight from the fragment indexes — no
-            # plane materialization or device round trip needed
-            row_set = set()
-            for view in views:
-                for s in ctx.shards:
-                    if s == PAD_SHARD:
-                        continue
-                    frag = view.fragment(s)
-                    if frag is not None:
-                        row_set.update(frag.row_ids())
-            rows = np.array(sorted(row_set), dtype=np.uint64)
+            # live rows per view, memoised against the view's fragment
+            # generations: unchanged data walks no fragment.  A memo
+            # array is read-only; the filters below copy or slice it
+            parts = [self.planes.live_rows(field, view.name, ctx.shards)
+                     for view in views]
+            rows = (parts[0] if len(parts) == 1
+                    else np.unique(np.concatenate(parts)) if parts
+                    else np.empty(0, np.uint64))
         like = call.args.get("like")
         if like is not None:
             # SQL-style pattern over row KEYS (reference: Rows like=,

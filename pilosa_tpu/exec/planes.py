@@ -228,7 +228,14 @@ class PlaneCache:
         # (shared race-handling with FusedCache — exec/_lru.py)
         self._entries: dict[tuple, tuple[tuple, object, int]] = {}
         self._stamps = Stamps()
-        self._bytes_cache: dict[tuple, tuple[tuple, int]] = {}
+        # a view's live row set over a shard tuple, memoised against
+        # the view's fragment generations (:meth:`live_rows`): key
+        # (field path, view, shards) -> (gens, read-only sorted rows).
+        # Counted like hits / misses: plain increments, no lock.
+        self._row_sets: dict[tuple, tuple[tuple, np.ndarray]] = {}
+        self._row_set_bytes = 0
+        self.row_set_hits = 0
+        self.row_set_misses = 0
         self._zeros: dict[int, jax.Array] = {}
         self._bytes = 0
         self._lock = threading.RLock()
@@ -323,7 +330,8 @@ class PlaneCache:
         and makes every other in-flight query rebuild from scratch.
         Returns the bytes freed."""
         with self._lock:
-            self._bytes_cache.clear()
+            self._row_sets.clear()
+            self._row_set_bytes = 0
             pinned = self._pinned()
             freed = 0
             for key in self._eviction_order(pinned):
@@ -1213,29 +1221,57 @@ class PlaneCache:
     def plane_bytes(self, field: Field, view_name: str,
                     shards: tuple[int, ...],
                     gens: tuple | None = None) -> int:
-        """Estimated dense-plane footprint (for budget decisions).
+        """Estimated dense-plane footprint (for budget decisions): the
+        view's live rows padded as the build pads them.  The row count
+        comes from :meth:`live_rows`' memo — the estimate runs on
+        every query of the field that finds no resident plane, and
+        walking the fragments for it measured ~7 s a query for a
+        5M-row sparse field at 954 shards.  ``gens`` is the caller's
+        own sweep of the view (:meth:`generations`)."""
+        n_rows = len(self.live_rows(field, view_name, shards, gens))
+        return len(shards) * _pow2(max(1, n_rows)) * WORDS_PER_SHARD * 4
 
-        Generation-cached: the estimate runs on EVERY query of the
-        field (admission check), and recomputing it for a 5M-row
-        sparse field measured ~7 s/query at 954 shards (the same
-        class as the r3 warm-path metadata fixes).  ``gens``
-        is the caller's own sweep of the view (:meth:`generations`)."""
+    # the row-set memo's bounds: entries, and bytes of row ids (a
+    # 5M-row field's set is 40 MB); the newest entry always stays
+    ROW_SET_MAX = 256
+    ROW_SET_MAX_BYTES = 512 << 20
+
+    def live_rows(self, field: Field, view_name: str,
+                  shards: tuple[int, ...],
+                  gens: tuple | None = None) -> np.ndarray:
+        """Sorted uint64 ids of the rows with at least one bit in the
+        view over ``shards`` (``PAD_SHARD`` skipped), memoised against
+        the view's fragment generations: a hit costs one generation
+        sweep — none given the caller's own (``gens``, taken before
+        this call) — where a miss walks every fragment.
+
+        Exact because a fragment's live row set moves only with its
+        generation: every mutation bumps it, and a snapshot row that
+        expands lazily has at least one bit (its containers' headers
+        say so), so the expansion changes no live row.  The array is
+        shared and read-only: filter it, never write into it."""
         if gens is None:
-            gens = self._gens(field, view_name, shards)
+            gens = self._gens_fast(field, view_name, shards)
         key = (field.path, view_name, shards)
+        hit = self._row_sets.get(key)  # GIL-atomic; no lock needed
+        if hit is not None and hit[0] == gens:
+            self.row_set_hits += 1
+            return hit[1]
+        self.row_set_misses += 1
+        rows = self._union_row_ids(field, view_name, shards)
+        rows.flags.writeable = False
         with self._lock:
-            hit = self._bytes_cache.get(key)
-            if hit is not None and hit[0] == gens:
-                return hit[1]
-        est = (len(shards)
-               * _pow2(max(1, len(self._union_row_ids(field, view_name,
-                                                      shards))))
-               * WORDS_PER_SHARD * 4)
-        with self._lock:
-            self._bytes_cache[key] = (gens, est)
-            while len(self._bytes_cache) > 256:
-                self._bytes_cache.pop(next(iter(self._bytes_cache)))
-        return est
+            old = self._row_sets.pop(key, None)
+            if old is not None:
+                self._row_set_bytes -= old[1].nbytes
+            self._row_sets[key] = (gens, rows)
+            self._row_set_bytes += rows.nbytes
+            while len(self._row_sets) > 1 and (
+                    len(self._row_sets) > self.ROW_SET_MAX
+                    or self._row_set_bytes > self.ROW_SET_MAX_BYTES):
+                _, dropped = self._row_sets.pop(next(iter(self._row_sets)))
+                self._row_set_bytes -= dropped.nbytes
+        return rows
 
     @staticmethod
     def _union_row_ids(field: Field, view_name: str,
@@ -1315,6 +1351,10 @@ class PlaneCache:
                     "hitRatio": (round(hits / (hits + misses), 4)
                                  if hits + misses else 0.0),
                     "incrementalRefreshes": self.incremental_applied,
+                    # live row sets answered from the memo vs walked
+                    # from the fragments (:meth:`live_rows`)
+                    "rowSetHits": self.row_set_hits,
+                    "rowSetMisses": self.row_set_misses,
                     # r17 tenancy: explicit-order eviction accounting
                     # (budget pass, OOM recovery, quota make-room,
                     # stale page drops)
@@ -1338,11 +1378,12 @@ class PlaneCache:
 
     def invalidate(self, index: str | None = None) -> None:
         with self._lock:
-            # footprint estimates drop wholesale either way: their
+            # memoised row sets drop wholesale either way: their
             # generation guard can false-match after an index is
             # deleted and recreated at the same path (generations
-            # restart at 0), and recomputing them is cheap
-            self._bytes_cache.clear()
+            # restart at 0), and recomputing them is one walk
+            self._row_sets.clear()
+            self._row_set_bytes = 0
             if index is None:
                 self._entries.clear()
                 self._stamps.clear()

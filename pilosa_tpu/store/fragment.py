@@ -238,9 +238,14 @@ class Fragment:
         parse) failure: drop the snapshot refs and serve EMPTY locally
         — in cluster mode reads route to a replica and the scrubber's
         repair pulls a fresh copy; single-node, a loud quarantined
-        empty beats silently-wrong bits."""
+        empty beats silently-wrong bits.  The generation moves with
+        the rows it drops, as in :meth:`poison_snapshot`: caches keyed
+        on it (device planes, live row sets) must not serve them."""
         self._drop_snapshot()
         self._snap_crc = None
+        self.generation += 1
+        self._recent.clear()
+        self._recent.append((self.generation, None))
         self._sync_presence()
         h = self._health
         if h is not None:

@@ -3,7 +3,8 @@ reference: two and three ``Rows`` levels (the pair form of
 ``exec.groupby``, one pair matrix or one per outer combination), every
 argument the call takes, aggregates (the mapped form), combination
 blocks small enough that a boundary falls inside a level, a four-device
-mesh, and the counter that says which form a block took."""
+mesh (whose padded shard tuple keys the live-row memo), and the counter
+that says which form a block took."""
 
 import itertools
 
@@ -15,9 +16,11 @@ from pilosa_tpu.api import API
 from pilosa_tpu.engine.words import SHARD_WIDTH
 from pilosa_tpu.exec import Executor
 from pilosa_tpu.exec import groupby as gb
+from pilosa_tpu.exec.planes import PAD_SHARD
 from pilosa_tpu.obs import Stats
 from pilosa_tpu.parallel import MeshPlacement
 from pilosa_tpu.store import FieldOptions, Holder
+from pilosa_tpu.store.fragment import Fragment
 
 ROWS = {"f": 5, "g": 3, "h": 4}   # f pads to 8 slots, g to 4
 N_SHARDS = 3
@@ -189,6 +192,33 @@ def test_groupby_on_four_devices_equals_the_host_reference(world, case):
     assert ex.mesh_status()["devices"] == 4
     fields, args, ref_args = CASES[case]
     assert _got(ex, _pql(fields, args)) == _want(data, fields, ref_args)
+
+
+@pytest.mark.parametrize("case", ["two", "three", "two_filter"])
+def test_groupby_on_four_devices_reads_its_row_sets_from_the_memo(
+        world, monkeypatch, case):
+    """The padded shard tuple (three shards and a pad) keys the live-row
+    memo: once warm, a GroupBy walks no fragment for its row sets, takes
+    one memo hit per ``Rows`` level and answers as before."""
+    executor, data = world
+    ex = executor("mesh")
+    fields, args, ref_args = CASES[case]
+    want = _want(data, fields, ref_args)
+    assert _got(ex, _pql(fields, args)) == want
+    walked = []
+    for name in ("row_ids", "row_ids_array"):
+        orig = getattr(Fragment, name)
+        monkeypatch.setattr(Fragment, name,
+                            lambda self, _o=orig: walked.append(1) or _o(self))
+    before = ex.planes.stats()
+    assert _got(ex, _pql(fields, args)) == want
+    after = ex.planes.stats()
+    assert walked == []
+    assert after["rowSetHits"] - before["rowSetHits"] == len(fields)
+    assert after["rowSetMisses"] == before["rowSetMisses"]
+    padded = [k[2] for k in ex.planes._row_sets if k[1] == "standard"]
+    assert padded and all(s[-1] == PAD_SHARD and len(s) == 4
+                          for s in padded)
 
 
 @pytest.mark.parametrize("case,block_bytes,pair,mapped", [
