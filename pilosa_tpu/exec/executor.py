@@ -29,10 +29,12 @@ from pilosa_tpu import fault
 from pilosa_tpu.engine import bsi as bsik
 from pilosa_tpu.engine import kernels
 from pilosa_tpu.engine.words import SHARD_WIDTH, WORDS_PER_SHARD, unpack_columns
-from pilosa_tpu.exec.planes import PAD_SHARD, PlaneCache
+from pilosa_tpu.exec.planes import (CODED_ROWS_OVER, PAD_SHARD, PlaneCache,
+                                   PlaneSet)
 from pilosa_tpu.exec.result import (ExtractResult, GroupCountsResult,
                                     Pair, PairsResult, RowIdsResult,
                                     RowResult, ValCount)
+from pilosa_tpu.obs import metrics as _metrics
 from pilosa_tpu.obs.ledger import (clear_query_context,
                                    set_query_context)
 from pilosa_tpu.obs.metrics import (StageTimer, current_timer,
@@ -454,6 +456,10 @@ class Executor:
         # counts are computed (exec.groupby.block_form)
         for form in ("pair", "mapped"):
             self.stats.count("groupby_blocks_total", 0, form=form)
+        # the combinations a GroupBy dispatched, after its levels were
+        # cut to the rows its filter reaches, and the rows so cut
+        self.stats.count("groupby_combinations_total", 0)
+        self.stats.count("groupby_rows_pruned_total", 0)
         # device-cost ledger + flight recorder (r19): one ledger and
         # one event ring per executor, threaded into every layer that
         # spends device time (planes, pager, fused cache, batcher,
@@ -1030,6 +1036,8 @@ class Executor:
         if hit is None:
             return None
         field, values = hit
+        if self.planes.has_code(ctx.index.name, field, ctx.shards):
+            return None  # rows derived from the code, one by one
         row_ids = [self._row_id(ctx, field, v, create=False)
                    for v in values]
         if not self.planes.has_plane(ctx.index.name, field, VIEW_STANDARD,
@@ -1135,8 +1143,9 @@ class Executor:
             return None
         field, values = hit
         if self.planes.has_plane(ctx.index.name, field, VIEW_STANDARD,
-                                 ctx.shards):
-            return None  # whole plane resident: the normal path serves
+                                 ctx.shards) or self.planes.has_code(
+                ctx.index.name, field, ctx.shards):
+            return None  # whole plane resident, or a coded field
         est = self.planes.plane_bytes(field, VIEW_STANDARD, ctx.shards)
         if not self._paging_engaged(est):
             return None
@@ -1397,6 +1406,8 @@ class Executor:
             return None
         if len(ctx.shards) > self._REDUCE_SHARD_MAX:
             return None  # device int32 shard reduce must stay exact
+        if self.planes.has_code(ctx.index.name, field, ctx.shards):
+            return None  # a coded field's rows come one by one
         if not self.planes.has_plane(ctx.index.name, field,
                                      VIEW_STANDARD, ctx.shards):
             # admission mirrors _count_batch_plane: budget walk only
@@ -2140,6 +2151,11 @@ class Executor:
             return None
         # residency only — admission (budget walks) stays on the
         # un-cached path, exactly like _count_batch_plane
+        if self.planes.has_code(ctx.index.name, field, ctx.shards):
+            # a coded field answers by the entry's per-row form
+            if hit is not None:
+                hit.by_rule = True
+            return None
         if not self.planes.has_plane(ctx.index.name, field,
                                      VIEW_STANDARD, ctx.shards):
             if hit is not None:
@@ -3099,8 +3115,13 @@ class Executor:
         row_totals = None
         ps = None
         tried_nowait = False
-        if self.planes.has_entry(ctx.index.name, field, VIEW_STANDARD,
-                                 ctx.shards):
+        # 0. a coded field: its row counts are the histogram of its
+        # codes (asked only where no dense plane of it is resident)
+        code = (None if self.planes.has_entry(
+            ctx.index.name, field, VIEW_STANDARD, ctx.shards)
+            else self.planes.code_plane(ctx.index.name, field, ctx.shards))
+        if code is None and self.planes.has_entry(
+                ctx.index.name, field, VIEW_STANDARD, ctx.shards):
             # a resident entry (fresh or delta-dirty) serves without
             # the per-request plane_bytes fragment walk — under
             # sustained ingest the generations move every batch and
@@ -3109,7 +3130,7 @@ class Executor:
             ps = self.planes.field_plane_nowait(ctx.index.name, field,
                                                 VIEW_STANDARD, ctx.shards)
             tried_nowait = True
-        if ps is None:
+        if ps is None and code is None:
             est = self.planes.plane_bytes(field, VIEW_STANDARD,
                                           ctx.shards)
             if est <= self.planes.budget and not tried_nowait:
@@ -3119,7 +3140,12 @@ class Executor:
                 # stalling minutes
                 ps = self.planes.field_plane_nowait(
                     ctx.index.name, field, VIEW_STANDARD, ctx.shards)
-        if ps is not None:
+        if code is not None:
+            totals = self.planes.code_counts(code, filter_words)
+            if need_row_counts:
+                row_totals = self.planes.code_counts(code, None)
+            all_rows = code.row_ids
+        elif ps is not None:
             if ps.n_rows == 0:
                 return ({"pairs": [], "srcCount": src_count} if want_partial
                         else PairsResult([]))
@@ -3638,18 +3664,56 @@ class Executor:
                 f"GroupBy: more than {gb.MAX_SHARDS} shards per node "
                 "unsupported")
 
-        specs = []  # (field, row_ids, PlaneSet)
-        for rc in rows_calls:
-            f = self._field(ctx, str(rc.args.get("_field") or
-                                     rc.args.get("field")))
-            rows = self._rows_of(ctx, f, rc)
-            if len(rows) == 0:
-                return GroupCountsResult([])  # no combinations possible
-            # plane over the selected rows only — GroupBy memory scales
-            # with the Rows() selections, not field cardinality
-            ps = self.planes.rows_plane(ctx.index.name, f, VIEW_STANDARD,
-                                        rows, ctx.shards)
-            specs.append((f, rows, ps))
+        # each level's rows, cut to those the filter reaches (a group
+        # with a zero count is never returned, so the answer is the
+        # same): a dense level by the row counts of its plane under the
+        # filter, a coded level by the histogram of its codes.  A coded
+        # level's reached rows are derived on the device in blocks
+        # (``_groupby_chunks``).
+        specs = []  # (field, row_ids, PlaneSet | CodeSet)
+        pruned = 0
+        with _metrics.span("groupby.reach"):
+            for rc in rows_calls:
+                f = self._field(ctx, str(rc.args.get("_field") or
+                                         rc.args.get("field")))
+                rows = self._rows_of(ctx, f, rc)
+                if len(rows) == 0:
+                    return GroupCountsResult([])  # no combinations
+                # a level of at most CODED_ROWS_OVER rows is read dense
+                # unless its field holds a code already (no sweep)
+                code = (self.planes.code_plane(ctx.index.name, f, ctx.shards)
+                        if len(rows) > CODED_ROWS_OVER or self.planes.has_code(
+                            ctx.index.name, f, ctx.shards) else None)
+                ps = code
+                if code is not None:
+                    counts = (self.planes.code_counts(code, filter_words)
+                              if filter_words is not None else None)
+                else:
+                    # plane over the selected rows only — GroupBy
+                    # memory scales with the Rows() selections, not
+                    # field cardinality
+                    ps = self.planes.rows_plane(ctx.index.name, f,
+                                                VIEW_STANDARD, rows,
+                                                ctx.shards)
+                    counts = (np.asarray(gb.level_counts(ps.plane,
+                                                         filter_words))
+                              if filter_words is not None else None)
+                if counts is not None:
+                    slots = np.array([ps.slot_of.get(int(r), -1)
+                                      for r in rows], np.int64)
+                    keep = (slots >= 0) & (counts[slots] > 0)
+                    pruned += len(rows) - int(keep.sum())
+                    if not keep.all():
+                        rows = rows[keep]
+                        if len(rows) and code is None:
+                            ps = self.planes.take_rows(ps, rows)
+                if len(rows) == 0:
+                    self.stats.count("groupby_rows_pruned_total", pruned)
+                    return GroupCountsResult([])
+                specs.append((f, rows, ps))
+        self.stats.count("groupby_rows_pruned_total", pruned)
+        self.stats.count("groupby_combinations_total",
+                         math.prod(len(rows) for _, rows, _ in specs))
         # delta-aware agg plane (r20): sustained BSI ingest absorbs
         # into the overlay and the GroupBy program merges base⊕delta
         # in-program — no fold on the query path.  The depth>30 host
@@ -3679,9 +3743,6 @@ class Executor:
             raise ExecutionError(
                 "GroupBy: previous= must list one row per Rows call")
 
-        last_f, last_rows, last_ps = specs[-1]
-        last_slots = [last_ps.slot_of[int(r)] for r in last_rows]
-        last_rows_arr = np.asarray(last_rows, np.uint64)
         base = agg_field.options.base if agg_field is not None else 0
         # columnar accumulation: per block, fancy-index the surviving
         # (combo, last-row) cells straight into row-id/count/agg arrays.
@@ -3694,7 +3755,6 @@ class Executor:
         acc_aggs: list[np.ndarray] = []
         acc_mask: list[np.ndarray] = []
         n_levels = len(specs)
-        total = 0
         agg_kind = (None if minmax_host
                     else self._GROUPBY_AGGS.get(agg_name))
         submit = gb.run_block
@@ -3725,6 +3785,91 @@ class Executor:
             self.stats.count("groupby_blocks_total", 1,
                              form=gb.block_form(pl, agg))
             return submit(pl, ci, lp, fw, ap, agg, ad)
+        chunks = self._groupby_chunks(specs)
+        # more than one block of a coded level: the blocks' groups
+        # interleave, so they are sorted once all are in
+        stream = len(chunks) == 1
+        for chunk in chunks:
+            specs = [(f, rows, ps if isinstance(ps, PlaneSet)
+                      else self.planes.code_rows(ps, rows))
+                     for f, rows, ps in chunk]
+            if self._groupby_block_loop(
+                    ctx, specs, filter_words, agg_plane, agg_kind, agg_name,
+                    minmax_host, base, having_metric, having_cond,
+                    prev_tuple, limit if stream else None, run,
+                    acc_rows, acc_counts, acc_aggs, acc_mask):
+                break
+        if not acc_rows:
+            return GroupCountsResult([])
+        row_ids = np.concatenate(acc_rows)
+        counts = np.concatenate(acc_counts)
+        agg_col = np.concatenate(acc_aggs) if acc_aggs else None
+        mask_col = np.concatenate(acc_mask) if acc_mask else None
+        if not stream:
+            order = np.lexsort(row_ids.T[::-1])
+            row_ids, counts = row_ids[order], counts[order]
+            if agg_col is not None:
+                agg_col, mask_col = agg_col[order], mask_col[order]
+        if limit is not None:
+            row_ids = row_ids[: int(limit)]
+            counts = counts[: int(limit)]
+            if agg_col is not None:
+                agg_col = agg_col[: int(limit)]
+                mask_col = mask_col[: int(limit)]
+        # keyed fields translate ONCE per level over the unique row ids
+        # (was one KeyLog lookup per group member)
+        row_keys: list = [None] * n_levels
+        for lvl, (f, _, _) in enumerate(specs):
+            if f.options.keys and ctx.translate_output:
+                klog = self.translate.rows(ctx.index.name, f.name)
+                uniq, inv = np.unique(row_ids[:, lvl], return_inverse=True)
+                # strict=False: an id the translate log has not seen yet
+                # falls back to its numeric form (matches the Rows()
+                # output path, _execute_rows)
+                keys = klog.keys_of(uniq, strict=False)
+                row_keys[lvl] = [keys[i] for i in inv]
+        return GroupCountsResult(
+            fields=[f.name for f, _, _ in specs], row_ids=row_ids,
+            row_keys=row_keys if any(k is not None for k in row_keys)
+            else None,
+            counts=counts, aggs=agg_col, agg_mask=mask_col)
+
+    # bytes of a GroupBy's coded levels derived at once: past it a
+    # coded level's rows go in blocks (``_groupby_chunks``)
+    GROUPBY_CODED_BYTES = 2 << 30
+
+    def _groupby_chunks(self, specs: list) -> list:
+        """The GroupBy's levels as blocks to run in turn: each coded
+        level's rows cut so that the rows derived at once from the
+        codes stay within ``GROUPBY_CODED_BYTES``, every combination
+        of blocks in lexicographic order; one block when they fit."""
+        from itertools import product
+        coded = [i for i, (_, _, ps) in enumerate(specs)
+                 if not isinstance(ps, PlaneSet)]
+        options = [[spec] for spec in specs]
+        for i in coded:
+            f, rows, code = specs[i]
+            row_bytes = len(code.shards) * WORDS_PER_SHARD * 4
+            per = max(1, self.GROUPBY_CODED_BYTES
+                      // (len(coded) * row_bytes))
+            options[i] = [(f, rows[j:j + per], code)
+                          for j in range(0, len(rows), per)]
+        return [list(c) for c in product(*options)]
+
+    def _groupby_block_loop(self, ctx: _Ctx, specs: list, filter_words,
+                            agg_plane, agg_kind, agg_name, minmax_host,
+                            base, having_metric, having_cond, prev_tuple,
+                            limit, run, acc_rows, acc_counts, acc_aggs,
+                            acc_mask) -> bool:
+        """The combination blocks over one set of level planes, their
+        groups appended to the ``acc_*`` columns.  True once ``limit``
+        groups are in."""
+        from pilosa_tpu.exec import groupby as gb
+        n_levels = len(specs)
+        total = sum(len(r) for r in acc_rows)
+        last_f, last_rows, last_ps = specs[-1]
+        last_slots = [last_ps.slot_of[int(r)] for r in last_rows]
+        last_rows_arr = np.asarray(last_rows, np.uint64)
         for combo_rows, out in gb.iter_blocks(
                 specs, filter_words,
                 None if minmax_host else agg_plane, agg_kind,
@@ -3815,36 +3960,8 @@ class Executor:
                                 else np.ones(c_idx.size, bool))
             total += c_idx.size
             if limit is not None and total >= int(limit):
-                break
-        if not acc_rows:
-            return GroupCountsResult([])
-        row_ids = np.concatenate(acc_rows)
-        counts = np.concatenate(acc_counts)
-        agg_col = np.concatenate(acc_aggs) if acc_aggs else None
-        mask_col = np.concatenate(acc_mask) if acc_mask else None
-        if limit is not None:
-            row_ids = row_ids[: int(limit)]
-            counts = counts[: int(limit)]
-            if agg_col is not None:
-                agg_col = agg_col[: int(limit)]
-                mask_col = mask_col[: int(limit)]
-        # keyed fields translate ONCE per level over the unique row ids
-        # (was one KeyLog lookup per group member)
-        row_keys: list = [None] * n_levels
-        for lvl, (f, _, _) in enumerate(specs):
-            if f.options.keys and ctx.translate_output:
-                klog = self.translate.rows(ctx.index.name, f.name)
-                uniq, inv = np.unique(row_ids[:, lvl], return_inverse=True)
-                # strict=False: an id the translate log has not seen yet
-                # falls back to its numeric form (matches the Rows()
-                # output path, _execute_rows)
-                keys = klog.keys_of(uniq, strict=False)
-                row_keys[lvl] = [keys[i] for i in inv]
-        return GroupCountsResult(
-            fields=[f.name for f, _, _ in specs], row_ids=row_ids,
-            row_keys=row_keys if any(k is not None for k in row_keys)
-            else None,
-            counts=counts, aggs=agg_col, agg_mask=mask_col)
+                return True
+        return False
 
     def _host_group_minmax(self, ctx: _Ctx, specs, filter_words,
                            agg_plane, rows_mat: np.ndarray,

@@ -14,11 +14,14 @@ a combination selects from that small int32 matrix, never from a plane.
 Only the levels ABOVE those two loop (a sequential ``lax.map`` on the
 device, one pair matrix a step under the step's prefix rows).
 
-Aggregates (``aggregate=Sum/Count/Min/Max(field=f)``) keep a
-per-combination body mapped over every prefix level: per-combination
-BSI bit counts (Sum) or min/max bit descent (Min/Max) reduce over shards
-on device in int32; the host finishes the ``<< b`` weighting in exact
-int64 (``bsi.combine_sum`` policy).
+``aggregate=Sum(field=f)`` rides the same pairs: a step's combinations
+(the two innermost levels' pairs under the step's prefix rows) are the
+masks of one ``bsi.sum_pair_matrix`` against the BSI plane's rows, so a
+step reads the plane once for all of them.  Min/Max keep a
+per-combination body mapped over every prefix level (min/max bit
+descent).  Sums and bit counts reduce over shards on device in int32;
+the host finishes the ``<< b`` weighting in exact int64
+(``bsi.combine_sum`` policy).
 """
 
 from __future__ import annotations
@@ -44,9 +47,13 @@ MINMAX_MAX_DEPTH = 30
 
 def block_form(prefix_planes, agg) -> str:
     """How one block's counts are computed: ``"pair"`` (a pair-count
-    matrix over the two innermost levels) or ``"mapped"`` (one body per
-    combination: aggregates, and a GroupBy of a single ``Rows()``)."""
-    return "pair" if prefix_planes and agg is None else "mapped"
+    matrix over the two innermost levels, and for a Sum the pair
+    matrix of their masks against the BSI plane) or ``"mapped"`` (one
+    body per combination: Min/Max, and a bare or Count GroupBy of a
+    single ``Rows()``)."""
+    if agg == "sum" or (prefix_planes and agg is None):
+        return "pair"
+    return "mapped"
 
 
 def _prefix_words(planes, ix, filter_words):
@@ -59,31 +66,80 @@ def _prefix_words(planes, ix, filter_words):
     return prefix
 
 
-def _pair_counts_block(prefix_planes, combo_idx, last_plane, filter_words):
-    """Counts of one pair-form block, int32[Bo * n, n_last].
+def _pair_block(prefix_planes, combo_idx, last_plane, filter_words,
+                agg_plane=None, agg_delta=None):
+    """Outputs of one pair-form block: ``counts`` int32[Bo * n, n_last]
+    and, with ``agg_plane`` (a Sum), ``pos`` / ``neg``
+    int32[Bo * n, n_last, depth] and ``cnt`` int32[Bo * n, n_last].
 
     combo_idx: int32[Bo, n, L-1] — Bo combinations of the OUTER prefix
     levels, each with the n selected rows of the innermost prefix level
     (slots 0..n-1 of its plane: ``PlaneCache.rows_plane`` puts the
-    selected rows first, the pow2 pad behind them stays out of the scan).
-    """
-    *outer, inner = prefix_planes
-    n = combo_idx.shape[1]
+    selected rows first, the pow2 pad behind them stays out of the scan);
+    int32[1, 0] for a single ``Rows()`` (a Sum only: its one step pairs
+    the filter with every row).
 
-    def pairs_under(ix):
-        return kernels.pair_counts(inner[:, :n, :], last_plane,
-                                   _prefix_words(outer, ix, filter_words))
+    ``agg_delta``: the BSI plane's write overlay (``groupby_out``); its
+    touched word columns leave the base pass and are counted from the
+    merged mini plane, as single-word shards of the same pair matrix.
+    """
+    if prefix_planes:
+        *outer, inner = prefix_planes
+        n = combo_idx.shape[1]
+        inner = inner[:, :n, :]
+    else:
+        outer, inner = [], None
+    excl = mini = None
+    if agg_delta is not None:
+        from pilosa_tpu.ingest.delta import (bsi_excl_filter,
+                                             bsi_mini_plane)
+        cs, cw, cv, cm = agg_delta
+        excl = bsi_excl_filter(agg_plane, cs, cw, None)   # [S, W]
+        mini = bsi_mini_plane(agg_plane, cs, cw, cv, cm)  # [K, R, 1]
+        s = agg_plane.shape[0]
+        valid = jnp.where(cs < s, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+        cs_c = jnp.clip(cs, 0, s - 1)
+
+    def step(ix):
+        prefix = _prefix_words(outer, ix, filter_words)
+        if inner is None:
+            counts = jnp.sum(kernels.row_counts(last_plane, prefix),
+                             axis=0, dtype=jnp.int32)[None]
+        else:
+            counts = kernels.pair_counts(inner, last_plane, prefix)
+        out = {"counts": counts}
+        if agg_plane is None:
+            return out
+        base = prefix if excl is None else (
+            excl if prefix is None else prefix & excl)
+        pos, neg, cnt = bsik.sum_pair_matrix(agg_plane, inner, last_plane,
+                                             base)
+        if mini is not None:
+            # the touched columns as K single-word shards: the
+            # combination's words gathered there, the pads masked out
+            m_pre = valid if prefix is None else valid & prefix[cs_c, cw]
+            mp, mn, mc = bsik.sum_pair_matrix(
+                mini, None if inner is None else inner[cs_c, :, cw][..., None],
+                last_plane[cs_c, :, cw][..., None], m_pre[:, None])
+            pos, neg, cnt = pos + mp, neg + mn, cnt + mc
+        out.update(pos=pos, neg=neg, cnt=cnt)
+        return out
 
     if outer:
         # a plain (sequential) map: one step is a whole pair matrix, and
         # its prefix rows are dynamic slices fused into it.  A vmapped
         # step turns the row read into a gather that the TPU expands to
         # a loop copying every row out before anything is counted.
-        mats = jax.lax.map(pairs_under, combo_idx[:, 0, :-1])
+        mats = jax.lax.map(step, combo_idx[:, 0, :-1])
     else:
-        mats = pairs_under(None)[None]
-    counts = jnp.take_along_axis(mats, combo_idx[:, :, -1:], axis=1)
-    return counts.reshape(-1, last_plane.shape[1])
+        mats = jax.tree.map(lambda x: x[None], step(None))
+    if inner is None:
+        return jax.tree.map(lambda x: x[0], mats)
+    sel = combo_idx[:, :, -1]
+    return {k: jnp.take_along_axis(
+                v, sel.reshape(sel.shape + (1,) * (v.ndim - 2)), axis=1)
+            .reshape((-1,) + v.shape[2:])
+            for k, v in mats.items()}
 
 
 def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
@@ -93,8 +149,9 @@ def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
     prefix_planes: tuple of uint32[S, n_l, W], one per non-innermost
         ``Rows()`` level (possibly empty).  ``combo_idx`` holds one row
         slot per level per combination: int32[Bo, n, L-1] in the pair
-        form (:func:`block_form`; see :func:`_pair_counts_block`),
-        int32[C, L-1] in the mapped form.
+        form (:func:`block_form`; see :func:`_pair_block`),
+        int32[C, L-1] in the mapped form (Min/Max, and a single bare
+        ``Rows()``).
     last_plane: uint32[S, n_last, W] — innermost level, vectorized.
     filter_words: uint32[S, W] | None.
     agg_plane: BSI uint32[S, D+2, W] | None; agg: None | "sum" | "minmax".
@@ -109,10 +166,11 @@ def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
     (C = Bo * n in the pair form) and aggregate arrays (see body).
     """
     if block_form(prefix_planes, agg) == "pair":
-        return {"counts": _pair_counts_block(prefix_planes, combo_idx,
-                                             last_plane, filter_words)}
+        return _pair_block(prefix_planes, combo_idx, last_plane,
+                           filter_words,
+                           agg_plane if agg == "sum" else None, agg_delta)
     mini = excl = None
-    if agg is not None and agg_delta is not None:
+    if agg_delta is not None:
         from pilosa_tpu.ingest.delta import (bsi_excl_filter,
                                              bsi_mini_plane)
         cs, cw, cv, cm = agg_delta
@@ -142,54 +200,43 @@ def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
             words = jnp.bitwise_and(words, excl[:, None, :])
             mini_b = mini[:, None]       # [K, 1, R, 1] over n_last
             wmini_b = wmini[..., None]   # [K, n_last, 1]
-        if agg == "sum":
-            pos_c, neg_c, cnt = bsik.bit_counts(aplane, words)
-            pos = jnp.sum(pos_c, axis=0, dtype=jnp.int32)
-            neg = jnp.sum(neg_c, axis=0, dtype=jnp.int32)
-            cn = jnp.sum(cnt, axis=0, dtype=jnp.int32)
-            if mini is not None:
-                mp, mn, mc = bsik.bit_counts(mini_b, wmini_b)
-                pos = pos + jnp.sum(mp, axis=0, dtype=jnp.int32)
-                neg = neg + jnp.sum(mn, axis=0, dtype=jnp.int32)
-                cn = cn + jnp.sum(mc, axis=0, dtype=jnp.int32)
-            out["pos"], out["neg"], out["cnt"] = pos, neg, cn
-        else:  # minmax: signed int32 offsets, sentinel-reduced over shards
-            mm = bsik.min_max_bits(aplane, words)
-            if mini is not None:
-                # touched columns append as pseudo-shard entries (the
-                # per-key shapes match: [S, n_last, ...] ⧺ [K, n_last,
-                # ...]); the sentinel reduce over axis 0 below then
-                # combines base and mini exactly
-                mmm = bsik.min_max_bits(mini_b, wmini_b)
-                mm = {k: jnp.concatenate([mm[k], mmm[k]], axis=0)
-                      for k in mm}
-            depth = mm["min_bits"].shape[-1]
-            weights = (jnp.int32(1) << jnp.arange(depth, dtype=jnp.int32))
+        # minmax: signed int32 offsets, sentinel-reduced over shards
+        mm = bsik.min_max_bits(aplane, words)
+        if mini is not None:
+            # touched columns append as pseudo-shard entries (the
+            # per-key shapes match: [S, n_last, ...] ⧺ [K, n_last,
+            # ...]); the sentinel reduce over axis 0 below then
+            # combines base and mini exactly
+            mmm = bsik.min_max_bits(mini_b, wmini_b)
+            mm = {k: jnp.concatenate([mm[k], mmm[k]], axis=0)
+                  for k in mm}
+        depth = mm["min_bits"].shape[-1]
+        weights = (jnp.int32(1) << jnp.arange(depth, dtype=jnp.int32))
 
-            def signed(bits, neg):
-                v = jnp.sum(bits.astype(jnp.int32) * weights, axis=-1)
-                return jnp.where(neg, -v, v)
+        def signed(bits, neg):
+            v = jnp.sum(bits.astype(jnp.int32) * weights, axis=-1)
+            return jnp.where(neg, -v, v)
 
-            big = jnp.int32(2**31 - 1)
-            mn = jnp.where(mm["min_cnt"] > 0,
-                           signed(mm["min_bits"], mm["min_neg"]), big)
-            mx = jnp.where(mm["max_cnt"] > 0,
-                           signed(mm["max_bits"], mm["max_neg"]), -big)
-            gmn, gmx = jnp.min(mn, axis=0), jnp.max(mx, axis=0)
-            out["min"] = gmn
-            out["min_cnt"] = jnp.sum(
-                jnp.where(mn == gmn[None], mm["min_cnt"], 0), axis=0,
-                dtype=jnp.int32)
-            out["max"] = gmx
-            out["max_cnt"] = jnp.sum(
-                jnp.where(mx == gmx[None], mm["max_cnt"], 0), axis=0,
-                dtype=jnp.int32)
+        big = jnp.int32(2**31 - 1)
+        mn = jnp.where(mm["min_cnt"] > 0,
+                       signed(mm["min_bits"], mm["min_neg"]), big)
+        mx = jnp.where(mm["max_cnt"] > 0,
+                       signed(mm["max_bits"], mm["max_neg"]), -big)
+        gmn, gmx = jnp.min(mn, axis=0), jnp.max(mx, axis=0)
+        out["min"] = gmn
+        out["min_cnt"] = jnp.sum(
+            jnp.where(mn == gmn[None], mm["min_cnt"], 0), axis=0,
+            dtype=jnp.int32)
+        out["max"] = gmx
+        out["max_cnt"] = jnp.sum(
+            jnp.where(mx == gmx[None], mm["max_cnt"], 0), axis=0,
+            dtype=jnp.int32)
         return out
 
     if not prefix_planes:
         return jax.tree.map(lambda x: x[None],
                             body(jnp.zeros((0,), jnp.int32)))
-    # aggregates only: batch_size vmaps combos in chunks of 32, which
+    # Min/Max only: batch_size vmaps combos in chunks of 32, which
     # amortizes the per-iteration overhead of a serial map (~1.7 ms a
     # combination on a v5e — 4.3 s for a 50x50 prefix grid) while
     # bounding the fused intermediate.  Its price is the vmapped row
@@ -198,6 +245,14 @@ def groupby_out(prefix_planes, combo_idx, last_plane, filter_words,
 
 
 _groupby_program = partial(jax.jit, static_argnames=("agg",))(groupby_out)
+
+
+@jax.jit
+def level_counts(plane, filter_words):
+    """int32[R]: each row's columns under the filter, summed over
+    shards (a level's reach; exact while S <= MAX_SHARDS)."""
+    return jnp.sum(kernels.row_counts(plane, filter_words), axis=0,
+                   dtype=jnp.int32)
 
 
 def run_block(planes, combo_idx, last_plane, filter_words, agg_plane, agg,
@@ -308,10 +363,10 @@ def iter_blocks(specs, filter_words, agg_plane, agg_kind,
     elif agg_kind == "minmax":
         per_combo += n_last * 16
     planes = tuple(ps.plane for _, _, ps in prefix_specs)
-    pair = block_form(planes, agg_kind) == "pair"
     # a block is a whole number of units: one combination, or in the
     # pair form one run of the innermost prefix level
-    unit = len(slot_levels[-1]) if pair else 1
+    runs = block_form(planes, agg_kind) == "pair" and bool(prefix_specs)
+    unit = len(slot_levels[-1]) if runs else 1
     block = max(unit, min(n_combos, BLOCK_OUT_BYTES // per_combo,
                           *([LIMIT_BLOCK] if limited else []))
                 // unit * unit)
@@ -323,7 +378,7 @@ def iter_blocks(specs, filter_words, agg_plane, agg_kind,
         if n < block:  # pad to the compiled shape; tail dropped below
             sl = np.concatenate(
                 [sl, np.tile(sl[-unit:], ((block - n) // unit, 1))])
-        if pair:
+        if runs:
             sl = sl.reshape(block // unit, unit, -1)
         # the combo block stays a HOST array here: the batcher route
         # hashes it for dedupe (a device array would force a blocking

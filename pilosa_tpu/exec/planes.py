@@ -25,14 +25,24 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
-from pilosa_tpu.engine.bsi import OFFSET_ROW
-from pilosa_tpu.engine.words import WORDS_PER_SHARD
+from pilosa_tpu.engine import bsi as bsik
+from pilosa_tpu.engine.bsi import EXISTS_ROW, OFFSET_ROW
+from pilosa_tpu.engine.words import SHARD_WIDTH, WORDS_PER_SHARD
 from pilosa_tpu.obs import metrics as _metrics
-from pilosa_tpu.store.field import Field
+from pilosa_tpu.store.field import TYPE_MUTEX, TYPE_SET, Field
+from pilosa_tpu.store.view import VIEW_STANDARD
 
 PAD_SHARD = -1  # shard-list padding entry (meshed execution): all-zero words
 
 DEFAULT_BUDGET = 4 << 30
+
+# A single-valued set field of more than this many rows keeps its
+# standard view as a bit-sliced code (the row's slot in binary plus an
+# existence row, :class:`CodeSet`) in place of one dense row per row id.
+# The widest set field of the deployments measured before the code
+# existed has 64 rows (taxi-full-mesh4's dist_miles and
+# duration_minutes), so none of their planes changes layout.
+CODED_ROWS_OVER = 64
 
 
 def _pow2(n: int) -> int:
@@ -118,6 +128,49 @@ class PlaneSet:
         (delta absorb / rebuild) before this map is consulted."""
         return [self.slot_of.get(int(r)) if r is not None else None
                 for r in row_ids]
+
+
+@dataclass
+class CodeSet:
+    """A coded field's standard view over ``shards``: BSI's plane shape
+    ``uint32[n_shards, depth+2, W]`` (existence row, a sign row that
+    stays empty, ``depth`` bit rows) holding each column's row slot —
+    the row's position in ``row_ids``.  Valid while no column lies in
+    two rows; the build checks that (unless the field is ``mutex``)
+    and refuses the code otherwise."""
+
+    plane: jax.Array
+    shards: tuple[int, ...]
+    row_ids: np.ndarray       # uint64[R] sorted; slot = position
+    slot_of: dict[int, int]
+
+    @property
+    def n_rows(self) -> int:
+        return len(self.row_ids)
+
+    @property
+    def depth(self) -> int:
+        return self.plane.shape[-2] - OFFSET_ROW
+
+
+def _in_two_rows(cols: np.ndarray) -> bool:
+    """Whether a column of one fragment's set positions lies in two
+    rows (the check a non-mutex field's code build makes)."""
+    return bool(np.bincount(cols, minlength=SHARD_WIDTH).max() > 1)
+
+
+_equal_rows = jax.jit(bsik.equal_rows)
+_value_histogram = jax.jit(bsik.value_histogram)
+
+
+@jax.jit
+def _take_rows(plane, slots):
+    # a row at a time: a gather over the [S, R, W] view makes the TPU
+    # compiler copy the plane out first (542 MB for 5 of 32 rows over
+    # 172 shards, by its memory analysis; 0.1 MB this way)
+    rows = jax.lax.map(lambda s: jax.lax.dynamic_index_in_dim(
+        plane, s, axis=1, keepdims=False), slots)
+    return jax.numpy.transpose(rows, (1, 0, 2))
 
 
 @dataclass
@@ -236,6 +289,12 @@ class PlaneCache:
         self._row_set_bytes = 0
         self.row_set_hits = 0
         self.row_set_misses = 0
+        # coded fields (:meth:`code_plane`): key -> the generations at
+        # which the field was found to be held dense, and the code
+        # rebuilds that found a once-coded field multi-valued
+        self._code_dense: dict[tuple, tuple] = {}
+        self.coded_fallbacks = 0
+        self._stats.count("plane_coded_fallbacks_total", 0)
         self._zeros: dict[int, jax.Array] = {}
         self._bytes = 0
         self._lock = threading.RLock()
@@ -1032,6 +1091,15 @@ class PlaneCache:
         return ("plane", index, field.name, view_name,
                 shards) in self._entries
 
+    def has_code(self, index: str, field: Field,
+                 shards: tuple[int, ...]) -> bool:
+        """The field's standard view holds a code entry (fresh or
+        stale; :meth:`code_plane`): a hint that its rows are derived
+        from the code and no dense plane of the field is worth
+        building, never a promise that the next fetch still codes."""
+        return ("code", index, field.name, VIEW_STANDARD,
+                shards) in self._entries
+
     def has_rows(self, index: str, field: Field, view_name: str,
                  row_ids, shards: tuple[int, ...]) -> bool:
         """Every one of ``row_ids`` holds a single-row entry
@@ -1214,9 +1282,148 @@ class PlaneCache:
         fresh resident row answers without a second sweep."""
         key = ("row", index, field.name, view_name, row_id, shards)
         ps = self._get(key, field, view_name, shards,
-                       lambda f, v, s: self._build_row(f, v, s, row_id),
+                       lambda f, v, s: self._build_row(f, v, s, row_id,
+                                                       index),
                        gens=gens)
         return ps.plane
+
+    # -- coded fields ----------------------------------------------------------
+
+    def code_plane(self, index: str, field: Field,
+                   shards: tuple[int, ...]) -> CodeSet | None:
+        """The field's standard view as a bit-sliced code when it is
+        held so — a set or mutex field of more than
+        ``CODED_ROWS_OVER`` rows in which no column lies in two rows —
+        else None (the field is dense).  Validated against the view's
+        generations like every entry: a write rebuilds the code, and a
+        rebuild that finds a column in two rows holds the field dense
+        again (``coded_fallbacks``).  Both answers are memoised, so a
+        fresh one costs one generation sweep."""
+        if field.options.type not in (TYPE_SET, TYPE_MUTEX):
+            return None
+        key = ("code", index, field.name, VIEW_STANDARD, shards)
+        gens = self._gens_fast(field, VIEW_STANDARD, shards)
+        hit = self._entries.get(key)
+        if hit is not None and hit[0] == gens:
+            self._touch(key)
+            self._lease_fast(key)
+            self.hits += 1
+            return hit[1]
+        if self._code_dense.get(key) == gens:
+            return None
+        # the row set the caller's Rows / Row just had from the memo is
+        # read without counting a second consult of it
+        memo = self._row_sets.get((field.path, VIEW_STANDARD, shards))
+        rows = (memo[1] if memo is not None and memo[0] == gens
+                else self.live_rows(field, VIEW_STANDARD, shards, gens))
+        code = None
+        if len(rows) > CODED_ROWS_OVER:
+            self.misses += 1
+            code = self._build_code(field, shards, rows)
+        if code is None:
+            with self._lock:
+                self._code_dense[key] = gens
+                stale = self._entries.pop(key, None)
+                if stale is not None:
+                    self._stamps.pop(key)
+                    self._bytes -= stale[2]
+                    if len(rows) > CODED_ROWS_OVER:
+                        # the rebuild met a column in two rows
+                        self.coded_fallbacks += 1
+                        self._stats.count("plane_coded_fallbacks_total", 1)
+            return None
+        self._insert_entry(key, gens, code, code.plane.size * 4, lease=True)
+        return code
+
+    def _build_code(self, field: Field, shards: tuple[int, ...],
+                    row_ids: np.ndarray) -> CodeSet | None:
+        """Each column's row slot as bit rows, one fragment at a time
+        from its set positions (no dense expansion); None where a
+        column of a non-mutex field lies in two rows."""
+        from concurrent.futures import ThreadPoolExecutor
+        import time as _time
+        t0 = _time.perf_counter()
+        # one value past the last slot stays free: the code of a row
+        # with no bit anywhere (``code_rows``)
+        depth = max(1, len(row_ids).bit_length())
+        host = np.zeros((len(shards), depth + OFFSET_ROW, WORDS_PER_SHARD),
+                        np.uint32)
+        view = field.view(VIEW_STANDARD)
+        check = field.options.type != TYPE_MUTEX
+        # rows 0..R-1 are their own slots
+        slots_are_ids = int(row_ids[-1]) == len(row_ids) - 1
+        weights = np.arange(depth, dtype=np.int64)
+
+        def one(si: int) -> bool:
+            frag = view.fragment(shards[si]) \
+                if view is not None and shards[si] != PAD_SHARD else None
+            if frag is None:
+                return True
+            pos = frag.positions()
+            if not len(pos):
+                return True
+            cols = (pos % np.uint64(SHARD_WIDTH)).astype(np.int64)
+            if check and _in_two_rows(cols):
+                return False
+            rows = pos // np.uint64(SHARD_WIDTH)
+            slot = (rows.astype(np.int64) if slots_are_ids
+                    else np.searchsorted(row_ids, rows).astype(np.int64))
+            bits = np.zeros((depth + 1, SHARD_WIDTH), bool)
+            bits[0, cols] = True
+            bits[1:, cols] = (slot[None] >> weights[:, None]) & 1
+            packed = np.packbits(bits, axis=1, bitorder="little") \
+                .view(np.uint32)
+            host[si, EXISTS_ROW] = packed[0]
+            host[si, OFFSET_ROW:] = packed[1:]
+            return True
+
+        with _metrics.span("planes.code_rows", field=field.name):
+            with ThreadPoolExecutor(self.BUILD_WORKERS) as pool:
+                ok = all(pool.map(one, range(len(shards))))
+            if not ok:
+                return None
+            code = CodeSet(self.place(host), shards, row_ids,
+                           {int(r): i for i, r in enumerate(row_ids)})
+        dt = _time.perf_counter() - t0
+        with self._lock:
+            self.builds += 1
+            self.build_seconds_total += dt
+            self.build_bytes_total += host.nbytes
+        self._stats.observe("plane_build_seconds", dt)
+        self._stats.count("plane_build_bytes_total", host.nbytes)
+        return code
+
+    def code_rows(self, code: CodeSet, row_ids) -> PlaneSet:
+        """Rows of a coded field as a dense plane over exactly
+        ``row_ids`` (``uint32[n_shards, len(row_ids), W]``, derived on
+        the device; not cached — the caller bounds its size).  A row
+        with no slot (no bit anywhere) is all zeros."""
+        row_ids = np.asarray(row_ids, np.uint64)
+        # the slot no column holds: one past the last row
+        slots = np.array([code.slot_of.get(int(r), code.n_rows)
+                          for r in row_ids], np.int64)
+        with _metrics.span("planes.code_rows"):
+            plane = _equal_rows(code.plane,
+                                bsik.code_masks(slots, code.depth))
+        return PlaneSet(plane, code.shards, row_ids,
+                        {int(r): i for i, r in enumerate(row_ids)})
+
+    def code_counts(self, code: CodeSet,
+                    filter_words: jax.Array | None) -> np.ndarray:
+        """int64[R]: the columns under ``filter_words`` (all, without
+        one) that each row of a coded field holds — one pass over its
+        code rows (``bsi.value_histogram``)."""
+        hist = _value_histogram(code.plane, filter_words)
+        return np.asarray(hist)[:code.n_rows].astype(np.int64)
+
+    @staticmethod
+    def take_rows(ps: PlaneSet, row_ids) -> PlaneSet:
+        """A dense plane over ``row_ids`` of ``ps``'s rows, copied out
+        on the device (not cached)."""
+        row_ids = np.asarray(row_ids, np.uint64)
+        slots = np.array([ps.slot_of[int(r)] for r in row_ids], np.int32)
+        return PlaneSet(_take_rows(ps.plane, slots), ps.shards, row_ids,
+                        {int(r): i for i, r in enumerate(row_ids)})
 
     def plane_bytes(self, field: Field, view_name: str,
                     shards: tuple[int, ...],
@@ -1339,6 +1546,7 @@ class PlaneCache:
         only supported external view of the cache's internals)."""
         with self._lock:
             hits, misses = self.hits, self.misses
+            coded = [e for k, e in self._entries.items() if k[0] == "code"]
             return {"bytes": self._bytes, "budgetBytes": self.budget,
                     "entries": len(self._entries),
                     "pinnedEntries": len(self._pinned()),
@@ -1355,6 +1563,12 @@ class PlaneCache:
                     # from the fragments (:meth:`live_rows`)
                     "rowSetHits": self.row_set_hits,
                     "rowSetMisses": self.row_set_misses,
+                    # fields held as bit-sliced codes (code_plane),
+                    # their resident bytes, and the rebuilds that
+                    # found a coded field multi-valued
+                    "codedFields": len(coded),
+                    "codedBytes": sum(e[2] for e in coded),
+                    "codedFallbacks": self.coded_fallbacks,
                     # r17 tenancy: explicit-order eviction accounting
                     # (budget pass, OOM recovery, quota make-room,
                     # stale page drops)
@@ -1384,6 +1598,7 @@ class PlaneCache:
             # restart at 0), and recomputing them is one walk
             self._row_sets.clear()
             self._row_set_bytes = 0
+            self._code_dense.clear()
             if index is None:
                 self._entries.clear()
                 self._stamps.clear()
@@ -2038,7 +2253,15 @@ class PlaneCache:
                         {i: i for i in range(n_rows)})
 
     def _build_row(self, field: Field, view_name: str,
-                   shards: tuple[int, ...], row_id: int) -> PlaneSet:
+                   shards: tuple[int, ...], row_id: int,
+                   index: str | None = None) -> PlaneSet:
+        code = (self.code_plane(index, field, shards)
+                if index is not None and view_name == VIEW_STANDARD
+                else None)
+        if code is not None:
+            ps = self.code_rows(code, [row_id])
+            return PlaneSet(ps.plane[:, 0, :], shards,
+                            np.array([row_id], np.uint64), {row_id: 0})
         host = np.zeros((len(shards), WORDS_PER_SHARD), dtype=np.uint32)
         view = field.view(view_name)
         if view is not None:

@@ -15,7 +15,8 @@ event that covers it:
 
 - a ``pilosa.batcher.*`` phase where a batcher thread has one open,
 - else the stage of a serving thread (``pilosa.compile``,
-  ``pilosa.plane_build`` and ``pilosa.mesh.launch_wait``, nested in a
+  ``pilosa.plane_build``, ``pilosa.mesh.launch_wait``,
+  ``pilosa.groupby.reach`` and ``pilosa.planes.code_rows``, nested in a
   stage, win over it),
 - else ``no_request``: the server was waiting for its clients.
 

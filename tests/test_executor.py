@@ -833,6 +833,9 @@ class TestSparseTopN:
         cols = rng.choice(SHARD_WIDTH + 1000, size=n_bits,
                           replace=False).astype(np.uint64)
         idx.field("f").import_bits(rows, cols)  # spans 2 shards
+        # a few columns in a second row of f: a single-valued field of
+        # this width is held as a bit-sliced code, not sparse triplets
+        idx.field("f").import_bits((rows[:8] + 1) % n_rows, cols[:8])
         idx.field("g").import_bits(np.ones(n_bits // 2, np.uint64),
                                    cols[: n_bits // 2])
         idx.create_field("h")  # small source row: tanimoto can pass

@@ -1,7 +1,8 @@
 """GroupBy through ``Executor.execute`` against a set-based host
 reference: two and three ``Rows`` levels (the pair form of
 ``exec.groupby``, one pair matrix or one per outer combination), every
-argument the call takes, aggregates (the mapped form), combination
+argument the call takes, aggregates (a Sum in the pair form, Min/Max
+in the mapped form), combination
 blocks small enough that a boundary falls inside a level, a four-device
 mesh (whose padded shard tuple keys the live-row memo), and the counter
 that says which form a block took."""
@@ -148,8 +149,8 @@ def _blocks(ex) -> dict:
 
 
 # 112 B: seven combinations of a bare GroupBy whose last plane has four
-# slots — two whole runs of ``g`` a block, so ``f`` splits; under an
-# aggregate one combination a block, so every level splits
+# slots — two whole runs of ``g`` a block, so ``f`` splits; under a
+# Min/Max one combination a block, so every level splits
 @pytest.mark.parametrize("mode,block_bytes", [
     ("off", None), ("lane", None), ("lane", 112)],
     ids=["off", "lane", "lane_small_blocks"])
@@ -227,8 +228,9 @@ def test_groupby_on_four_devices_reads_its_row_sets_from_the_memo(
     ("three", None, 1, 0),
     ("three", 112, 3, 0),             # five runs of g, two a block
     ("three_limit", 112, 1, 0),       # the limit is met in the first
-    ("two_sum", None, 0, 1),
-    ("two_sum", 112, 0, 5),
+    ("two_sum", None, 1, 0),
+    ("two_sum", 112, 1, 0),           # a Sum's block is whole runs too
+    ("two_min", 112, 0, 5),
 ])
 def test_groupby_blocks_total_says_which_form_ran(world, monkeypatch, case,
                                                   block_bytes, pair, mapped):
