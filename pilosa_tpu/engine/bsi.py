@@ -294,28 +294,100 @@ def value_histogram(plane: jax.Array,
     shard, its shards counted already masked out), so the temporaries
     stay a few tens of MB at any shard count.  Rows are taken as
     ``[R, S, W]`` (see :func:`sum_pair_counts`): a block is a slice of
-    every row, never a copy of the plane."""
+    every row, never a copy of the plane.
+
+    Under a filter the high half is descended first: one pass finds the
+    n high values the filter's columns hold (an OR over the high masks,
+    no popcount), and a ``lax.switch`` on the power of two K >= n pairs
+    only those K highs' masks with the low ones — a high no column
+    reaches has a zero row, so the histogram is the same, for
+    ``K x 2^lo`` pair popcounts a word in place of ``2^depth``.  One
+    program for every filter: the bucket is chosen on the device.
+    Without a filter nearly every high is reached, so the pairs are
+    formed at once (:func:`histogram_pairs` counts the pairs a call
+    formed)."""
     rows = jnp.transpose(plane, (1, 0, 2))
     n_shards = rows.shape[1]
     block = min(HISTOGRAM_SHARDS, n_shards)
-    lo = (rows.shape[0] - OFFSET_ROW) // 2
+    depth = rows.shape[0] - OFFSET_ROW
+    lo = depth // 2
+    hi = depth - lo
 
-    def one(i):
-        start = jnp.minimum(i * block, n_shards - block)
-        part = jax.lax.dynamic_slice_in_dim(rows, start, block, axis=1)
-        fresh = start + jnp.arange(block) >= i * block
-        e = jnp.where(fresh[:, None],
-                      part[EXISTS_ROW] & ~part[SIGN_ROW], jnp.uint32(0))
-        if filter_words is not None:
-            e = e & jax.lax.dynamic_slice_in_dim(filter_words, start, block,
-                                                 axis=0)
-        high = _value_masks(part[OFFSET_ROW + lo:]) & e[None]
-        low = _value_masks(part[OFFSET_ROW:OFFSET_ROW + lo])
-        return jnp.sum(kernels.popcount(high[:, None] & low[None]),
-                       axis=(2, 3), dtype=jnp.int32)
+    def over_blocks(step):
+        """``step(bits, e)`` of every block stacked: the block's value
+        bit rows and its columns that count (present, non-negative,
+        under the filter, not counted by an earlier block)."""
+        def one(i):
+            start = jnp.minimum(i * block, n_shards - block)
+            part = jax.lax.dynamic_slice_in_dim(rows, start, block, axis=1)
+            fresh = start + jnp.arange(block) >= i * block
+            e = jnp.where(fresh[:, None],
+                          part[EXISTS_ROW] & ~part[SIGN_ROW], jnp.uint32(0))
+            if filter_words is not None:
+                e = e & jax.lax.dynamic_slice_in_dim(filter_words, start,
+                                                     block, axis=0)
+            return step(part[OFFSET_ROW:], e)
+        return jax.lax.map(one, jnp.arange(-(-n_shards // block)))
 
-    per = jax.lax.map(one, jnp.arange(-(-n_shards // block)))
-    return jnp.sum(per, axis=0, dtype=jnp.int32).reshape(-1)
+    def pairs(high_of):
+        """int32[K, 2^lo]: ``high_of(high bits, e)``'s K masks paired
+        with every low value's, summed over shards."""
+        def step(bits, e):
+            high = high_of(bits[lo:], e)
+            low = _value_masks(bits[:lo])
+            return jnp.sum(kernels.popcount(high[:, None] & low[None]),
+                           axis=(2, 3), dtype=jnp.int32)
+        return jnp.sum(over_blocks(step), axis=0, dtype=jnp.int32)
+
+    def every_high():
+        return pairs(lambda bits, e: _value_masks(bits) & e[None])
+
+    if filter_words is None:
+        return every_high().reshape(-1)
+
+    reached = jnp.any(over_blocks(
+        lambda bits, e: jnp.any((_value_masks(bits[lo:]) & e[None]) != 0,
+                                axis=(1, 2))), axis=0)
+    n = jnp.sum(reached, dtype=jnp.int32)
+
+    def some_highs(k):
+        def branch():
+            (high_ids,) = jnp.nonzero(reached, size=k, fill_value=0)
+            live = jnp.arange(k) < n
+            # per slot and high bit: all ones where the bit is clear,
+            # so ``row ^ flip`` is the columns that agree with the bit
+            flip = jnp.where((high_ids[:, None] >> jnp.arange(hi)) & 1 == 1,
+                             jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
+
+            def high_of(bits, e):
+                acc = jnp.where(live[:, None, None], e[None], jnp.uint32(0))
+                for b in range(hi):
+                    acc = acc & (bits[b][None] ^ flip[:, b, None, None])
+                return acc
+            # a slot past n counts 0 at high 0: the add leaves it so
+            return jnp.zeros((1 << hi, 1 << lo), jnp.int32).at[
+                high_ids].add(pairs(high_of))
+        return branch
+
+    branches = ([lambda: jnp.zeros((1 << hi, 1 << lo), jnp.int32)]
+                + [some_highs(1 << j) for j in range(hi)] + [every_high])
+    # 0 for no high, else 1 + log2 of the power of two K >= n (the bit
+    # length of n - 1, plus one)
+    pick = jnp.where(n == 0, 0, 33 - jax.lax.clz(jnp.maximum(n - 1, 0)))
+    return jax.lax.switch(pick, branches).reshape(-1)
+
+
+def histogram_pairs(hist: np.ndarray, filtered: bool) -> int:
+    """The pair popcounts a word that :func:`value_histogram` formed for
+    the histogram ``hist`` it returned: ``2^depth`` unfiltered, else
+    ``K x 2^lo`` for the power of two K >= the high values that hold a
+    count (0 for none) — the bucket the device chose."""
+    depth = int(hist.size).bit_length() - 1
+    if not filtered:
+        return 1 << depth
+    lo = depth // 2
+    n = int(np.count_nonzero(hist.reshape(-1, 1 << lo).any(axis=1)))
+    return 0 if n == 0 else (1 << (n - 1).bit_length()) << lo
 
 
 def combine_sum(pos_c, neg_c, cnt) -> tuple[int, int]:

@@ -295,6 +295,9 @@ class PlaneCache:
         self._code_dense: dict[tuple, tuple] = {}
         self.coded_fallbacks = 0
         self._stats.count("plane_coded_fallbacks_total", 0)
+        # pair popcounts a word that coded fields' histograms formed
+        # (:meth:`code_counts`)
+        self._stats.count("code_histogram_pairs_total", 0)
         self._zeros: dict[int, jax.Array] = {}
         self._bytes = 0
         self._lock = threading.RLock()
@@ -1413,8 +1416,10 @@ class PlaneCache:
         """int64[R]: the columns under ``filter_words`` (all, without
         one) that each row of a coded field holds — one pass over its
         code rows (``bsi.value_histogram``)."""
-        hist = _value_histogram(code.plane, filter_words)
-        return np.asarray(hist)[:code.n_rows].astype(np.int64)
+        hist = np.asarray(_value_histogram(code.plane, filter_words))
+        self._stats.count("code_histogram_pairs_total", bsik.histogram_pairs(
+            hist, filter_words is not None))
+        return hist[:code.n_rows].astype(np.int64)
 
     @staticmethod
     def take_rows(ps: PlaneSet, row_ids) -> PlaneSet:

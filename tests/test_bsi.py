@@ -140,3 +140,59 @@ def test_sum_pair_counts_equals_bit_counts_item_by_item(signed, items):
         want = np.concatenate([pos, neg, np.asarray(cnt)[:, None]], axis=-1)
         np.testing.assert_array_equal(out[k], want)
     assert (out[..., DEPTH:2 * DEPTH] != 0).any() == signed
+
+
+HISTOGRAM_CASES = {
+    # id: (depth, shards, the high values the filter keeps; None = no
+    # filter, "all" = half the columns at random, "dropped" = only the
+    # columns the histogram leaves out: negative or absent)
+    "unfiltered": (10, 3, None),
+    "empty_filter": (10, 3, ()),
+    "one_high": (10, 3, (7,)),
+    "two_far_highs": (10, 3, (1, 30)),
+    "three_highs_k4": (10, 3, (0, 2, 5)),
+    "every_high": (10, 3, "all"),
+    "odd_depth": (7, 3, (2, 9)),
+    "shifted_last_block": (8, 11, (3,)),
+    "shifted_last_block_unfiltered": (8, 11, None),
+    "negative_and_absent_only": (10, 3, "dropped"),
+}
+
+
+@pytest.mark.parametrize("depth,n_shards,highs", HISTOGRAM_CASES.values(),
+                         ids=HISTOGRAM_CASES.keys())
+def test_value_histogram_equals_numpy_under_the_filter(depth, n_shards,
+                                                        highs):
+    """The histogram of the non-negative stored values under a filter
+    against numpy's, whichever bucket of high values the filter picks;
+    the pairs it formed are the bucket's (``K x 2^lo``, ``2^depth``
+    without a filter)."""
+    rng = np.random.default_rng(depth * 100 + n_shards)
+    lo = depth // 2
+    vals = rng.integers(-(1 << depth) + 1, 1 << depth, (n_shards, NBITS))
+    present = rng.random((n_shards, NBITS)) < 0.9
+    plane = np.stack([
+        words.bsi_encode(np.flatnonzero(present[s]).astype(np.uint64),
+                         vals[s][present[s]], 0, depth, W)
+        for s in range(n_shards)])
+    counted = present & (vals >= 0)
+    if highs is None:
+        keep = np.ones_like(present)
+    elif highs == "all":
+        keep = rng.random(present.shape) < 0.5
+    elif highs == "dropped":
+        keep = ~counted
+    else:
+        keep = np.isin(np.abs(vals) >> lo, highs)
+    filt = np.stack([words.pack_columns(np.flatnonzero(k).astype(np.uint64),
+                                        W) for k in keep])
+    got = np.asarray(jax.jit(bsi.value_histogram)(
+        jnp.asarray(plane), None if highs is None else jnp.asarray(filt)))
+    want = np.bincount(vals[counted & keep], minlength=1 << depth)
+    np.testing.assert_array_equal(got, want)
+    n = len(np.unique(vals[counted & keep] >> lo))
+    if highs not in (None, "all", "dropped"):
+        assert n == len(highs)
+    pairs = (1 << depth if highs is None else
+             0 if n == 0 else (1 << (n - 1).bit_length()) << lo)
+    assert bsi.histogram_pairs(got, highs is not None) == pairs

@@ -222,6 +222,33 @@ def test_groupby_over_a_coded_field_equals_the_walk_and_the_dense_path(
     assert got(_executor(holder, True, monkeypatch)) == want
 
 
+@pytest.mark.parametrize("pql,pairs", [
+    # city = nation x 10 + d, 8 code bits: high = city >> 4
+    ("GroupBy(Rows(city), Rows(nation), filter=Row(nation=7))", 1 * 16),
+    ("GroupBy(Rows(city), filter=Row(nation=4))", 2 * 16),
+    ("GroupBy(Rows(city), filter=Union(Row(nation=0), Row(nation=14)))",
+     4 * 16),
+    ("TopN(city, n=5)", 256),
+    ("GroupBy(Rows(city))", 0),
+], ids=["one_high", "two_highs", "three_highs_k4", "unfiltered", "no_reach"])
+def test_code_histogram_pairs_count_the_bucket_a_call_formed(world, pql,
+                                                             pairs):
+    """``code_histogram_pairs_total`` grows by ``K x 2^lo`` for a
+    filtered histogram (K the power of two at or above the high values
+    the filter reaches) and by ``2^depth`` for an unfiltered one; a
+    GroupBy with no filter cuts no level and forms none."""
+    holder, _ = world
+    ex = _executor(holder)
+
+    def total():
+        return sum(ex.stats.snapshot()["counters"][
+            "code_histogram_pairs_total"].values())
+
+    before = total()
+    ex.execute("i", pql)
+    assert total() - before == pairs
+
+
 def test_a_write_that_puts_a_column_in_two_rows_holds_the_field_dense(
         tmp_path):
     holder = Holder(str(tmp_path)).open()
